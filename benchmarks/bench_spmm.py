@@ -93,11 +93,12 @@ def run_sharded() -> list:
     """--shard: the sharded engine end-to-end on an n-device mesh."""
     from repro.core.formats import batched_bcsr_from_dense
     from repro.kernels import engine
+    from repro.parallel.mesh import make_mesh
 
     rng = np.random.default_rng(0)
     rows = []
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     # Interpret-mode kernels pay a large per-grid-step emulation cost on
     # CPU, so the sharded demo runs reduced shapes; relative numbers (and
     # the end-to-end engine path) are what this mode exercises.

@@ -12,6 +12,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_moe, bench_precision, bench_spmm,
                             bench_spmspm, bench_stencil)
     sections = [

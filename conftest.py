@@ -10,7 +10,6 @@ Two jobs, both of which must happen before any test module imports jax:
    (full serving-loop smoke) tests are deselected by default so the tier-1
    gate (``pytest -x -q``) finishes in minutes; run them with
    ``--run-slow`` / ``--run-serve`` (or select explicitly with ``-m``).
-   ``tpu`` tests are skipped unless a TPU backend is attached.
 """
 import os
 
@@ -42,14 +41,6 @@ def pytest_addoption(parser):
 
 
 def pytest_collection_modifyitems(config, items):
-    import jax
-
-    if jax.default_backend() != "tpu":
-        skip_tpu = pytest.mark.skip(reason="requires a TPU backend")
-        for item in items:
-            if "tpu" in item.keywords:
-                item.add_marker(skip_tpu)
-
     # Explicit opt-ins override the default deselection: --run-slow, a -m
     # marker expression, or directly naming a file / node id on the CLI
     # (`pytest tests/test_models_smoke.py::test_x` should run that test,

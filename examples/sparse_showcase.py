@@ -79,8 +79,9 @@ print(f"[SU union] top-32 grad stream unioned with itself -> "
 # 5 -- the sharded + batched engine (the multi-cluster layer)
 from repro.core.formats import batched_bcsr_from_dense
 from repro.kernels import engine
+from repro.parallel.mesh import make_mesh
 
-mesh = jax.make_mesh((jax.device_count(),), ("data",))
+mesh = make_mesh((jax.device_count(),), ("data",))
 c_sh = engine.shard_spmm(a, b, mesh=mesh)
 print(f"[engine shard_spmm x{jax.device_count()}] bit-for-bit vs 1-device: "
       f"{bool((np.asarray(c_sh) == np.asarray(c)).all())}")

@@ -38,39 +38,11 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.formats import BCSR, INVALID_KEY, BatchedBCSR
-from repro.parallel.sharding import compat_shard_map
 from repro.kernels import tuning
 from repro.kernels.spmm import ops as spmm_ops
 from repro.kernels.spmm.kernel import spmm_bcsr
 from repro.kernels.spmspm.kernel import spmspm_ell
-
-
-_PROBE_MISSING = object()
-
-
-def backend_initialized() -> Optional[bool]:
-    """Best-effort, side-effect-free probe: has a jax backend initialized?
-
-    Returns True/False when one of the known (private) probe points exists,
-    or ``None`` when a jax upgrade has moved them all -- callers must treat
-    ``None`` as "unknown" and fall back to public APIs (which may themselves
-    initialize the backend), never crash.  There is deliberately no public
-    side-effect-free probe in jax, hence the version-tolerant ladder."""
-    import importlib
-    for mod_name, attr in (("jax._src.xla_bridge", "_backends"),
-                           ("jax.lib.xla_bridge", "_backends")):
-        try:
-            mod = importlib.import_module(mod_name)
-        except Exception:
-            continue
-        probe = getattr(mod, attr, _PROBE_MISSING)
-        if probe is _PROBE_MISSING:
-            continue
-        try:
-            return bool(probe)
-        except Exception:
-            return None
-    return None
+from repro.parallel.mesh import make_mesh
 
 
 def ensure_virtual_devices(n: int = 4, *, strict: bool = False) -> None:
@@ -86,15 +58,9 @@ def ensure_virtual_devices(n: int = 4, *, strict: bool = False) -> None:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={n}".strip())
-    initialized = backend_initialized()
-    if initialized is None:
-        # Probe points moved (jax upgrade): fall back to the public device
-        # count.  This *does* initialize the backend, but the flag above is
-        # already exported, so a fresh init honors it and the count check
-        # below stays accurate; a short count can only mean the backend
-        # predates this call.
-        initialized = True
-    if initialized and jax.local_device_count() < n:
+    # The flag is exported first, so a backend that initializes here honors
+    # it; a short count can only mean the backend predates this call.
+    if jax.local_device_count() < n:
         msg = (f"ensure_virtual_devices({n}): the JAX backend already "
                f"initialized with {jax.local_device_count()} device(s); the "
                "XLA_FLAGS override cannot take effect in this process. "
@@ -134,8 +100,7 @@ def auto_mesh(mesh: Optional[Mesh] = None) -> Tuple[Mesh, str]:
         from repro.parallel import context as pctx
         mesh = pctx.MESH
     if mesh is None:
-        devs = jax.devices()
-        mesh = jax.make_mesh((len(devs),), ("data",))
+        mesh = make_mesh((jax.device_count(),), ("data",))
     mesh = _intern_mesh(mesh)
     axis = "data" if "data" in mesh.axis_names else mesh.axis_names[0]
     return mesh, axis
@@ -186,8 +151,7 @@ class StreamPipeline:
       work for the next layer (double buffering); pushing the next execute
       first waits out the previous one.
 
-    :meth:`busy` probes (``jax.Array.is_ready``, failing closed to "in
-    flight" if a jax version drops the probe) whether an in-flight execute
+    :meth:`busy` probes (``jax.Array.is_ready``) whether an in-flight execute
     is still running on the device -- what the serving loop samples at
     route entry to attribute the route fetch wait as *hidden* behind
     device compute rather than serial with it."""
@@ -225,8 +189,7 @@ class StreamPipeline:
         """Is any in-flight entry still executing on the device?"""
         for _, h in self._inflight:
             for leaf in jax.tree.leaves(h):
-                is_ready = getattr(leaf, "is_ready", None)
-                if is_ready is None or not is_ready():
+                if not leaf.is_ready():
                     return True
         return False
 
@@ -252,7 +215,7 @@ class StreamPipeline:
             _, h = self._inflight.popleft()
             try:
                 jax.block_until_ready(h)
-            except Exception:
+            except jax.errors.JaxRuntimeError:
                 pass
 
 
@@ -277,20 +240,20 @@ def _sharded_spmm_fn(mesh: Mesh, axis: str, gm: int, bn: int, nt: int,
     if quant:
         # BlockQuant stream: per-block scales replicated alongside the index
         # stream (every device dequantizes the same narrow blocks).
-        return jax.jit(compat_shard_map(
+        return jax.jit(jax.shard_map(
             lambda rows, cols, blocks, scales, dense: kern(
                 rows, cols, blocks, dense, scales=scales),
             mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(None, axis)),
             out_specs=P(None, axis),
-            check=False,
+            check_vma=False,
         ))
-    return jax.jit(compat_shard_map(
+    return jax.jit(jax.shard_map(
         lambda rows, cols, blocks, dense: kern(rows, cols, blocks, dense),
         mesh=mesh,
         in_specs=(P(), P(), P(), P(None, axis)),
         out_specs=P(None, axis),
-        check=False,  # pallas_call has no replication/vma rule
+        check_vma=False,  # pallas_call has no vma rule
     ))
 
 
@@ -343,22 +306,22 @@ def _sharded_spmm_batched_fn(mesh: Mesh, axis: str, gm: int, bn: int, nt: int,
             return jax.vmap(lambda bl, s, d: kern(rows, cols, bl, d, scales=s)
                             )(blocks, scales, dense)
 
-        return jax.jit(compat_shard_map(
+        return jax.jit(jax.shard_map(
             local_q, mesh=mesh,
             in_specs=(P(), P(), P(axis), P(axis), P(axis)),
             out_specs=P(axis),
-            check=False,
+            check_vma=False,
         ))
 
     def local(rows, cols, blocks, dense):
         # vmap over this device's slice of the batch; index stream shared.
         return jax.vmap(lambda bl, d: kern(rows, cols, bl, d))(blocks, dense)
 
-    return jax.jit(compat_shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis)),
         out_specs=P(axis),
-        check=False,
+        check_vma=False,
     ))
 
 
@@ -452,19 +415,19 @@ def _sharded_spmspm_fn(mesh: Mesh, axis: str, rt: int, ct: int, nt: int,
                              out_dtype=jnp.dtype(out_dtype), interpret=interpret)
     if quant:
         # Per-row scales are replicated with A's row streams.
-        return jax.jit(compat_shard_map(
+        return jax.jit(jax.shard_map(
             lambda ak, av, asc, bk, bv: kern(ak, av, bk, bv, a_scales=asc),
             mesh=mesh,
             in_specs=(P(), P(), P(), P(axis, None), P(axis, None)),
             out_specs=P(None, axis),
-            check=False,
+            check_vma=False,
         ))
-    return jax.jit(compat_shard_map(
+    return jax.jit(jax.shard_map(
         lambda ak, av, bk, bv: kern(ak, av, bk, bv),
         mesh=mesh,
         in_specs=(P(), P(), P(axis, None), P(axis, None)),
         out_specs=P(None, axis),
-        check=False,
+        check_vma=False,
     ))
 
 
@@ -528,12 +491,12 @@ def _sharded_attention_sparse_fn(mesh: Mesh, axis: str, s_loc: int,
                                       bq=bq, bk=bk, q_offset=off,
                                       interpret=interpret)
 
-    return jax.jit(compat_shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None, axis, None), P(), P(),
                   P(axis), P(axis), P(axis)),
         out_specs=P(None, None, axis, None),
-        check=False,
+        check_vma=False,
     ))
 
 

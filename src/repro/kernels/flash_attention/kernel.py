@@ -156,11 +156,17 @@ def _tile_update(q, k, v, m_ref, l_ref, acc_ref, *, scale: float, kind,
     vf = v.astype(jnp.float32)                         # (bk, d)
     s = jax.lax.dot_general(qf, kf, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (bq, bk)
-    mask = k_pos < skv                                 # KV tail validity
-    mask &= jnp.where((kind & KIND_CAUSAL) != 0, q_pos >= k_pos, True)
+    # The kind bits pick scalar bounds on q_pos - k_pos (causal: >= 0,
+    # window: < window); an unset bit leaves that side open.  Mosaic cannot
+    # select between two boolean vectors, so the refinement is an integer
+    # compare rather than a where over masks.
+    diff = q_pos - k_pos
+    lo = jnp.where((kind & KIND_CAUSAL) != 0, 0, jnp.iinfo(jnp.int32).min)
+    mask = (k_pos < skv) & (diff >= lo)                # KV tail validity
     if window is not None:
-        mask &= jnp.where((kind & KIND_WINDOW) != 0,
-                          (q_pos - k_pos) < window, True)
+        hi = jnp.where((kind & KIND_WINDOW) != 0, window,
+                       jnp.iinfo(jnp.int32).max)
+        mask &= diff < hi
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]                                # (bq, 1)

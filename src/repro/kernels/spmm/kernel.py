@@ -51,9 +51,16 @@ def _spmm_kernel(brows_ref, bcols_ref, blocks_ref, b_ref, o_ref, *,
         # as ``values.astype(f32) * scale`` -- verbatim the host dequantize
         # contract, so the narrow path is bit-identical to dequantizing on
         # host and running the f32 kernel.
-        a = a.astype(jnp.float32) * scales_ref[0, 0]
+        a = a.astype(jnp.float32) * scales_ref[0]
     b = b_ref[...]             # (bk, bn)
-    acc = jnp.dot(a, b, preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    # f32 operands are multiplied at f32 accuracy (HIGHEST), not as one bf16
+    # MXU pass (the default), so f32 and dequantized values keep their
+    # precision and 0/1 dispatch blocks copy f32 activations exactly.  bf16
+    # products are exact at the default (Mosaic refuses HIGHEST for them).
+    f32 = jnp.result_type(a, b) == jnp.float32
+    acc = jnp.dot(a, b, preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST if f32 else None
+                  ).astype(o_ref.dtype)
     if nt == 1:
         o_ref[...] += acc
     else:
@@ -117,10 +124,12 @@ def spmm_bcsr(block_rows: jax.Array, block_cols: jax.Array, blocks: jax.Array,
         kern = functools.partial(_spmm_kernel, bn=bn, nt=nt)
     else:
         # Scale stream rides the same affine walk as the A blocks (one
-        # (1, 1) scalar per stream position, constant across t).
+        # (1, 1) tile per stream position, constant across t).  The array is
+        # (nnzb, 1, 1) so the block's last two dims equal the array's, as
+        # Mosaic requires of a block narrower than the (8, 128) tiling.
         kern = functools.partial(_spmm_quant_kernel, bn=bn, nt=nt)
-        in_specs.insert(1, walk.stream_spec((1, 1)))
-        operands.insert(3, scales.reshape(nnzb, 1).astype(jnp.float32))
+        in_specs.insert(1, walk.stream_spec((1, 1, 1)))
+        operands.insert(3, scales.reshape(nnzb, 1, 1).astype(jnp.float32))
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -6,10 +6,11 @@ the paper scores the comparator array by *index comparison rate* (GCOMP/s).
 
 TPU translation: merge loops are serial and hostile to the VPU, so the
 comparator array is re-shaped into what the VPU does natively -- **broadcast
-all-pairs comparison of index tiles**: one (rt x ct x Lb) vector `==` performs
-rt*ct*Lb index comparisons per step. Rows of A (padded-ELL, sorted keys) meet
-columns of B; matches gate a multiply-accumulate into a dense (rt x ct) output
-tile resident in VMEM. GCOMP/s maps to VPU comparison throughput; utilization
+all-pairs comparison of index tiles**: for stream positions (p, q), one
+lane-dense (rt x ct) vector `==` compares key p of rt rows of A with key q of
+ct columns of B. Rows of A (padded-ELL, sorted keys) meet columns of B;
+matches gate a multiply-accumulate into a dense (rt x ct) output tile
+resident in VMEM. GCOMP/s maps to VPU comparison throughput; utilization
 is useful/issued comparisons (reported by ``ops.comparison_stats``).
 """
 from __future__ import annotations
@@ -33,19 +34,32 @@ def _spmspm_kernel(ak_ref, av_ref, bk_ref, bv_ref, o_ref, *, rt, ct, la, lb,
         # dequantize_rows contract, so narrow A values are bit-identical to
         # dequantizing on host and running the f32 kernel.
         av = av * as_ref[...]             # (rt, la) * (rt, 1)
-    bk = bk_ref[...]                      # (ct, lb)
-    bv = bv_ref[...].astype(jnp.float32)  # (ct, lb)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rt, la), 1)
 
-    def body(p, acc):
-        # Comparator array step: keys of A at stream position p vs all of B.
-        a_key = jax.lax.dynamic_slice(ak, (0, p), (rt, 1))      # (rt, 1)
-        a_val = jax.lax.dynamic_slice(av, (0, p), (rt, 1))      # (rt, 1)
-        eq = (a_key[:, None, :] == bk[None, :, :])              # (rt, ct, lb)
-        eq &= a_key[:, None, :] != INVALID_KEY
-        contrib = jnp.where(eq, a_val[:, None, :] * bv[None, :, :], 0.0)
-        return acc + contrib.sum(axis=-1)                       # (rt, ct)
+    def a_step(p, acc):
+        # Column p of the A row stream as an (rt, 1) vector: a masked lane
+        # sum (one live term per row, so it is exact) -- Mosaic has no
+        # dynamic lane slice.
+        sel = lane == p
+        a_key = jnp.sum(jnp.where(sel, ak, 0), axis=1, keepdims=True)
+        a_val = jnp.sum(jnp.where(sel, av, 0.0), axis=1, keepdims=True)
+        live = a_key != INVALID_KEY
 
-    acc = jax.lax.fori_loop(0, la, body, jnp.zeros((rt, ct), jnp.float32))
+        def b_step(q, part):
+            # Comparator array step: keys of A at stream position p vs
+            # position q of every B column in the tile, lane-dense.
+            b_key = bk_ref[pl.ds(q, 1), :]                          # (1, ct)
+            b_val = bv_ref[pl.ds(q, 1), :].astype(jnp.float32)      # (1, ct)
+            eq = (a_key == b_key) & live                            # (rt, ct)
+            return part + jnp.where(eq, a_val * b_val, 0.0)
+
+        # keys are unique within a row and within a column, so at most one
+        # q matches per (r, c): the inner sum is exact in any order
+        part = jax.lax.fori_loop(0, lb, b_step,
+                                 jnp.zeros((rt, ct), jnp.float32))
+        return acc + part
+
+    acc = jax.lax.fori_loop(0, la, a_step, jnp.zeros((rt, ct), jnp.float32))
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -81,13 +95,15 @@ def spmspm_ell(a_keys: jax.Array, a_vals: jax.Array,
     assert nt >= 1, nt
     wct = nt * ct
     assert R % rt == 0 and C % wct == 0, ((R, C), (rt, ct, nt))
+    # B's column streams go in transposed, (lb, C): stream position q of a
+    # tile of columns is then one lane-dense row.
     in_specs = [
         pl.BlockSpec((rt, la), lambda i, j: (i, 0)),
         pl.BlockSpec((rt, la), lambda i, j: (i, 0)),
-        pl.BlockSpec((wct, lb), lambda i, j: (j, 0)),
-        pl.BlockSpec((wct, lb), lambda i, j: (j, 0)),
+        pl.BlockSpec((lb, wct), lambda i, j: (0, j)),
+        pl.BlockSpec((lb, wct), lambda i, j: (0, j)),
     ]
-    operands = [a_keys, a_vals, b_keys, b_vals]
+    operands = [a_keys, a_vals, b_keys.T, b_vals.T]
     if a_scales is None:
         kern = functools.partial(_spmspm_kernel, rt=rt, ct=wct, la=la, lb=lb)
     else:

@@ -3,7 +3,7 @@
 The SU mechanism being reproduced: Occamy programs two affine streams (grid
 reads, result writes) so the FPU executes one FMA per tap per cycle with zero
 address arithmetic. Here the Pallas grid pipeline streams overlapping
-(tile + 2*halo) VMEM blocks (element-offset ``pl.unblocked`` indexing) while the unrolled
+(tile + 2*halo) VMEM blocks (element-offset ``pl.Element`` indexing) while the unrolled
 shifted-slice FMA chain inside the kernel is the exact analogue of Fig. 5's
 "continuous FMA execution". Double-buffering of HBM->VMEM tiles is Pallas'
 automatic pipelining -- Occamy's DMA-core double buffering.
@@ -21,84 +21,68 @@ from jax.experimental import pallas as pl
 from repro.core.stencils import StencilSpec
 
 
-def _overlap_spec(elem_shape, index_map):
-    """Element-offset (overlapping halo window) BlockSpec across jax
-    versions: ``pl.Element`` on newer jax, ``indexing_mode=pl.unblocked``
-    on 0.4.x (same semantics -- index_map returns element offsets)."""
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(tuple(pl.Element(s) for s in elem_shape), index_map)
-    return pl.BlockSpec(elem_shape, index_map, indexing_mode=pl.unblocked)
+LANE, SUBLANE = 128, 8
 
 
-def _stencil_kernel_2d(x_ref, o_ref, *, spec: StencilSpec, th: int, tw: int):
+def halo_window(tile, r, blocks):
+    """The VMEM window one grid step reads: ``tile + 2r`` per dim.
+
+    Mosaic takes a block whose last two dims are multiples of the (8, 128)
+    tiling or equal to the array's.  Where a grid has one block along such
+    a dim the window is the whole padded dim; where it has more, the window
+    is rounded up to the tiling, an over-read past the halo that the taps
+    never touch."""
+    window = [t + 2 * r for t in tile]
+    for d, q in ((-1, LANE), (-2, SUBLANE)):
+        if blocks[d] > 1:
+            window[d] = -(-window[d] // q) * q
+    return tuple(window)
+
+
+def input_extent(interior, tile, r):
+    """The shape :func:`stencil` reads for ``interior`` (a multiple of
+    ``tile`` per dim): the halo, plus the far-end round-up of the last
+    window."""
+    blocks = [n // t for n, t in zip(interior, tile)]
+    window = halo_window(tile, r, blocks)
+    return tuple((b - 1) * t + w for b, t, w in zip(blocks, tile, window))
+
+
+def _stencil_kernel(x_ref, o_ref, *, spec: StencilSpec, tile):
     r = spec.radius
-    acc = jnp.zeros((th, tw), jnp.float32)
+    acc = jnp.zeros(tile, jnp.float32)
     # Unrolled FMA chain: one shifted VMEM read per tap, no address arithmetic.
     for off, c in zip(spec.offsets, spec.coeffs):
-        dy, dx = off
-        tap = x_ref[r + dy : r + dy + th, r + dx : r + dx + tw]
+        tap = x_ref[tuple(slice(r + o, r + o + t) for o, t in zip(off, tile))]
         acc += c * tap.astype(jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _stencil_kernel_3d(x_ref, o_ref, *, spec: StencilSpec, tz: int, ty: int, tx: int):
-    r = spec.radius
-    acc = jnp.zeros((tz, ty, tx), jnp.float32)
-    for off, c in zip(spec.offsets, spec.coeffs):
-        dz, dy, dx = off
-        tap = x_ref[
-            r + dz : r + dz + tz,
-            r + dy : r + dy + ty,
-            r + dx : r + dx + tx,
-        ]
-        acc += c * tap.astype(jnp.float32)
-    o_ref[...] = acc.astype(o_ref.dtype)
+def stencil(x: jax.Array, spec: StencilSpec, *, tile, interior,
+            interpret: bool = False) -> jax.Array:
+    """Apply ``spec`` to ``x``; returns the ``interior``-shaped result.
 
-
-def stencil_2d(grid_in: jax.Array, spec: StencilSpec, *, tile=(64, 128),
-               interpret: bool = False) -> jax.Array:
-    """Apply ``spec`` to ``grid_in`` (halo included); returns the interior.
-
-    ``grid_in``: (H + 2r, W + 2r); output (H, W). H % tile[0] == 0 etc.
-    (padding is handled by ops.apply).
+    2-D or 3-D (j3d27pt is the paper's 83%-utilization kernel).  Each
+    ``interior`` dim is a multiple of its ``tile`` entry and ``x`` has the
+    shape :func:`input_extent` gives (ops.apply does all the padding).
+    Each grid step reads an overlapping halo window at element offsets
+    (``pl.Element``).
     """
     r = spec.radius
-    th, tw = tile
-    H = grid_in.shape[0] - 2 * r
-    W = grid_in.shape[1] - 2 * r
-    assert H % th == 0 and W % tw == 0, (grid_in.shape, tile)
-    kern = functools.partial(_stencil_kernel_2d, spec=spec, th=th, tw=tw)
+    assert len(tile) == len(interior) == x.ndim == spec.ndim, (x.shape, tile)
+    assert all(n % t == 0 for n, t in zip(interior, tile)), (interior, tile)
+    assert x.shape == input_extent(interior, tile, r), (x.shape, interior,
+                                                        tile)
+    blocks = tuple(n // t for n, t in zip(interior, tile))
+    window = halo_window(tile, r, blocks)
+    kern = functools.partial(_stencil_kernel, spec=spec, tile=tuple(tile))
     return pl.pallas_call(
         kern,
-        grid=(H // th, W // tw),
-        in_specs=[_overlap_spec(
-            (th + 2 * r, tw + 2 * r),
-            lambda i, j: (i * th, j * tw),
-        )],
-        out_specs=pl.BlockSpec((th, tw), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((H, W), grid_in.dtype),
+        grid=blocks,
+        in_specs=[pl.BlockSpec(
+            tuple(pl.Element(w) for w in window),
+            lambda *ij: tuple(i * t for i, t in zip(ij, tile)))],
+        out_specs=pl.BlockSpec(tuple(tile), lambda *ij: ij),
+        out_shape=jax.ShapeDtypeStruct(tuple(interior), x.dtype),
         interpret=interpret,
-    )(grid_in)
-
-
-def stencil_3d(grid_in: jax.Array, spec: StencilSpec, *, tile=(8, 16, 128),
-               interpret: bool = False) -> jax.Array:
-    """3-D variant (j3d7pt / j3d27pt -- the paper's 83%-utilization kernel)."""
-    r = spec.radius
-    tz, ty, tx = tile
-    Z = grid_in.shape[0] - 2 * r
-    Y = grid_in.shape[1] - 2 * r
-    X = grid_in.shape[2] - 2 * r
-    assert Z % tz == 0 and Y % ty == 0 and X % tx == 0, (grid_in.shape, tile)
-    kern = functools.partial(_stencil_kernel_3d, spec=spec, tz=tz, ty=ty, tx=tx)
-    return pl.pallas_call(
-        kern,
-        grid=(Z // tz, Y // ty, X // tx),
-        in_specs=[_overlap_spec(
-            (tz + 2 * r, ty + 2 * r, tx + 2 * r),
-            lambda i, j, k: (i * tz, j * ty, k * tx),
-        )],
-        out_specs=pl.BlockSpec((tz, ty, tx), lambda i, j, k: (i, j, k)),
-        out_shape=jax.ShapeDtypeStruct((Z, Y, X), grid_in.dtype),
-        interpret=interpret,
-    )(grid_in)
+    )(x)

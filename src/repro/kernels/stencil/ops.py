@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 from repro.core.stencils import StencilSpec
 from repro.kernels import tuning
-from repro.kernels.stencil.kernel import stencil_2d, stencil_3d
+from repro.kernels.stencil.kernel import input_extent, stencil
 
 
 def _padded_tiles(interior: Tuple[int, ...], tile: Tuple[int, ...]):
@@ -33,10 +33,11 @@ def apply(grid_in: jax.Array, spec: StencilSpec, *, tile: Tuple[int, ...] | None
     tile = tuple(min(t, -(-n // 8) * 8 if i < ndim - 1 else -(-n // 128) * 128)
                  for i, (t, n) in enumerate(zip(tile, interior)))
     padded = _padded_tiles(interior, tile)
-    pad = [(0, p - n) for n, p in zip(interior, padded)]
-    x = jnp.pad(grid_in, pad)
-    fn = stencil_2d if ndim == 2 else stencil_3d
-    out = fn(x, spec, tile=tile, interpret=interpret)
+    # One zero pad, at the far end: up to tile multiples and out to the last
+    # halo window the kernel reads.
+    extent = input_extent(padded, tile, r)
+    x = jnp.pad(grid_in, [(0, e - s) for e, s in zip(extent, grid_in.shape)])
+    out = stencil(x, spec, tile=tile, interior=padded, interpret=interpret)
     return out[tuple(slice(0, n) for n in interior)]
 
 

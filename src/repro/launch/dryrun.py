@@ -28,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCH_NAMES, get_config
 from repro.launch import steps as St
 from repro.launch.hlo_analysis import analyze
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, cell_is_runnable, token_inputs
 from repro.parallel import sharding as Sh
 
@@ -142,7 +142,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_dev = mesh.size
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted, args, cfg, shape = build_cell(arch, shape_name, mesh, variant)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0

@@ -1,47 +1,16 @@
-"""Production mesh construction (single-pod 16x16 and multi-pod 2x16x16).
+"""The production mesh layout, built by :func:`repro.parallel.mesh.make_mesh`.
 
 A FUNCTION, not a module-level constant: importing this module never touches
 jax device state (device count locks on first backend init).
-
-Version compat: newer jax exposes ``axis_types=`` on ``jax.make_mesh`` and a
-``jax.set_mesh`` context; jax 0.4.x has neither.  ``compat_make_mesh`` /
-``mesh_context`` paper over the difference so every mesh construction in the
-repo goes through one door.
 """
 from __future__ import annotations
 
-import jax
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis_types where supported (newer jax)."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` on newer jax; the legacy ``Mesh`` context
-    manager (which scopes pjit's implicit mesh) on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from repro.parallel.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 ("data","model") per pod; 2x16x16 ("pod","data","model") for the
-    dual-pod system (the dual-chiplet analogue -- DESIGN.md S5)."""
+    dual-pod system (the dual-chiplet analogue)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
-
-
-def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
-    """Small mesh over however many (possibly fake) local devices exist --
-    used by tests and the smoke-scale distributed examples."""
-    if pod > 1:
-        return compat_make_mesh((pod, data, model), ("pod", "data", "model"))
-    return compat_make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)

@@ -314,7 +314,7 @@ class _ServeBase:
             pctx.MOE_DISPATCH = prev
 
     def _moe_two_phase(self, p_ffn, h, cfg, counts=None, pos=None,
-                       phase1=None):
+                       phase1=None, layer=None):
         """The route -> execute stage injected at every attn+moe layer.
 
         Serial mode (``pipeline_depth=0``): the drain on ``h`` happens
@@ -356,7 +356,8 @@ class _ServeBase:
                                               dtype=h.dtype)
         else:
             plan, info = moe.route_moe(p_ffn, h, cfg, counts=counts,
-                                       pos=pos, dispatch=self.backend)
+                                       pos=pos, dispatch=self.backend,
+                                       layer=layer)
         self.stats.append(StepStat(
             "route", step, time.monotonic() - t0,
             tokens=h.shape[0] * h.shape[1],
@@ -367,7 +368,7 @@ class _ServeBase:
                else (plan.stream.nnzb,) + tuple(plan.stream.shape))
         self._exec_keys.add(sig)
         t0 = time.monotonic()
-        out, new_counts = moe.execute_moe_jit(p_ffn, h, plan, cfg)
+        out, new_counts = moe.execute_moe_jit(p_ffn, h, plan, cfg, layer)
         out = self._fault("execute", out, step=step)
         # depth 0: push blocks immediately (the serial execute wall);
         # depth 1: the execute stays in flight behind the next host route
@@ -424,9 +425,9 @@ class _ServeBase:
                                       if route_s > 0 else 0.0),
                 "attention_ref_fallbacks": fallbacks,
             }
-        elif fallbacks:
-            # non-MoE (no route/execute stats) but the flash kernel silently
-            # fell back to the jnp reference: still surface the count
+        else:
+            # non-MoE (no route/execute stats): the fallback count is still
+            # surfaced, zero included
             out["timing"] = {"attention_ref_fallbacks": fallbacks}
         if self.two_phase:
             streams = [s for s in routes if "nnzb_stream" in s.extra]
@@ -962,7 +963,8 @@ class ServeScheduler(_ServeBase):
     def _prefill_into(self, req: Request, slot: int) -> bool:
         """Single-request prefill into cache row ``slot``, with bounded
         exponential-backoff retry (``RetryPolicy``).  Failed attempts --
-        a host-side exception anywhere in the layered pass, or non-finite
+        a retryable host-side exception (``resilience.RETRYABLE``) anywhere
+        in the layered pass, or non-finite
         first-token logits -- leave the shared cache and the request's key
         chain untouched (the health check runs BEFORE the scatter and
         before any key split), so a retry reproduces the fault-free
@@ -979,7 +981,7 @@ class ServeScheduler(_ServeBase):
                     self._sleep(delay)
             try:
                 ok = self._prefill_attempt(req, slot)
-            except Exception as e:
+            except R.RETRYABLE as e:
                 self._pipe.abort()
                 last_reason = f"prefill_error:{type(e).__name__}"
                 self.health.record("prefill_error", uid=req.uid,
@@ -1072,8 +1074,9 @@ class ServeScheduler(_ServeBase):
         the (request, token) pairs emitted.
 
         Failure handling (the per-request isolation contract,
-        tests/test_resilience.py): a host-side exception anywhere in the
-        step aborts the stream pipeline and retries the whole step under
+        tests/test_resilience.py): a retryable host-side exception
+        (``resilience.RETRYABLE``) anywhere in the step aborts the stream
+        pipeline and retries the whole step under
         the ``RetryPolicy`` -- nothing was committed (no cache write, no
         key split, no token append happens before the failure can
         surface), so the retry reproduces the fault-free step exactly.  A
@@ -1104,7 +1107,7 @@ class ServeScheduler(_ServeBase):
                     self._sleep(delay)
             try:
                 return self._decode_attempt(active)
-            except Exception as e:
+            except R.RETRYABLE as e:
                 self._pipe.abort()
                 err = e
                 self.health.record("decode_error", step=self.step_idx,
@@ -1272,6 +1275,8 @@ class ServeScheduler(_ServeBase):
 
 
 def main():
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -104,20 +104,20 @@ def sharded_flash_attention(q, k, v, *, causal=True, window=None):
     tp = "model"
     S = q.shape[2]
     S_loc = S // mesh.shape[tp]
-    interpret = jax.devices()[0].platform != "tpu"
+    from repro.kernels import tuning
+    interpret = not tuning.on_tpu()
 
     def body(qb, kb, vb):
         off = jax.lax.axis_index(tp) * S_loc
         return _fk(qb, kb, vb, causal=causal, window=window, q_offset=off,
                    bq=min(128, S_loc), bk=128, interpret=interpret)
 
-    from repro.parallel.sharding import compat_shard_map
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, tp, None), P(dp, None, None, None),
                   P(dp, None, None, None)),
         out_specs=P(dp, None, tp, None),
-        check=False)  # pallas_call outputs carry no replication/vma metadata
+        check_vma=False)  # pallas_call outputs carry no vma metadata
     return fn(q, k, v)
 
 

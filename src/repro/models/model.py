@@ -599,13 +599,17 @@ def decode_step(params: Params, cfg: ArchConfig, cache, pos, tokens_1,
 # program per (cfg, layer kind) -- lru-cached here, while jit's own cache
 # keys the (x, cache, pos) *shapes* -- so a whole decode phase reuses a
 # handful of compiled programs and the only eager seams left are the
-# intentional host routing yields.
+# intentional host routing yields.  Each program takes the repeat-stacked
+# params and the layer index and slices the layer inside (:func:`_layer`):
+# an eager ``stack[i]`` would copy the layer's weights on every call, and
+# for a layer as large as the free device memory it cannot be made at all.
 
 @functools.lru_cache(maxsize=None)
 def _layer_decode_jit(cfg: ArchConfig, kind: str):
     """Whole-layer one-token decode step (any kind; attn+moe dispatches its
     MoE in-trace, i.e. without the two-phase host yield)."""
-    def fn(p, x, cache, pos):
+    def fn(stack, i, x, cache, pos):
+        p = _layer(stack, i)
         if kind in ATTN_KINDS:
             return _decode_block_attn(kind, p, x, cfg, cache, pos, None)
         return apply_block(kind, p, x, cfg, cache=cache, pos=pos)
@@ -617,7 +621,8 @@ def _layer_decode_attn_head_jit(cfg: ArchConfig):
     """The attention half of an attn+moe decode layer, up to the host MoE
     yield: ln1 + attention + residual + ln2.  Returns (x_mid, h, new_attn).
     attn+moe layers never use ring buffers (see _decode_block_attn)."""
-    def fn(p, x, attn_cache, pos):
+    def fn(stack, i, x, attn_cache, pos):
+        p = _layer(stack, i)
         h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         a, new_attn = L.apply_attention(
             p["attn"], h, cfg, window=None, impl="chunked", cache=attn_cache,
@@ -639,7 +644,8 @@ def _layer_decode_attn_route_jit(cfg: ArchConfig, capacity: int):
     slot stream (``moe.plan_from_phase1``), never the hidden state.
     ``capacity`` is the static dispatch capacity the slot encoding assumes
     (always 1 for single-token decode, see ``moe.dispatch_capacity``)."""
-    def fn(p, x, attn_cache, counts, pos):
+    def fn(stack, i, x, attn_cache, counts, pos):
+        p = _layer(stack, i)
         h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         a, new_attn = L.apply_attention(
             p["attn"], h, cfg, window=None, impl="chunked", cache=attn_cache,
@@ -660,8 +666,9 @@ def _layer_prefill_jit(cfg: ArchConfig, kind: str, collect_kv: int,
     is a frozen (hashable) AttnMaskSpec so mask-routed prefills share this
     cache; the concrete BlockMask is built at trace time from the static
     sequence length."""
-    def fn(p, x):
-        return apply_block(kind, p, x, cfg, impl=impl, collect_kv=collect_kv,
+    def fn(stack, i, x):
+        return apply_block(kind, _layer(stack, i), x, cfg, impl=impl,
+                           collect_kv=collect_kv,
                            kv_quant=kv_quant, attn_mask=attn_mask)
     return jax.jit(fn)
 
@@ -671,7 +678,8 @@ def _layer_prefill_attn_head_jit(cfg: ArchConfig, kind: str, collect_kv: int,
                                  impl: str, kv_quant: Optional[str] = None,
                                  attn_mask: Optional[AttnMaskSpec] = None):
     """Prefill attention half of an attn+moe layer (up to the MoE yield)."""
-    def fn(p, x):
+    def fn(stack, i, x):
+        p = _layer(stack, i)
         h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         a, new_attn = L.apply_attention(
             p["attn"], h, cfg, window=_window_for(kind, cfg), impl=impl,
@@ -691,7 +699,8 @@ def _layer_prefill_attn_route_jit(cfg: ArchConfig, kind: str,
     """Prefill twin of :func:`_layer_decode_attn_route_jit`: attention half
     fused with MoE route phase 1 for a fresh sequence (zero occupancy,
     position 0); ``capacity`` is static per prompt length."""
-    def fn(p, x):
+    def fn(stack, i, x):
+        p = _layer(stack, i)
         h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         a, new_attn = L.apply_attention(
             p["attn"], h, cfg, window=_window_for(kind, cfg), impl=impl,
@@ -726,6 +735,12 @@ def _tree_take(tree, i):
     return jax.tree.map(lambda a: a[i], tree)
 
 
+def _layer(stack, i):
+    """Layer ``i`` of a repeat-stacked param tree; ``i=None`` means
+    ``stack`` is a single layer already (the shared attention block)."""
+    return stack if i is None else _tree_take(stack, i)
+
+
 def _tree_stack(per_step):
     """Re-stack per-repeat cache trees along a new leading dim."""
     return jax.tree.map(lambda *xs: jnp.stack(xs), *per_step)
@@ -746,7 +761,9 @@ def decode_step_layered(params: Params, cfg: ArchConfig, cache, pos,
     (:func:`_layer_decode_jit` / :func:`_layer_decode_attn_head_jit`, keyed
     on (cfg, kind) here and on the x/cache shapes by jit itself), so the
     host-dispatch tax is one call per layer, not one per op.  ``moe_fn`` is
-    threaded to every attn+moe block (signature of ``moe.apply_moe``);
+    threaded to every attn+moe block with the repeat-stacked ffn params and
+    ``layer=i`` (signature of ``moe.route_moe`` + ``moe.execute_moe``, which
+    slice the layer themselves);
     ``pos`` should be concrete here (a Python int, or an int ``(B,)``
     numpy vector for continuous batching -- per-row positions ride through
     attention writes, RoPE, and the prefix-stable MoE occupancy exactly like
@@ -778,28 +795,29 @@ def decode_step_layered(params: Params, cfg: ArchConfig, cache, pos,
         # same capacity route_moe would compute (C = 1 for S = 1 decode)
         route_cap = moe.dispatch_capacity(tokens_1.shape[1], cfg, pos0=pos)
 
-    def layered_block(kind, p_i, x, c_i):
+    def layered_block(kind, stack, i, x, c_i):
         if kind == "attn+moe" and moe_fn is not None:
             if route_ahead:
                 x, h, new_attn, ph1 = _layer_decode_attn_route_jit(
-                    cfg, route_cap)(p_i, x, c_i["attn"], c_i["moe"], pos_t)
+                    cfg, route_cap)(stack, i, x, c_i["attn"], c_i["moe"],
+                                    pos_t)
                 f, moe_counts = moe_fn(
-                    p_i["ffn"], h, cfg, counts=c_i.get("moe"), pos=pos,
-                    phase1=moe.Phase1(*ph1, route_cap))
+                    stack["ffn"], h, cfg, counts=c_i.get("moe"), pos=pos,
+                    layer=i, phase1=moe.Phase1(*ph1, route_cap))
             else:
                 x, h, new_attn = _layer_decode_attn_head_jit(cfg)(
-                    p_i, x, c_i["attn"], pos_t)
-                f, moe_counts = moe_fn(p_i["ffn"], h, cfg,
-                                       counts=c_i.get("moe"), pos=pos)
+                    stack, i, x, c_i["attn"], pos_t)
+                f, moe_counts = moe_fn(stack["ffn"], h, cfg,
+                                       counts=c_i.get("moe"), pos=pos,
+                                       layer=i)
             return x + f, {"attn": new_attn, "moe": moe_counts}
-        return _layer_decode_jit(cfg, kind)(p_i, x, c_i, pos_t)
+        return _layer_decode_jit(cfg, kind)(stack, i, x, c_i, pos_t)
 
     if "prologue" in params:
         pro = []
         for i in range(cfg.n_prologue):
-            x, nc = layered_block(cfg.block_unit[0],
-                                  take(params["prologue"], i), x,
-                                  take(cache["prologue"], i))
+            x, nc = layered_block(cfg.block_unit[0], params["prologue"], i,
+                                  x, take(cache["prologue"], i))
             pro.append(nc)
         new_cache["prologue"] = restack(pro)
 
@@ -807,16 +825,15 @@ def decode_step_layered(params: Params, cfg: ArchConfig, cache, pos,
     for i in range(cfg.n_repeats):
         new_slots = []
         for slot, kind in enumerate(cfg.block_unit):
-            p_i = take(params["blocks"][slot], i)
             c_i = take(cache["slots"][slot], i)
-            x, nc = layered_block(kind, p_i, x, c_i)
+            x, nc = layered_block(kind, params["blocks"][slot], i, x, c_i)
             new_slots.append(nc)
         if cfg.shared_attn_every:
             c_i = take(cache["slots"][-1], i)
             # step index is concrete here, so the fire test is plain Python
             if (i % cfg.shared_attn_every) == (cfg.shared_attn_every - 1):
                 x, nc = _layer_decode_jit(cfg, "shared_attn")(
-                    shared_p, x, c_i, pos_t)
+                    shared_p, None, x, c_i, pos_t)
             else:
                 nc = c_i
             new_slots.append(nc)
@@ -841,8 +858,8 @@ def prefill_layered(params: Params, tokens: jax.Array, cfg: ArchConfig, *,
     interleave host work (two-phase MoE routing) between layers.  This is
     what lets prefill ride the *bucketed routed stream* instead of tracing
     the full ``E*C x T`` dispatch grid (the single-phase jit fallback).
-    Each layer runs as a cached jitted step; ``moe_fn`` (signature of
-    ``moe.apply_moe``) is injected at every attn+moe block with
+    Each layer runs as a cached jitted step; ``moe_fn`` (as in
+    :func:`decode_step_layered`) is injected at every attn+moe block with
     ``counts=None, pos=None`` -- a fresh sequence at position 0, exactly the
     fused prefill's routing state.  ``route_ahead=True`` fuses route
     phase 1 into each attn+moe layer's jitted attention step and passes the
@@ -860,29 +877,30 @@ def prefill_layered(params: Params, tokens: jax.Array, cfg: ArchConfig, *,
     if route_ahead:
         route_cap = moe.dispatch_capacity(S_total, cfg, pos0=0)
 
-    def layered_block(kind, p_i, x):
+    def layered_block(kind, stack, i, x):
         if kind == "attn+moe" and moe_fn is not None:
             if route_ahead:
                 x, h, new_attn, ph1 = _layer_prefill_attn_route_jit(
                     cfg, kind, max_seq, impl, route_cap, kv_quant,
-                    attn_mask)(p_i, x)
-                f, moe_counts = moe_fn(p_i["ffn"], h, cfg, counts=None,
-                                       pos=None,
+                    attn_mask)(stack, i, x)
+                f, moe_counts = moe_fn(stack["ffn"], h, cfg, counts=None,
+                                       pos=None, layer=i,
                                        phase1=moe.Phase1(*ph1, route_cap))
             else:
                 x, h, new_attn = _layer_prefill_attn_head_jit(
-                    cfg, kind, max_seq, impl, kv_quant, attn_mask)(p_i, x)
-                f, moe_counts = moe_fn(p_i["ffn"], h, cfg, counts=None,
-                                       pos=None)
+                    cfg, kind, max_seq, impl, kv_quant, attn_mask)(stack, i,
+                                                                   x)
+                f, moe_counts = moe_fn(stack["ffn"], h, cfg, counts=None,
+                                       pos=None, layer=i)
             return x + f, {"attn": new_attn, "moe": moe_counts}
         return _layer_prefill_jit(cfg, kind, max_seq, impl, kv_quant,
-                                  attn_mask)(p_i, x)
+                                  attn_mask)(stack, i, x)
 
     if "prologue" in params:
         pro = []
         for i in range(cfg.n_prologue):
-            x, nc = layered_block(cfg.block_unit[0],
-                                  take(params["prologue"], i), x)
+            x, nc = layered_block(cfg.block_unit[0], params["prologue"], i,
+                                  x)
             pro.append(nc)
         cache["prologue"] = restack(pro)
 
@@ -890,14 +908,15 @@ def prefill_layered(params: Params, tokens: jax.Array, cfg: ArchConfig, *,
     for i in range(cfg.n_repeats):
         new_slots = []
         for slot, kind in enumerate(cfg.block_unit):
-            x, nc = layered_block(kind, take(params["blocks"][slot], i), x)
+            x, nc = layered_block(kind, params["blocks"][slot], i, x)
             new_slots.append(nc)
         if cfg.shared_attn_every:
             # cache is collected every repeat (like the fused prefill); the
             # residual only advances on fire steps
             fire = (i % cfg.shared_attn_every) == (cfg.shared_attn_every - 1)
             y2, c2 = _layer_prefill_jit(cfg, "shared_attn", max_seq,
-                                        impl, kv_quant, attn_mask)(shared_p, x)
+                                        impl, kv_quant, attn_mask)(shared_p,
+                                                                   None, x)
             if fire:
                 x = y2
             new_slots.append(c2)

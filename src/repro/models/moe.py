@@ -615,7 +615,8 @@ class MoEPlan:
 
 def route_moe(p, x, cfg: ArchConfig, *, counts: Optional[jax.Array] = None,
               pos=None, dispatch: Optional[str] = None,
-              groups: Optional[int] = None) -> Tuple[MoEPlan, dict]:
+              groups: Optional[int] = None,
+              layer: Optional[int] = None) -> Tuple[MoEPlan, dict]:
     """Phase 1: route on a *concrete* ``x``, materialize the dispatch stream.
 
     The router matmul + slot cumsums run as one jit-compiled program
@@ -634,6 +635,9 @@ def route_moe(p, x, cfg: ArchConfig, *, counts: Optional[jax.Array] = None,
     every-row-appears coverage blocks), ``nnzb_stream`` (after bucketing),
     ``grid_nnzb`` (what the single-phase jit fallback would stream), and
     ``bucket``.
+
+    ``layer`` indexes a repeat-stacked ``p`` (only the small router is
+    sliced, here).
     """
     from repro.parallel import context as pctx
     from repro.kernels import tuning
@@ -665,8 +669,9 @@ def route_moe(p, x, cfg: ArchConfig, *, counts: Optional[jax.Array] = None,
     # whole decode phase reuses a single compile); the stream compaction
     # stays host-side (plan_from_phase1) -- the data-dependent step jit
     # cannot do.
+    router = p["router"] if layer is None else p["router"][layer]
     gate, keep, new_counts, flat_slot = _route_phase1_jit(
-        p["router"], x, cfg, counts, jnp.asarray(pos0, jnp.int32), C)
+        router, x, cfg, counts, jnp.asarray(pos0, jnp.int32), C)
     return plan_from_phase1(Phase1(gate, keep, new_counts, flat_slot, C),
                             cfg, dispatch=backend, dtype=x.dtype)
 
@@ -719,7 +724,8 @@ def plan_from_phase1(phase1: Phase1, cfg: ArchConfig, *,
     return plan, info
 
 
-def execute_moe(p, x, plan: MoEPlan, cfg: ArchConfig):
+def execute_moe(p, x, plan: MoEPlan, cfg: ArchConfig,
+                layer: Optional[int] = None):
     """Phase 2: dispatch + expert FFN + combine from a phase-1 plan.
 
     Pure and jit-friendly: all data-dependence is frozen into ``plan``'s
@@ -728,7 +734,13 @@ def execute_moe(p, x, plan: MoEPlan, cfg: ArchConfig):
     nnzb-bucket) -- never per routing pattern.  Bit-identical to
     ``apply_moe(..., dispatch=plan.backend)`` on the same inputs: the
     dispatch buffer is built from the same 0/1 blocks and everything
-    downstream is the shared :func:`_moe_tail`."""
+    downstream is the shared :func:`_moe_tail`.
+
+    ``layer`` indexes a repeat-stacked ``p``; under :func:`execute_moe_jit`
+    the slice is part of the compiled program, so the expert weights are
+    never copied out of the stack eagerly."""
+    if layer is not None:
+        p = jax.tree.map(lambda a: a[layer], p)
     E, C = cfg.n_experts, plan.capacity
     if plan.backend == "bcsr":
         xe = _dispatch_stream(x, plan.stream, E, C)

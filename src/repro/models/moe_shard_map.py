@@ -92,7 +92,6 @@ def apply_moe_shard_map(p, x, cfg: ArchConfig, mesh, *, dp_axes, tp_axis):
         {k: P(dp, None) if k in ("w_gate", "w_up") else P(dp, None)
          for k in shared},
     )
-    from repro.parallel.sharding import compat_shard_map
-    fn = compat_shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=P(dp, tp_axis, None))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(dp, tp_axis, None))
     return fn(x, p["router"], p["experts"], shared)

@@ -173,25 +173,8 @@ def cache_specs(cache, cfg, mesh, *, batch: int) -> Any:
 
 
 def constrain(x, spec: P):
-    """with_sharding_constraint that is a no-op outside a mesh context."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    """``with_sharding_constraint`` against the mesh set by ``jax.set_mesh``;
+    a no-op when no mesh is set.  Any other failure raises."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """shard_map across jax versions: ``jax.shard_map(check_vma=)`` on new
-    jax, ``jax.experimental.shard_map.shard_map(check_rep=)`` on 0.4.x.
-    ``check=False`` is required whenever the body contains a pallas_call
-    (no replication/vma rule is registered for it)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check)
-        except TypeError:  # promotion window where the kwarg was check_rep
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    return jax.lax.with_sharding_constraint(x, spec)

@@ -58,6 +58,12 @@ class ShedError(RuntimeError):
     """Raised when admission control rejects a request (queue full)."""
 
 
+# What a serving driver retries: the host-side failures this module injects.
+# Every other exception is a fault of the program and propagates -- retrying
+# it would turn a bug into a "failed request" and hide it.
+RETRYABLE: Tuple[type, ...] = (InjectedFault,)
+
+
 def poison_rows(x: jax.Array, rows: Sequence[int], kind: str) -> jax.Array:
     """Overwrite batch rows of ``x`` with NaN or Inf, rows elsewhere intact.
 

@@ -87,12 +87,12 @@ from repro.launch import steps as St
 from repro.launch.shapes import ShapeSpec
 from repro.launch.hlo_analysis import analyze
 
-from repro.launch.mesh import compat_make_mesh, mesh_context
+from repro.parallel.mesh import make_mesh
 
 cfg = get_smoke("llama4-scout-17b-a16e")
 shape = ShapeSpec("tiny_train", "train", 32, 8)
-mesh = compat_make_mesh((2, 4), ("data", "model"))
-with mesh_context(mesh):
+mesh = make_mesh((2, 4), ("data", "model"))
+with jax.set_mesh(mesh):
     opt = St.default_optimizer()
     step, (p_s, o_s, tok_s, emb_s), out_s = St.make_train_step(
         cfg, shape, mesh, opt, seq_chunk=16)
@@ -156,13 +156,13 @@ sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from repro.models.layers import chunked_attention, flash_fwd_chunked_bwd
 from repro.parallel import context as pctx
-from repro.launch.mesh import compat_make_mesh, mesh_context
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from repro.parallel.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 q = jnp.asarray(rng.standard_normal((2, 4, 256, 32)), jnp.float32)
 k = jnp.asarray(rng.standard_normal((2, 2, 256, 32)), jnp.float32)
 v = jnp.asarray(rng.standard_normal((2, 2, 256, 32)), jnp.float32)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     with pctx.activation_specs(mesh=mesh):
         f = flash_fwd_chunked_bwd(True, None)
         gk = jax.grad(lambda q, k, v: (f(q, k, v) ** 2).sum(),

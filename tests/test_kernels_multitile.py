@@ -21,12 +21,13 @@ from repro.kernels.spmm.kernel import stream_walks
 from repro.kernels.spmm.ref import spmm_ref
 from repro.kernels.spmspm import ops as spmspm_ops
 from repro.kernels.spmspm.ref import spmspm_ref
+from repro.parallel.mesh import make_mesh
 
 RNG = np.random.default_rng(11)
 
 
 def _mesh(n):
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 # ---------------------------------------------------------------------------

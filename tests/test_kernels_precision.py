@@ -29,6 +29,7 @@ from repro.core.formats import (BCSR, BatchedBCSR, batched_bcsr_from_dense,
 from repro.kernels import engine
 from repro.kernels.spmm import ops as spmm_ops
 from repro.kernels.spmspm import ops as spmspm_ops
+from repro.parallel.mesh import make_mesh
 
 RNG = np.random.default_rng(7)
 QUANT = ["fp8_e4m3", "fp8_e5m2", "int8"]
@@ -184,7 +185,7 @@ def test_shard_spmm_quant_bit_identical(name):
     a = bcsr_from_dense(_block_sparse(RNG, (64, 64), 0.15), (8, 8))
     aq = a.quantize(name)
     b = jnp.asarray(RNG.standard_normal((64, 256)), jnp.float32)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     got = engine.shard_spmm(aq, b, mesh=mesh)
     want = spmm_ops.spmm(aq.dequantize(), b, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -195,7 +196,7 @@ def test_shard_spmm_batched_quant_bit_identical():
     d = np.stack([_block_sparse(RNG, (64, 64), 0.15) for _ in range(4)])
     ab = batched_bcsr_from_dense(d, (8, 8)).quantize("int8")
     b = jnp.asarray(RNG.standard_normal((4, 64, 128)), jnp.float32)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     got = engine.shard_spmm_batched(ab, b, mesh=mesh)
     want = spmm_ops.spmm_batched(ab.dequantize(), b, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -242,7 +243,7 @@ def test_shard_spmspm_quant_bit_identical():
     bk, bv = spmspm_ops.dense_to_ell_cols(bd)
     qv, qs = precision.quantize_rows(jnp.asarray(av), "fp8_e4m3")
     dq = precision.dequantize_rows(qv, qs)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     got = engine.shard_spmspm(ak, qv, bk, bv, mesh=mesh, a_scales=qs)
     want = spmspm_ops.spmspm(ak, dq, bk, bv, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -42,3 +42,17 @@ def test_stencil_flops_accounting():
     spec = STENCILS["j3d27pt"]
     assert spec.points == 27
     assert ops.flops(spec, (10, 10, 10)) == 2 * 27 * 1000
+
+
+@pytest.mark.parametrize("tile,interior,window,extent", [
+    # one block along the last two dims: the window is the whole padded dim
+    ((8, 32, 256), (256, 32, 256), (10, 34, 258), (258, 34, 258)),
+    # several: rounded up to the (8, 128) tiling, far end padded to match
+    ((8, 32, 256), (256, 256, 256), (10, 40, 258), (258, 264, 258)),
+    ((256, 256), (4096, 4096), (264, 384), (4104, 4224)),
+])
+def test_halo_window_and_extent(tile, interior, window, extent):
+    from repro.kernels.stencil.kernel import halo_window, input_extent
+    blocks = [n // t for n, t in zip(interior, tile)]
+    assert halo_window(tile, 1, blocks) == window
+    assert input_extent(interior, tile, 1) == extent

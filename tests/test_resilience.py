@@ -461,6 +461,24 @@ class TestRetryPolicyIntegration:
             sched.run()
         assert len(sched._pipe) == 0     # aborted clean, not wedged
 
+    @pytest.mark.parametrize("stage", ["prefill", "decode"])
+    def test_program_error_propagates_unretried(self, params, prompts, stage):
+        """Only ``R.RETRYABLE`` is retried: any other exception is a fault
+        of the program and leaves ``run`` as itself, no request FAILED."""
+        sched = ServeScheduler(
+            params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch="bcsr",
+            two_phase=True, cache_dtype=jnp.float32, pipeline_depth=1)
+
+        def bug(*a, **k):
+            raise ZeroDivisionError("a bug, not a flaky request")
+        setattr(sched, f"_{stage}_attempt", bug)
+        for p in prompts:
+            sched.submit(p, GEN)
+        with pytest.raises(ZeroDivisionError):
+            sched.run()
+        assert not sched.failed
+        assert not sched.health.counters     # no retry, no recorded error
+
 
 class TestDeadlinesAndShedding:
     def _sched(self, params, **kw):

@@ -18,6 +18,7 @@ from repro.kernels.spmm import ops as spmm_ops
 from repro.kernels.spmm.ref import spmm_ref
 from repro.kernels.spmspm import ops as spmspm_ops
 from repro.kernels.spmspm.ref import spmspm_ref
+from repro.parallel.mesh import make_mesh
 
 RNG = np.random.default_rng(42)
 
@@ -27,7 +28,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh(n):
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def test_mesh_has_virtual_devices():
@@ -193,24 +194,60 @@ def test_shard_spmm_batched_stream_is_trace_safe():
 def test_mesh_interning_dedups_equal_meshes():
     """Equal-but-fresh Mesh objects resolve to ONE interned mesh, so the
     lru-cached sharded programs never recompile for a recreated mesh."""
-    m1, _ = engine.auto_mesh(jax.make_mesh((2,), ("data",)))
-    m2, _ = engine.auto_mesh(jax.make_mesh((2,), ("data",)))
+    m1, _ = engine.auto_mesh(_mesh(2))
+    m2, _ = engine.auto_mesh(_mesh(2))
     assert m1 is m2
-    m3, _ = engine.auto_mesh(jax.make_mesh((2,), ("model",)))
+    m3, _ = engine.auto_mesh(make_mesh((2,), ("model",)))
     assert m3 is not m1  # different axis names = different program
 
     a = bcsr_from_dense(random_dense_sparse(RNG, (32, 32), 0.5), (8, 8))
     b = jnp.asarray(RNG.standard_normal((32, 256)), jnp.float32)
-    engine.shard_spmm(a, b, mesh=jax.make_mesh((2,), ("data",)))
+    engine.shard_spmm(a, b, mesh=_mesh(2))
     n_cached = engine._sharded_spmm_fn.cache_info().currsize
-    engine.shard_spmm(a, b, mesh=jax.make_mesh((2,), ("data",)))
+    engine.shard_spmm(a, b, mesh=_mesh(2))
     assert engine._sharded_spmm_fn.cache_info().currsize == n_cached
 
 
-def test_backend_initialized_probe():
-    """The version-tolerant probe reports True here (conftest initialized
-    the backend long ago) and never raises."""
-    assert engine.backend_initialized() in (True, None)
+def test_auto_mesh_axes_are_auto_and_results_slice():
+    """Every mesh is Auto-axis: a sharded result slices like any array (an
+    Explicit-axis mesh raises ShardingTypeError on the slice)."""
+    mesh, _ = engine.auto_mesh()
+    assert set(mesh.axis_types) == {jax.sharding.AxisType.Auto}
+    a = bcsr_from_dense(random_dense_sparse(RNG, (32, 32), 0.5), (8, 8))
+    b = jnp.asarray(RNG.standard_normal((32, 200)), jnp.float32)
+    out = engine.shard_spmm(a, b)
+    np.testing.assert_allclose(np.asarray(out[:, :100]),
+                               np.asarray(spmm_ref(a, b))[:, :100],
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_constrain_noop_without_mesh_and_loud_with_one():
+    from jax.sharding import PartitionSpec as P
+    from repro.parallel.sharding import constrain
+
+    x = jnp.ones((8, 8))
+    assert constrain(x, P("data", None)) is x        # no mesh set
+    with jax.set_mesh(_mesh(2)):
+        y = jax.jit(lambda x: constrain(x, P("data", None)))(x)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+        with pytest.raises(ValueError, match="nope"):  # no such mesh axis
+            jax.jit(lambda x: constrain(x, P("nope", None)))(x)
+
+
+def test_on_tpu_lets_backend_errors_raise(monkeypatch):
+    from repro.kernels import tuning
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+    monkeypatch.setattr(tuning.jax, "default_backend", broken)
+    tuning.on_tpu.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="failed to initialize"):
+            tuning.on_tpu()
+    finally:
+        monkeypatch.undo()
+        tuning.on_tpu.cache_clear()
+    assert tuning.on_tpu() is False
 
 
 # ---------------------------------------------------------------------------

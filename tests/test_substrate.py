@@ -113,18 +113,18 @@ import sys, jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 sys.path.insert(0, "src")
 from repro.checkpoint.manager import CheckpointManager
-from repro.launch.mesh import compat_make_mesh
+from repro.parallel.mesh import make_mesh
 
 d = sys.argv[1]
 mode = sys.argv[2]
 mgr = CheckpointManager(d)
 if mode == "save":
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     w = jax.device_put(np.arange(64.0).reshape(8, 8),
                        NamedSharding(mesh, P("data", "model")))
     mgr.save(3, {"w": w})
 else:  # restore on a DIFFERENT mesh shape
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     sh = {"w": NamedSharding(mesh, P("model", "data"))}
     like = {"w": jax.ShapeDtypeStruct((8, 8), jnp.float64)}
     got, step = mgr.restore(like, shardings=sh)
