@@ -85,4 +85,5 @@ def stencil(x: jax.Array, spec: StencilSpec, *, tile, interior,
         out_specs=pl.BlockSpec(tuple(tile), lambda *ij: ij),
         out_shape=jax.ShapeDtypeStruct(tuple(interior), x.dtype),
         interpret=interpret,
+        name=f"stencil_{spec.name}",
     )(x)

@@ -8,10 +8,10 @@ the hardcoded ``bn=128`` / ``rt=ct=8`` / stencil-tile defaults scattered
 through the ops layers with a single provenance-tracked table.
 
 Provenance: entries were selected by sweeping interpret-mode correctness on
-CPU and the roofline model in ``benchmarks/roofline.py`` for TPU shapes
-(VMEM budget ~16 MiB/core, MXU 128x128, VPU 8x128).  They are *static*
-heuristics, not on-device measurements -- re-measure when real TPU time is
-available and override via :func:`register`.
+CPU and a hand roofline model for TPU shapes (VMEM budget ~16 MiB/core, MXU
+128x128, VPU 8x128).  They are *static* heuristics, not on-device
+measurements -- re-measure when real TPU time is available and override via
+:func:`register`.
 
 Selection contract:
   * ``lookup("spmm", ...)``    -> {"bn": int}

@@ -73,6 +73,14 @@ shed policy.  Accumulated failures walk a ``DegradationLadder`` (quantized
 KV -> wide, sparse mask -> ref, pipeline depth 1 -> 0); everything is
 surfaced in ``summary()["health"]``.
 
+**Spans**: each timed phase is one :class:`StepStat` in ``stats`` and one
+``serve.<phase>`` profiler annotation, opened and closed at the same two
+points (``_ServeBase._span``), so a device trace shows what the host was
+doing in each gap.  A scheduler tick is ``step``, holding ``admit`` (a
+``prefill`` per admission), ``decode`` (the forward through the health
+fetch), ``writeback`` (the KV-cache write-back) and ``sample`` (the per-row
+token loop); ``step`` carries the tick's host-sync count.
+
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b --smoke \
       --batch 4 --prompt-len 32 --gen 32
@@ -105,13 +113,16 @@ from repro.runtime import resilience as R
 
 @dataclasses.dataclass
 class StepStat:
-    """One timed phase of the loop; ``extra`` carries phase-specific detail
-    (e.g. the route phase's nnzb stream accounting)."""
-    phase: str          # prefill | route | execute | decode | sample
+    """One timed phase of the loop, the program's span record; ``extra``
+    carries phase-specific detail (e.g. the route phase's nnzb stream
+    accounting).  ``start`` is the host monotonic clock at the span's open,
+    so ``start + seconds`` is its close."""
+    phase: str  # step|admit|prefill|route|execute|decode|writeback|sample|drain
     step: int           # decode step index (-1 for prefill)
     seconds: float
     tokens: int = 0
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    start: float = 0.0
 
 
 def _percentiles_ms(seconds: List[float]) -> Dict[str, float]:
@@ -202,6 +213,14 @@ def _health_accum_jit(vocab: int):
         jnp.isfinite(lg[:, :vocab]), axis=-1))
 
 
+def _decode_program(cfg):
+    """The fused one-token decode step, jitted under a stable name (the
+    device trace shows ``jit_decode_step``)."""
+    def decode_step(params, cache, pos, tokens):
+        return M.decode_step(params, cfg, cache, pos, tokens)
+    return jax.jit(decode_step)
+
+
 class _ServeBase:
     """Phase machinery shared by the static-batch :class:`ServeLoop` and the
     continuous-batching :class:`ServeScheduler`: dispatch-backend selection,
@@ -249,6 +268,32 @@ class _ServeBase:
             pipeline_depth=self.pipeline_depth,
             fail_threshold=fail_threshold)
         self._row_uids: Optional[List[Optional[int]]] = None
+        self._host_syncs = 0   # every wait on / fetch of a device value
+
+    # -------------------------------------------------------------- spans --
+
+    @contextlib.contextmanager
+    def _span(self, phase: str, step: int, *, tokens: int = 0, **extra):
+        """Time one phase: a ``serve.<phase>`` profiler annotation (on the
+        device trace's clock) and a :class:`StepStat` appended to
+        ``self.stats`` on a normal exit, both opened and closed at the same
+        two points.  Yields the record, whose ``extra`` the body may fill.
+        The annotation's name stays bare: request ids and the like go in
+        ``extra``, never in the profiler's name."""
+        with jax.profiler.TraceAnnotation(f"serve.{phase}"):
+            st = StepStat(phase, step, 0.0, tokens=tokens, extra=extra,
+                          start=time.monotonic())
+            yield st
+            st.seconds = time.monotonic() - st.start
+        self.stats.append(st)
+
+    def _sync(self, fetch, x):
+        """``fetch(x)``, counted as one host sync: ``fetch`` is
+        ``jax.block_until_ready``, ``jax.device_get``, ``np.asarray`` or
+        ``int`` of a device value.  Syncs inside other modules (the MoE
+        router's slot fetch, the stream pipeline's drain) are not counted."""
+        self._host_syncs += 1
+        return fetch(x)
 
     # ---------------------------------------------------------- resilience --
 
@@ -346,39 +391,35 @@ class _ServeBase:
         drain_s = 0.0
         if not pipelined:
             t_d = time.monotonic()
-            h = jax.block_until_ready(h)
+            h = self._sync(jax.block_until_ready, h)
             drain_s = time.monotonic() - t_d
         busy = pipelined and self._pipe.busy()
-        t0 = time.monotonic()
-        if phase1 is not None:
-            plan, info = moe.plan_from_phase1(phase1, cfg,
-                                              dispatch=self.backend,
-                                              dtype=h.dtype)
-        else:
-            plan, info = moe.route_moe(p_ffn, h, cfg, counts=counts,
-                                       pos=pos, dispatch=self.backend,
-                                       layer=layer)
-        self.stats.append(StepStat(
-            "route", step, time.monotonic() - t0,
-            tokens=h.shape[0] * h.shape[1],
-            extra={**info, "drain_s": drain_s, "pipelined": pipelined,
-                   "hidden_s": info.get("wait_s", 0.0) if busy else 0.0}))
+        tokens = h.shape[0] * h.shape[1]
+        with self._span("route", step, tokens=tokens) as st:
+            if phase1 is not None:
+                plan, info = moe.plan_from_phase1(phase1, cfg,
+                                                  dispatch=self.backend,
+                                                  dtype=h.dtype)
+            else:
+                plan, info = moe.route_moe(p_ffn, h, cfg, counts=counts,
+                                           pos=pos, dispatch=self.backend,
+                                           layer=layer)
+            st.extra.update(
+                info, drain_s=drain_s, pipelined=pipelined,
+                hidden_s=info.get("wait_s", 0.0) if busy else 0.0)
         sig = (plan.capacity, plan.backend, tuple(h.shape),
                None if plan.stream is None
                else (plan.stream.nnzb,) + tuple(plan.stream.shape))
         self._exec_keys.add(sig)
-        t0 = time.monotonic()
-        out, new_counts = moe.execute_moe_jit(p_ffn, h, plan, cfg, layer)
-        out = self._fault("execute", out, step=step)
-        # depth 0: push blocks immediately (the serial execute wall);
-        # depth 1: the execute stays in flight behind the next host route
-        self._pipe.push(plan, out)
-        self.stats.append(StepStat(
-            "execute", step, time.monotonic() - t0,
-            tokens=h.shape[0] * h.shape[1],
-            extra={"nnzb_stream": info.get("nnzb_stream"),
-                   "compile_signatures": len(self._exec_keys),
-                   "dispatch_only": pipelined}))
+        with self._span("execute", step, tokens=tokens,
+                        nnzb_stream=info.get("nnzb_stream"),
+                        compile_signatures=len(self._exec_keys),
+                        dispatch_only=pipelined):
+            out, new_counts = moe.execute_moe_jit(p_ffn, h, plan, cfg, layer)
+            out = self._fault("execute", out, step=step)
+            # depth 0: push blocks immediately (the serial execute wall);
+            # depth 1: the execute stays in flight behind the next host route
+            self._pipe.push(plan, out)
         return out, new_counts
 
     def _phase_summary(self) -> Dict[str, Any]:
@@ -506,8 +547,7 @@ class ServeLoop(_ServeBase):
                          fault_plan=fault_plan, retry=retry,
                          fail_threshold=fail_threshold)
         self.max_seq = max_seq
-        self._decode_fused = jax.jit(
-            lambda p, c, pos, tok: M.decode_step(p, cfg, c, pos, tok))
+        self._decode_fused = _decode_program(cfg)
         self.cache = None
         self.pos: Optional[int] = None
         self.generated: List[jax.Array] = []
@@ -538,28 +578,24 @@ class ServeLoop(_ServeBase):
         bcsr dispatch back to the full ``E*C x T`` grid (the single-phase
         fallback this loop exists to avoid)."""
         self.generated = []
-        t0 = time.monotonic()
-        if self.two_phase:
-            logits, cache, pos = M.prefill_layered(
-                self.params, prompts, self.cfg, max_seq=self.max_seq,
-                embeddings=embeddings, moe_fn=self._moe_two_phase,
-                route_ahead=self.pipeline_depth > 0,
-                kv_quant=self.kv_quant, attn_mask=self.attn_mask)
-        else:
-            with self._dispatch_ctx():
-                logits, cache, pos = M.prefill(self.params, prompts, self.cfg,
-                                               max_seq=self.max_seq,
-                                               embeddings=embeddings,
-                                               kv_quant=self.kv_quant,
-                                               attn_mask=self.attn_mask)
-        logits, cache = jax.block_until_ready((logits, cache))
-        self._pipe.drain()   # prefill executes all completed with logits
-        logits = self._fault("prefill", logits, step=-1)
-        cache = self._fault_cache(cache, step=-1,
-                                  nrows=int(prompts.shape[0]))
-        self.stats.append(StepStat(
-            "prefill", -1, time.monotonic() - t0,
-            tokens=int(np.prod(prompts.shape))))
+        with self._span("prefill", -1, tokens=int(np.prod(prompts.shape))):
+            if self.two_phase:
+                logits, cache, pos = M.prefill_layered(
+                    self.params, prompts, self.cfg, max_seq=self.max_seq,
+                    embeddings=embeddings, moe_fn=self._moe_two_phase,
+                    route_ahead=self.pipeline_depth > 0,
+                    kv_quant=self.kv_quant, attn_mask=self.attn_mask)
+            else:
+                with self._dispatch_ctx():
+                    logits, cache, pos = M.prefill(
+                        self.params, prompts, self.cfg, max_seq=self.max_seq,
+                        embeddings=embeddings, kv_quant=self.kv_quant,
+                        attn_mask=self.attn_mask)
+            logits, cache = jax.block_until_ready((logits, cache))
+            self._pipe.drain()   # prefill executes all completed with logits
+            logits = self._fault("prefill", logits, step=-1)
+            cache = self._fault_cache(cache, step=-1,
+                                      nrows=int(prompts.shape[0]))
         self.cache, self.pos = cache, int(pos)
         self._health_dev = jnp.all(
             jnp.isfinite(logits[:, -1, : self.cfg.vocab_size]), axis=-1)
@@ -649,11 +685,9 @@ class ServeLoop(_ServeBase):
         if self.pipeline_depth > 0 and self.generated:
             # the one host sync of the pipelined decode phase: drain the
             # whole dispatched chain (tokens + cache + in-flight executes)
-            t0 = time.monotonic()
-            jax.block_until_ready((self.generated[-1], self.cache))
-            self._pipe.drain()
-            self.stats.append(StepStat("drain", len(self.generated) - 2,
-                                       time.monotonic() - t0))
+            with self._span("drain", len(self.generated) - 2):
+                jax.block_until_ready((self.generated[-1], self.cache))
+                self._pipe.drain()
 
     # -------------------------------------------------------------- drive --
 
@@ -836,8 +870,7 @@ class ServeScheduler(_ServeBase):
         self._stat_step = -1
         self._next_uid = 0
         self.batch_buckets: set = set()
-        self._decode_fused = jax.jit(
-            lambda p, c, pos, tok: M.decode_step(p, cfg, c, pos, tok))
+        self._decode_fused = _decode_program(cfg)
 
     # -------------------------------------------------------------- admit --
 
@@ -894,8 +927,9 @@ class ServeScheduler(_ServeBase):
         lg = logits_row[: self.cfg.vocab_size]
         if self.temperature > 0:
             req.key, k = jax.random.split(req.key)
-            return int(jax.random.categorical(k, lg / self.temperature))
-        return int(jnp.argmax(lg))
+            return self._sync(int, jax.random.categorical(
+                k, lg / self.temperature))
+        return self._sync(int, jnp.argmax(lg))
 
     def _finish_or_keep(self, req: Request, tok: int):
         if len(req.tokens) >= req.max_new_tokens or (
@@ -1001,33 +1035,35 @@ class ServeScheduler(_ServeBase):
         self._stat_step = -1
         self._row_uids = [req.uid]
         prompts = jnp.asarray(req.prompt[None, :])
-        t0 = time.monotonic()
-        try:
-            if self.two_phase:
-                logits, cache1, pos = M.prefill_layered(
-                    self.params, prompts, self.cfg, max_seq=self.max_seq,
-                    cache_dtype=self.cache_dtype, moe_fn=self._moe_two_phase,
-                    route_ahead=self.pipeline_depth > 0,
-                    kv_quant=self.kv_quant, attn_mask=self.attn_mask)
-            else:
-                with self._dispatch_ctx():
-                    logits, cache1, pos = M.prefill(
+        with self._span("prefill", self.step_idx, tokens=req.prompt_len,
+                        uid=req.uid, slot=slot) as st:
+            try:
+                if self.two_phase:
+                    logits, cache1, pos = M.prefill_layered(
                         self.params, prompts, self.cfg, max_seq=self.max_seq,
-                        cache_dtype=self.cache_dtype, kv_quant=self.kv_quant,
-                        attn_mask=self.attn_mask)
-            logits, cache1 = jax.block_until_ready((logits, cache1))
-            self._pipe.drain()  # prefill executes all completed with logits
-            logits = self._fault("prefill", logits)
-        finally:
-            self._row_uids = None
-        dt = time.monotonic() - t0
-        self.stats.append(StepStat("prefill", self.step_idx, dt,
-                                   tokens=req.prompt_len,
-                                   extra={"uid": req.uid, "slot": slot}))
+                        cache_dtype=self.cache_dtype,
+                        moe_fn=self._moe_two_phase,
+                        route_ahead=self.pipeline_depth > 0,
+                        kv_quant=self.kv_quant, attn_mask=self.attn_mask)
+                else:
+                    with self._dispatch_ctx():
+                        logits, cache1, pos = M.prefill(
+                            self.params, prompts, self.cfg,
+                            max_seq=self.max_seq,
+                            cache_dtype=self.cache_dtype,
+                            kv_quant=self.kv_quant, attn_mask=self.attn_mask)
+                logits, cache1 = self._sync(jax.block_until_ready,
+                                            (logits, cache1))
+                self._pipe.drain()  # prefill executes completed with logits
+                logits = self._fault("prefill", logits)
+            finally:
+                self._row_uids = None
+        dt = st.seconds
         # the poison gate, BEFORE the scatter and before any key split:
         # a failed attempt leaves shared + per-request state untouched.
         # prefill already syncs, so this (vocab,) fetch adds no sync point.
-        last_row = np.asarray(logits[0, -1, : self.cfg.vocab_size])
+        last_row = self._sync(np.asarray,
+                              logits[0, -1, : self.cfg.vocab_size])
         if not np.isfinite(last_row).all():
             return False
         # one scatter per cache leaf: row `slot` becomes this request, every
@@ -1036,7 +1072,7 @@ class ServeScheduler(_ServeBase):
             lambda big, small: big.at[:, slot].set(
                 small[:, 0].astype(big.dtype)),
             self.cache, cache1)
-        req.slot, req.pos = slot, int(pos)
+        req.slot, req.pos = slot, self._sync(int, pos)
         req.state = "active"
         self.slots[slot] = req
         # quantize-stage faults corrupt the freshly scattered row's scale
@@ -1057,10 +1093,11 @@ class ServeScheduler(_ServeBase):
         A request whose prefill exhausts its retries is FAILED and the
         slot offered to the next queued request."""
         joined = []
-        while self.queue and None in self.slots:
-            req = self.queue.popleft()
-            if self._prefill_into(req, self.slots.index(None)):
-                joined.append(req)
+        with self._span("admit", self.step_idx):
+            while self.queue and None in self.slots:
+                req = self.queue.popleft()
+                if self._prefill_into(req, self.slots.index(None)):
+                    joined.append(req)
         return joined
 
     # ------------------------------------------------------------- decode --
@@ -1139,7 +1176,45 @@ class ServeScheduler(_ServeBase):
         self._row_uids = [r.uid if r is not None else None
                           for r in self.slots[:bucket]]
         pipelined = self.pipeline_depth > 0
-        t0 = time.monotonic()
+        with self._span("decode", self.step_idx, tokens=len(active),
+                        batch_bucket=bucket, active=len(active),
+                        pipelined=pipelined) as st:
+            logits, new_cache, toks, fin = self._decode_forward(
+                step_cache, pos_vec, tok_vec, bucket, pipelined)
+        dt = st.seconds
+        with self._span("writeback", self.step_idx):
+            self.cache = jax.tree.map(
+                lambda big, small: big.at[:, :bucket].set(
+                    small.astype(big.dtype)),
+                self.cache, new_cache)
+        emitted = []
+        with self._span("sample", self.step_idx):
+            for i, r in enumerate(self.slots[:bucket]):
+                if r is None:
+                    continue   # vacant bucket row: computed, masked out here
+                if not fin[i]:
+                    # poisoned row: fail + evict + blank THIS request only;
+                    # survivors' rows were computed row-independently and
+                    # are committed above bit-identically to a fault-free
+                    # step
+                    self._fail(r, f"poisoned:step{self.step_idx}",
+                               poisoned=True)
+                    continue
+                tok = (int(toks[i]) if toks is not None
+                       else self._sample_one(logits[i, -1], r))
+                r.tokens.append(tok)
+                r.latencies_s.append(dt)
+                r.pos += 1
+                emitted.append((r, tok))
+                self._finish_or_keep(r, tok)
+        return emitted
+
+    def _decode_forward(self, step_cache, pos_vec, tok_vec, bucket: int,
+                        pipelined: bool):
+        """The step's forward through the health fetch: returns ``(logits,
+        new_cache, toks, fin)``, ``toks`` the sampled ids at depth 1 (None
+        at depth 0, where the rows are sampled on host) and ``fin`` the
+        per-row isfinite bits, both on the host."""
         try:
             if self.two_phase:
                 logits, new_cache = M.decode_step_layered(
@@ -1179,49 +1254,27 @@ class ServeScheduler(_ServeBase):
             toks_dev, fin_dev = _sampler_health_jit(
                 self.cfg.vocab_size, float(self.temperature), True)(
                     logits[:, -1], key_arr)
-            toks, fin = jax.device_get((toks_dev, fin_dev))
+            toks, fin = self._sync(jax.device_get, (toks_dev, fin_dev))
             toks, fin = np.asarray(toks), np.asarray(fin)
         else:
-            logits = jax.block_until_ready(logits)
-            fin = np.asarray(jnp.all(jnp.isfinite(
+            logits = self._sync(jax.block_until_ready, logits)
+            fin = self._sync(np.asarray, jnp.all(jnp.isfinite(
                 logits[:, -1, : self.cfg.vocab_size]), axis=-1))
-        dt = time.monotonic() - t0
-        self.stats.append(StepStat(
-            "decode", self.step_idx, dt, tokens=len(active),
-            extra={"batch_bucket": bucket, "active": len(active),
-                   "pipelined": pipelined}))
-        self.cache = jax.tree.map(
-            lambda big, small: big.at[:, :bucket].set(
-                small.astype(big.dtype)),
-            self.cache, new_cache)
-        emitted = []
-        for i, r in enumerate(self.slots[:bucket]):
-            if r is None:
-                continue   # vacant bucket row: computed, masked out here
-            if not fin[i]:
-                # poisoned row: fail + evict + blank THIS request only;
-                # survivors' rows were computed row-independently and are
-                # committed above bit-identically to a fault-free step
-                self._fail(r, f"poisoned:step{self.step_idx}",
-                           poisoned=True)
-                continue
-            tok = (int(toks[i]) if toks is not None
-                   else self._sample_one(logits[i, -1], r))
-            r.tokens.append(tok)
-            r.latencies_s.append(dt)
-            r.pos += 1
-            emitted.append((r, tok))
-            self._finish_or_keep(r, tok)
-        return emitted
+        return logits, new_cache, toks, fin
 
     # -------------------------------------------------------------- drive --
 
     def step(self) -> List[Tuple[Request, int]]:
         """One scheduler tick: enforce deadlines, admit into freed slots,
-        then decode one token for every resident sequence."""
-        self._shed_expired(self._clock())
-        self.admit()
-        out = self.decode_step()
+        then decode one token for every resident sequence.  The tick's
+        ``step`` record carries ``extra["host_syncs"]``, the host syncs
+        made inside it."""
+        syncs0 = self._host_syncs
+        with self._span("step", self.step_idx) as st:
+            self._shed_expired(self._clock())
+            self.admit()
+            out = self.decode_step()
+            st.extra["host_syncs"] = self._host_syncs - syncs0
         self.step_idx += 1
         return out
 

@@ -1,0 +1,160 @@
+"""ServeScheduler's spans and host-sync counter.
+
+Every scheduler tick is one ``step`` record (a :class:`StepStat` and a
+``serve.step`` profiler annotation) holding, in order, ``admit`` (with a
+``prefill`` per admission), ``decode`` (the forward through the health
+fetch), ``writeback`` (the KV-cache write-back) and ``sample`` (the per-row
+token loop).  The ``step`` record counts the host syncs made inside it.
+Tier-1, tiny config on the CPU.
+"""
+import glob
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.models.config import ArchConfig
+from repro.models import model as M
+from repro.launch.serve import ServeLoop, ServeScheduler
+
+TINY = ArchConfig(
+    name="tiny-spans", family="dense", d_model=32, n_heads=2, n_kv_heads=1,
+    d_ff=48, vocab_size=64, block_unit=("attn",), n_repeats=2, head_dim=16,
+    policy="f32")
+MAX_SEQ = 24
+# per admission: the prefill's block, the poison gate's fetch, the position,
+# the first token
+SYNCS_PER_ADMISSION = 4
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    params = M.init_params(jax.random.PRNGKey(0), TINY)
+    rng = np.random.default_rng(1)
+    reqs = [(rng.integers(0, TINY.vocab_size, int(rng.integers(4, 9))),
+             int(rng.integers(3, 7))) for _ in range(4)]
+    return params, reqs
+
+
+def _serve(params, reqs, depth, slots=2):
+    sched = ServeScheduler(params, TINY, max_seq=MAX_SEQ, max_slots=slots,
+                           dispatch="gather", pipeline_depth=depth)
+    for prompt, gen in reqs:
+        sched.submit(prompt, gen)
+    out = sched.run()
+    return sched, out
+
+
+def _ticks(stats):
+    """{tick: (its step record, its other records in append order)}."""
+    steps = {s.step: s for s in stats if s.phase == "step"}
+    kids = {k: [s for s in stats if s.step == k and s.phase != "step"]
+            for k in steps}
+    return {k: (steps[k], kids[k]) for k in steps}
+
+
+def _inside(inner, outer):
+    return (outer.start <= inner.start
+            and inner.start + inner.seconds <= outer.start + outer.seconds)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_each_tick_nests_its_phases_in_order(tiny, depth):
+    params, reqs = tiny
+    sched, _ = _serve(params, reqs, depth)
+    ticks = _ticks(sched.stats)
+    assert sorted(ticks) == list(range(sched.step_idx))
+    assert sum(s.phase == "step" for s in sched.stats) == sched.step_idx
+    admitted = 0
+    for k, (step, kids) in ticks.items():
+        assert all(_inside(s, step) for s in kids), k
+        phases = [s.phase for s in kids]
+        # prefills close before the admit that holds them
+        assert phases[-4:] == ["admit", "decode", "writeback", "sample"], (
+            k, phases)
+        assert set(phases[:-4]) <= {"prefill"}
+        admit = kids[-4]
+        assert all(_inside(s, admit) for s in kids[:-4])
+        admitted += len(kids) - 4
+        starts = [s.start for s in kids[-4:]]
+        assert starts == sorted(starts)
+        for a, b in zip(kids[-4:], kids[-3:]):
+            assert a.start + a.seconds <= b.start      # one after another
+    assert admitted == len(reqs)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_host_syncs_per_tick(tiny, depth):
+    params, reqs = tiny
+    sched, _ = _serve(params, reqs, depth)
+    for k, (step, kids) in _ticks(sched.stats).items():
+        decode = next(s for s in kids if s.phase == "decode")
+        per_decode = 2 + decode.extra["active"] if depth == 0 else 1
+        prefills = sum(s.phase == "prefill" for s in kids)
+        assert step.extra["host_syncs"] == (
+            per_decode + SYNCS_PER_ADMISSION * prefills), (k, kids)
+    assert sched._host_syncs == sum(
+        s.extra["host_syncs"] for s in sched.stats if s.phase == "step")
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_tokens_unchanged(tiny, depth):
+    """Spans and counting change no token: each request matches a
+    sequential single-request ServeLoop."""
+    params, reqs = tiny
+    _, out = _serve(params, reqs, depth)
+    for uid, (prompt, gen) in enumerate(reqs):
+        loop = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="gather")
+        want = loop.run(jnp.asarray(prompt[None, :], jnp.int32), gen)[0]
+        np.testing.assert_array_equal(out[uid], want)
+
+
+def test_decode_record_ends_at_the_health_fetch(tiny):
+    """``decode`` keeps its meaning (forward through the health fetch):
+    it is what ``summary()`` and each token's latency read."""
+    params, reqs = tiny
+    sched, _ = _serve(params, reqs, 0)
+    decodes = [s for s in sched.stats if s.phase == "decode"]
+    s = sched.summary()
+    assert s["decode"]["calls"] == len(decodes)
+    assert s["decode"]["seconds"] == pytest.approx(
+        sum(d.seconds for d in decodes))
+    by_step = {d.step: d.seconds for d in decodes}
+    req = next(r for r in sched.finished if r.uid == 0)   # from tick 0
+    assert req.latencies_s[1:] == [
+        by_step[k] for k in sorted(by_step)][:len(req.latencies_s) - 1]
+
+
+def test_a_failed_span_records_nothing(tiny):
+    params, _ = tiny
+    sched = ServeScheduler(params, TINY, max_seq=MAX_SEQ, max_slots=2,
+                           dispatch="gather")
+    with pytest.raises(ValueError):
+        with sched._span("decode", 0):
+            raise ValueError("boom")
+    assert sched.stats == []
+
+
+def test_profiler_sees_bare_span_names(tiny, tmp_path):
+    from jax.profiler import ProfileData
+    params, reqs = tiny
+    sched = ServeScheduler(params, TINY, max_seq=MAX_SEQ, max_slots=2,
+                           dispatch="gather")
+    for prompt, gen in reqs[:2]:
+        sched.submit(prompt, gen)
+    sched.step()                          # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        sched.step()
+        sched.step()
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(files) == 1
+    names = [e.name for plane in ProfileData.from_file(files[0]).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    assert names.count("serve.step") == 2
+    assert {"serve.step", "serve.admit", "serve.decode", "serve.writeback",
+            "serve.sample"} <= set(names)
+    assert all(n.count(".") == 1 and "#" not in n for n in names), names
