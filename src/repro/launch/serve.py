@@ -38,30 +38,34 @@ Two drivers share one phase machinery (:class:`_ServeBase`):
 
 Both drivers take a ``pipeline_depth`` knob (default 0):
 
-* ``pipeline_depth=0`` -- fully serial, the pre-pipelining behavior
-  bit-for-bit: every phase blocks on device results
-  (``jax.block_until_ready``) before reading the clock and *drains* pending
-  device work before starting a phase clock, so queued compute from the
-  previous phase is never misattributed.
+* ``pipeline_depth=0`` -- fully serial MoE: every route/execute phase
+  blocks on device results (``jax.block_until_ready``) before reading the
+  clock and *drains* pending device work before starting a phase clock, so
+  queued compute from the previous phase is never misattributed.
+  ``ServeLoop`` also samples eagerly on host after blocking on the logits.
 * ``pipeline_depth=1`` -- the pipelined hot path: each attn+moe layer's
   route phase 1 is fused into its jitted attention step (dispatched one
   program ahead; only the small slot stream is fetched to host, never the
   hidden state), the compiled execute phase stays *in flight* on the device
   behind the next layer's host route work (``engine.StreamPipeline``, the
   serving-loop analogue of the kernels' double-buffered K-tiles), and
-  sampling runs on device so the only per-step host sync left is the token
-  fetch (``ServeScheduler``) or nothing at all until the final drain
-  (``ServeLoop``).  Generated tokens are bit-identical to depth 0
+  ``ServeLoop`` samples on device, so it syncs nothing until the final
+  drain.  Generated tokens are bit-identical to depth 0
   (tests/test_serve_pipeline.py); ``summary()["timing"]`` reports how much
   route time the overlap actually hid (``route_hidden_frac``).
+
+``ServeScheduler`` samples its decode ticks on the device at both depths:
+one compiled sampler over the step's logits, and one fetch of the token
+ids and health bits per tick.  For it ``pipeline_depth`` governs only the
+MoE route/execute pipelining.
 
 **Resilience** (``runtime.resilience``, tests/README.md "Resilience
 contract"): both drivers take a deterministic ``fault_plan`` whose staged
 hooks (prefill / route / execute / attention / sample / quantize) poison
 rows, corrupt quant scales, raise, or straggle on demand.  The scheduler
 isolates failures per request: cheap on-device ``isfinite`` health bits
-piggyback on the existing per-step token fetch (zero NEW host syncs at
-depth 1), a poisoned row is moved to a FAILED state, its cache row
+piggyback on the existing per-step token fetch (zero NEW host syncs), a
+poisoned row is moved to a FAILED state, its cache row
 scatter-blanked (``model.blank_cache_row``) and its slot refilled --
 co-batched survivors' tokens stay bit-identical to a fault-free run
 (per-row independence, the same law behind the batch-bucket contract).
@@ -70,16 +74,18 @@ Failed prefills and decode steps retry under a bounded exponential-backoff
 retry reproduces the fault-free step exactly); requests carry optional
 TTFT/total deadlines and the admission queue is bounded with an explicit
 shed policy.  Accumulated failures walk a ``DegradationLadder`` (quantized
-KV -> wide, sparse mask -> ref, pipeline depth 1 -> 0); everything is
-surfaced in ``summary()["health"]``.
+KV -> wide, sparse mask -> ref, pipeline depth 1 -> 0; the serial rung
+keeps the scheduler's device sampling); everything is surfaced in
+``summary()["health"]``.
 
 **Spans**: each timed phase is one :class:`StepStat` in ``stats`` and one
 ``serve.<phase>`` profiler annotation, opened and closed at the same two
 points (``_ServeBase._span``), so a device trace shows what the host was
 doing in each gap.  A scheduler tick is ``step``, holding ``admit`` (a
 ``prefill`` per admission), ``decode`` (the forward through the health
-fetch), ``writeback`` (the KV-cache write-back) and ``sample`` (the per-row
-token loop); ``step`` carries the tick's host-sync count.
+fetch, the device sampler inside it), ``writeback`` (the KV-cache
+write-back) and ``sample`` (the per-row host bookkeeping: append, position,
+evict, fail); ``step`` carries the tick's host-sync count.
 
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b --smoke \
@@ -187,20 +193,23 @@ def _sampler_jit(vocab: int, temperature: float, per_row_keys: bool):
 
 @functools.lru_cache(maxsize=None)
 def _sampler_health_jit(vocab: int, temperature: float, per_row_keys: bool):
-    """:func:`_sampler_jit` + per-row health bits, one compiled program.
+    """:func:`_sampler_jit` + per-row health bits, one compiled program,
+    over a decode step's whole ``(B, S, V)`` logits: the last position is
+    sliced inside the program (the device trace shows ``jit_sample_step``).
 
     Returns ``(tokens, finite)`` where ``finite[b]`` is the
     ``all(isfinite)`` reduction of row ``b``'s vocab slice -- the poison
     detector.  The scheduler fetches both in the SAME ``jax.device_get``
     it already spends on the token ids, so per-request isolation costs
-    zero additional host syncs at ``pipeline_depth=1``; token bits are
-    untouched (the sampler body is shared verbatim)."""
+    zero additional host syncs; token bits are untouched (the sampler body
+    is shared verbatim).  Greedy takes ``None`` for the key operand."""
     body = _sampler_body(vocab, temperature, per_row_keys)
 
-    def fn(logits, key):
-        fin = jnp.all(jnp.isfinite(logits[:, :vocab]), axis=-1)
-        return body(logits, key), fin
-    return jax.jit(fn)
+    def sample_step(logits, key):
+        last = logits[:, -1]
+        fin = jnp.all(jnp.isfinite(last[:, :vocab]), axis=-1)
+        return body(last, key), fin
+    return jax.jit(sample_step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -820,6 +829,13 @@ class ServeScheduler(_ServeBase):
     tokens do not depend on batch composition; at temperature 0 the
     generated tokens are token-identical to a sequential single-request
     :class:`ServeLoop` with the same ``max_seq``.
+
+    **Sampling.**  Every decode tick samples on the device at every
+    ``pipeline_depth``: one compiled program (``_sampler_health_jit``) over
+    the step's logits, and one ``jax.device_get`` of the token ids and the
+    per-row health bits; ``pipeline_depth`` selects only the MoE
+    route/execute pipelining.  An admission's first token is still sampled
+    on host (``_sample_one``).
     """
 
     def __init__(self, params, cfg, *, max_seq: int, max_slots: int = 8,
@@ -1179,7 +1195,7 @@ class ServeScheduler(_ServeBase):
         with self._span("decode", self.step_idx, tokens=len(active),
                         batch_bucket=bucket, active=len(active),
                         pipelined=pipelined) as st:
-            logits, new_cache, toks, fin = self._decode_forward(
+            new_cache, toks, fin = self._decode_forward(
                 step_cache, pos_vec, tok_vec, bucket, pipelined)
         dt = st.seconds
         with self._span("writeback", self.step_idx):
@@ -1200,8 +1216,7 @@ class ServeScheduler(_ServeBase):
                     self._fail(r, f"poisoned:step{self.step_idx}",
                                poisoned=True)
                     continue
-                tok = (int(toks[i]) if toks is not None
-                       else self._sample_one(logits[i, -1], r))
+                tok = int(toks[i])
                 r.tokens.append(tok)
                 r.latencies_s.append(dt)
                 r.pos += 1
@@ -1211,10 +1226,10 @@ class ServeScheduler(_ServeBase):
 
     def _decode_forward(self, step_cache, pos_vec, tok_vec, bucket: int,
                         pipelined: bool):
-        """The step's forward through the health fetch: returns ``(logits,
-        new_cache, toks, fin)``, ``toks`` the sampled ids at depth 1 (None
-        at depth 0, where the rows are sampled on host) and ``fin`` the
-        per-row isfinite bits, both on the host."""
+        """The step's forward through the health fetch: returns
+        ``(new_cache, toks, fin)``, ``toks`` the sampled ids and ``fin``
+        the per-row isfinite bits, both on the host.  ``pipelined`` only
+        selects the MoE route/execute pipelining of the layered path."""
         try:
             if self.two_phase:
                 logits, new_cache = M.decode_step_layered(
@@ -1231,36 +1246,26 @@ class ServeScheduler(_ServeBase):
             logits = self._fault("sample", logits, step=self.step_idx)
         finally:
             self._row_uids = None
-        toks = None
-        if pipelined:
-            # sample on device (per-request key chains advance on host,
-            # exactly as _sample_one's) and fetch the (bucket,) token ids
-            # PLUS the per-row isfinite health bits in the single
-            # device_get the scheduler already cannot shed: EOS / eviction
-            # decisions need the values.  Zero additional host syncs.
-            if self.temperature > 0:
-                keys, dummy = [], None
-                for r in self.slots[:bucket]:
-                    if r is not None:
-                        r.key, k = jax.random.split(r.key)
-                        keys.append(k)
-                    else:   # vacant row: sampled then masked; any key works
-                        if dummy is None:
-                            dummy = jnp.zeros((2,), jnp.uint32)
-                        keys.append(dummy)
-                key_arr = jnp.stack(keys)
-            else:
-                key_arr = jnp.zeros((bucket, 2), jnp.uint32)
-            toks_dev, fin_dev = _sampler_health_jit(
-                self.cfg.vocab_size, float(self.temperature), True)(
-                    logits[:, -1], key_arr)
-            toks, fin = self._sync(jax.device_get, (toks_dev, fin_dev))
-            toks, fin = np.asarray(toks), np.asarray(fin)
-        else:
-            logits = self._sync(jax.block_until_ready, logits)
-            fin = self._sync(np.asarray, jnp.all(jnp.isfinite(
-                logits[:, -1, : self.cfg.vocab_size]), axis=-1))
-        return logits, new_cache, toks, fin
+        # sample on device (per-request key chains advance on host, exactly
+        # as _sample_one's) and fetch the (bucket,) token ids PLUS the
+        # per-row isfinite health bits in the single device_get the
+        # scheduler cannot shed: EOS / eviction decisions need the values.
+        key_arr = None      # greedy ignores the key operand
+        if self.temperature > 0:
+            keys, dummy = [], None
+            for r in self.slots[:bucket]:
+                if r is not None:
+                    r.key, k = jax.random.split(r.key)
+                    keys.append(k)
+                else:   # vacant row: sampled then masked; any key works
+                    if dummy is None:
+                        dummy = jnp.zeros((2,), jnp.uint32)
+                    keys.append(dummy)
+            key_arr = jnp.stack(keys)
+        toks, fin = self._sync(jax.device_get, _sampler_health_jit(
+            self.cfg.vocab_size, float(self.temperature), True)(
+                logits, key_arr))
+        return new_cache, toks, fin
 
     # -------------------------------------------------------------- drive --
 

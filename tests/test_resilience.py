@@ -10,8 +10,9 @@ The contract under test (see "Resilience contract" in ``tests/README.md``):
   independence of attention, prefix-stable MoE, bcsr dispatch, and
   per-request sampling keys), and host-side failures retry from untouched
   state (faults fire before any key split or cache commit).
-* **Zero new host syncs.**  At depth 1 the health bits ride the existing
-  per-step token fetch: exactly one ``jax.device_get`` per decode step.
+* **Zero new host syncs.**  At both depths the scheduler samples on the
+  device and the health bits ride the per-step token fetch: exactly one
+  ``jax.device_get`` per decode step.
 * **Policy.**  Bounded exponential-backoff retries, TTFT/total deadlines
   on a fake clock, a bounded admission queue with reject / drop-oldest
   shed policies, and the kv_wide -> mask_ref -> pipeline_serial ladder.
@@ -597,6 +598,35 @@ def test_depth1_health_adds_no_syncs(params, prompts, baselines,
     decode_steps = sum(1 for s in sched.stats if s.phase == "decode")
     assert len(fetches) == decode_steps
     _assert_survivors_identical(out, baselines[("bcsr", 1, None)])
+
+
+def test_depth0_samples_on_device_with_one_fetch(params, prompts, baselines,
+                                                monkeypatch):
+    """Depth 0 on the fused gather path samples each decode tick on the
+    device too: one ``jax.device_get`` per decode step, and no eager
+    argmax beyond each admission's first token."""
+    sched = ServeScheduler(
+        params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch="gather",
+        two_phase=False, cache_dtype=jnp.float32, pipeline_depth=0)
+    for p in prompts:
+        sched.submit(p, GEN)
+    fetches, eager_argmax = [], []
+    orig_get, orig_argmax = jax.device_get, jnp.argmax
+
+    def argmax(x, *a, **k):
+        if not isinstance(x, jax.core.Tracer):
+            eager_argmax.append(1)
+        return orig_argmax(x, *a, **k)
+    monkeypatch.setattr(jax, "device_get", lambda x: fetches.append(1)
+                        or orig_get(x))
+    monkeypatch.setattr(jnp, "argmax", argmax)
+    out = sched.run()
+    decode_steps = sum(1 for s in sched.stats if s.phase == "decode")
+    prefills = sum(1 for s in sched.stats if s.phase == "prefill")
+    assert decode_steps and prefills == len(prompts)
+    assert len(fetches) == decode_steps
+    assert len(eager_argmax) == prefills
+    _assert_survivors_identical(out, baselines[("gather", 0, None)])
 
 
 # ------------------------------------------------------------- stress -----

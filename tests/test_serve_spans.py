@@ -3,8 +3,9 @@
 Every scheduler tick is one ``step`` record (a :class:`StepStat` and a
 ``serve.step`` profiler annotation) holding, in order, ``admit`` (with a
 ``prefill`` per admission), ``decode`` (the forward through the health
-fetch), ``writeback`` (the KV-cache write-back) and ``sample`` (the per-row
-token loop).  The ``step`` record counts the host syncs made inside it.
+fetch, the device sampler inside it), ``writeback`` (the KV-cache
+write-back) and ``sample`` (the per-row host bookkeeping).  The ``step``
+record counts the host syncs made inside it.
 Tier-1, tiny config on the CPU.
 """
 import glob
@@ -91,10 +92,11 @@ def test_host_syncs_per_tick(tiny, depth):
     sched, _ = _serve(params, reqs, depth)
     for k, (step, kids) in _ticks(sched.stats).items():
         decode = next(s for s in kids if s.phase == "decode")
-        per_decode = 2 + decode.extra["active"] if depth == 0 else 1
+        # one fetch of the device-sampled ids and health bits, any depth
+        assert decode.extra["active"] >= 1
         prefills = sum(s.phase == "prefill" for s in kids)
         assert step.extra["host_syncs"] == (
-            per_decode + SYNCS_PER_ADMISSION * prefills), (k, kids)
+            1 + SYNCS_PER_ADMISSION * prefills), (k, kids)
     assert sched._host_syncs == sum(
         s.extra["host_syncs"] for s in sched.stats if s.phase == "step")
 
