@@ -19,6 +19,7 @@ ARCH_NAMES = [
     "zamba2-1.2b",
     "musicgen-large",
     "rwkv6-7b",
+    "qwen3-next-80b-a3b",
 ]
 
 _MODULES = {n: "repro.configs." + n.replace("-", "_").replace(".", "_")

@@ -83,9 +83,13 @@ keeps the scheduler's device sampling); everything is surfaced in
 points (``_ServeBase._span``), so a device trace shows what the host was
 doing in each gap.  A scheduler tick is ``step``, holding ``admit`` (a
 ``prefill`` per admission), ``decode`` (the forward through the health
-fetch, the device sampler inside it), ``writeback`` (the KV-cache
-write-back) and ``sample`` (the per-row host bookkeeping: append, position,
-evict, fail); ``step`` carries the tick's host-sync count.
+fetch, the device sampler inside it), ``writeback`` (the write-back of
+every per-row state the step made: KV cache, and for hybrid stacks the
+Gated DeltaNet conv tail and recurrent state) and ``sample`` (the per-row
+host bookkeeping: append, position, evict, fail); ``step`` carries the
+tick's host-sync count, and ``decode`` of a dropless expert share the
+step's (token, held expert) pairs as ``moe_held_pairs``, fetched with the
+token ids.
 
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b --smoke \
@@ -224,9 +228,13 @@ def _health_accum_jit(vocab: int):
 
 def _decode_program(cfg):
     """The fused one-token decode step, jitted under a stable name (the
-    device trace shows ``jit_decode_step``)."""
+    device trace shows ``jit_decode_step``).  Returns ``(logits, cache,
+    held)``: ``held`` is the step's (token, held expert) pair count
+    (``model.moe_held_pairs``), None where the configuration has no
+    dropless expert share."""
     def decode_step(params, cache, pos, tokens):
-        return M.decode_step(params, cfg, cache, pos, tokens)
+        logits, new_cache = M.decode_step(params, cfg, cache, pos, tokens)
+        return logits, new_cache, M.moe_held_pairs(cfg, new_cache)
     return jax.jit(decode_step)
 
 
@@ -660,7 +668,7 @@ class ServeLoop(_ServeBase):
                 moe_fn=self._moe_two_phase, route_ahead=pipelined)
         else:
             with self._dispatch_ctx():
-                logits, self.cache = self._decode_fused(
+                logits, self.cache, _ = self._decode_fused(
                     self.params, self.cache, jnp.asarray(pos, jnp.int32),
                     tok)
         logits = self._fault("sample", logits, step=step)
@@ -1195,8 +1203,10 @@ class ServeScheduler(_ServeBase):
         with self._span("decode", self.step_idx, tokens=len(active),
                         batch_bucket=bucket, active=len(active),
                         pipelined=pipelined) as st:
-            new_cache, toks, fin = self._decode_forward(
+            new_cache, toks, fin, held = self._decode_forward(
                 step_cache, pos_vec, tok_vec, bucket, pipelined)
+            if held is not None:
+                st.extra["moe_held_pairs"] = int(held)
         dt = st.seconds
         with self._span("writeback", self.step_idx):
             self.cache = jax.tree.map(
@@ -1227,9 +1237,12 @@ class ServeScheduler(_ServeBase):
     def _decode_forward(self, step_cache, pos_vec, tok_vec, bucket: int,
                         pipelined: bool):
         """The step's forward through the health fetch: returns
-        ``(new_cache, toks, fin)``, ``toks`` the sampled ids and ``fin``
-        the per-row isfinite bits, both on the host.  ``pipelined`` only
-        selects the MoE route/execute pipelining of the layered path."""
+        ``(new_cache, toks, fin, held)``, ``toks`` the sampled ids, ``fin``
+        the per-row isfinite bits and ``held`` the step's (token, held
+        expert) pairs (None without a dropless expert share), all on the
+        host.  ``pipelined`` only selects the MoE route/execute pipelining
+        of the layered path."""
+        held = None
         try:
             if self.two_phase:
                 logits, new_cache = M.decode_step_layered(
@@ -1238,7 +1251,7 @@ class ServeScheduler(_ServeBase):
                     route_ahead=pipelined)
             else:
                 with self._dispatch_ctx():
-                    logits, new_cache = self._decode_fused(
+                    logits, new_cache, held = self._decode_fused(
                         self.params, step_cache, jnp.asarray(pos_vec),
                         jnp.asarray(tok_vec))
             # the sample hook fires BEFORE any per-request key split below,
@@ -1262,10 +1275,11 @@ class ServeScheduler(_ServeBase):
                         dummy = jnp.zeros((2,), jnp.uint32)
                     keys.append(dummy)
             key_arr = jnp.stack(keys)
-        toks, fin = self._sync(jax.device_get, _sampler_health_jit(
+        toks, fin = _sampler_health_jit(
             self.cfg.vocab_size, float(self.temperature), True)(
-                logits, key_arr))
-        return new_cache, toks, fin
+                logits, key_arr)
+        toks, fin, held = self._sync(jax.device_get, (toks, fin, held))
+        return new_cache, toks, fin, held
 
     # -------------------------------------------------------------- drive --
 

@@ -39,8 +39,16 @@ def rope_freqs(hd: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, hd); positions: (S,) or broadcastable."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               fraction: float = 1.0) -> jax.Array:
+    """x: (..., S, hd); positions: (S,) or broadcastable.  ``fraction`` < 1
+    rotates only the first ``int(hd * fraction)`` dims of each head (their
+    two halves, frequencies over that width) and passes the rest through."""
+    if fraction != 1.0:
+        rd = int(x.shape[-1] * fraction)
+        return jnp.concatenate(
+            [apply_rope(x[..., :rd], positions, theta),
+             x[..., rd:]], axis=-1)
     hd = x.shape[-1]
     freqs = rope_freqs(hd, theta)                        # (hd/2,)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (S, hd/2)
@@ -125,8 +133,9 @@ def init_attention(key, cfg: ArchConfig):
     d, hd, Hq, Hkv = cfg.d_model, cfg.hd, cfg.n_heads, cfg.n_kv_heads
     k1, k2, k3, k4 = jax.random.split(key, 4)
     s = d ** -0.5
+    q_out = Hq * hd * (2 if cfg.attn_output_gate else 1)
     p = {
-        "wq": jax.random.normal(k1, (d, Hq * hd), jnp.float32) * s,
+        "wq": jax.random.normal(k1, (d, q_out), jnp.float32) * s,
         "wk": jax.random.normal(k2, (d, Hkv * hd), jnp.float32) * s,
         "wv": jax.random.normal(k3, (d, Hkv * hd), jnp.float32) * s,
         "wo": jax.random.normal(k4, (Hq * hd, d), jnp.float32) * s,
@@ -141,7 +150,10 @@ def init_attention(key, cfg: ArchConfig):
     return p
 
 
-def _qkv(p, x, cfg: ArchConfig, positions):
+def _qkv_gate(p, x, cfg: ArchConfig, positions):
+    """(q, k, v, gate): ``gate`` is None, or with ``cfg.attn_output_gate``
+    the (B, S, Hq * hd) pre-sigmoid output gate, which the q projection
+    emits after each head's query ([query | gate] per head)."""
     B, S, d = x.shape
     hd, Hq, Hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     cd = x.dtype
@@ -152,15 +164,19 @@ def _qkv(p, x, cfg: ArchConfig, positions):
         q = q + p["bq"].astype(cd)
         k = k + p["bk"].astype(cd)
         v = v + p["bv"].astype(cd)
+    gate = None
+    if cfg.attn_output_gate:
+        qg = q.reshape(B, S, Hq, 2 * hd)
+        q, gate = qg[..., :hd], qg[..., hd:].reshape(B, S, Hq * hd)
     q = q.reshape(B, S, Hq, hd).transpose(0, 2, 1, 3)
     k = k.reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
     if cfg.qk_norm:
         q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    return q, k, v
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
+    return q, k, v, gate
 
 
 def chunked_attention(q, k, v, *, causal=True, window=None, chunk=1024):
@@ -277,7 +293,7 @@ def apply_attention(p, x, cfg: ArchConfig, *, window=None, positions=None,
     B, S, d = x.shape
     if cache is None:
         positions = positions if positions is not None else jnp.arange(S)
-        q, k, v = _qkv(p, x, cfg, positions)
+        q, k, v, gate = _qkv_gate(p, x, cfg, positions)
         out = None
         if attn_mask is not None:
             out = _masked_prefill_attention(q, k, v, attn_mask, window)
@@ -322,7 +338,7 @@ def apply_attention(p, x, cfg: ArchConfig, *, window=None, positions=None,
         pos = jnp.asarray(cache_len)
         if pos.ndim:  # per-row fill pointers (continuous batching)
             pos = pos.reshape(-1).astype(jnp.int32)
-            q, k1, v1 = _qkv(p, x, cfg, pos[:, None, None])
+            q, k1, v1, gate = _qkv_gate(p, x, cfg, pos[:, None, None])
             b_idx = jnp.arange(B)
             if quant:
                 qk1, sk1 = precision.quantize_rows(k1[:, :, 0], qname)
@@ -338,7 +354,7 @@ def apply_attention(p, x, cfg: ArchConfig, *, window=None, positions=None,
                     v1[:, :, 0].astype(cache["v"].dtype))
         else:
             pos = pos.reshape(())  # scalar fill pointer
-            q, k1, v1 = _qkv(p, x, cfg, jnp.full((1,), pos))
+            q, k1, v1, gate = _qkv_gate(p, x, cfg, jnp.full((1,), pos))
             if quant:
                 qk1, sk1 = precision.quantize_rows(k1, qname)
                 qv1, sv1 = precision.quantize_rows(v1, qname)
@@ -359,6 +375,8 @@ def apply_attention(p, x, cfg: ArchConfig, *, window=None, positions=None,
             out = decode_attention(q, kc, vc, kv_len=pos + 1, window=window)
             new_cache = {"k": kc, "v": vc}
     out = out.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.hd)
+    if gate is not None:
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
     return out @ p["wo"].astype(out.dtype), new_cache
 
 

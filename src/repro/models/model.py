@@ -9,6 +9,9 @@ activation memory.
 Caches: per-slot stacked pytrees; decode scans (params, cache) pairs and
 emits updated cache slices. Attention caches for ``attn_local`` layers are
 ring buffers bounded by the window (what makes gemma-3 long_500k decodable).
+A hybrid stack keeps two kinds of per-row state side by side: ``gdn+moe``
+layers a conv tail and a float32 recurrent state, attention layers a KV
+cache; both are indexed by batch row.
 """
 from __future__ import annotations
 
@@ -22,7 +25,7 @@ from repro.core.masks import NEG_INF, AttnMaskSpec
 from repro.core.precision import policy as precision_policy
 from repro.models.config import ArchConfig
 from repro.models import layers as L
-from repro.models import mamba2, moe, rwkv6
+from repro.models import gdn, mamba2, moe, rwkv6
 
 Params = Dict[str, Any]
 
@@ -42,6 +45,12 @@ def init_block(key, kind: str, cfg: ArchConfig) -> Params:
         else:
             p["ffn"] = L.init_mlp(k2, cfg)
         return p
+    if kind == "gdn+moe":
+        k1, k2 = jax.random.split(key)
+        return {"ln1": L.init_rmsnorm(cfg.d_model),
+                "mixer": gdn.init_gdn(k1, cfg),
+                "ln2": L.init_rmsnorm(cfg.d_model),
+                "ffn": moe.init_moe(k2, cfg)}
     if kind == "mamba":
         return {"ln": L.init_rmsnorm(cfg.d_model),
                 "mixer": mamba2.init_mamba(key, cfg)}
@@ -121,6 +130,21 @@ def apply_block(kind: str, p: Params, x, cfg: ArchConfig, *, impl="chunked",
         if kind == "attn+moe":
             new_cache["moe"] = moe_counts
         return x, new_cache
+    if kind == "gdn+moe":
+        if not cfg.moe_dropless:
+            raise ValueError(f"{cfg.name}: gdn+moe layers route dropless "
+                             "top-k only (top_k > 1)")
+        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        a, new_g = gdn.apply_gdn(p["mixer"], h, cfg,
+                                 cache=cache.get("gdn") if cache else None,
+                                 collect=bool(collect_kv))
+        x = x + a
+        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        f, moe_state = (moe_fn or moe.apply_moe)(p["ffn"], h, cfg, pos=pos)
+        x = x + f
+        if new_g is None:
+            return x, None
+        return x, {"gdn": new_g, "moe": moe_state}
     if kind == "mamba":
         h = L.rmsnorm(p["ln"], x, cfg.norm_eps)
         m, new_c = mamba2.apply_mamba(p["mixer"], h, cfg, cache=cache,
@@ -273,9 +297,10 @@ def loss_fn(params: Params, tokens: jax.Array, cfg: ArchConfig, *,
 
 def _cache_to_dtype(cache, cd, cache_dtype):
     """Convert compute-dtype cache leaves to the decode cache dtype,
-    leaving quantization scale leaves (``k_scale``/``v_scale``) untouched --
-    they are f32 by contract even when the compute dtype is f32."""
-    skip = ("k_scale", "v_scale")
+    leaving quantization scale leaves (``k_scale``/``v_scale``) and the
+    Gated DeltaNet ``state`` untouched -- they are f32 by contract even
+    when the compute dtype is f32."""
+    skip = ("k_scale", "v_scale", "state")
 
     def conv(path, a):
         if path and getattr(path[-1], "key", None) in skip:
@@ -391,13 +416,29 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
                 "shift_t": jnp.zeros((cfg.n_repeats, batch, 1, d), dtype),
                 "shift_c": jnp.zeros((cfg.n_repeats, batch, 1, d), dtype)}
 
+    def gdn_cache():
+        _, Hv, Dk, Dv, C = gdn.dims(cfg)
+        return {"gdn": {
+            "conv": jnp.zeros((cfg.n_repeats, batch, cfg.gdn_conv - 1, C),
+                              dtype),
+            "state": jnp.zeros((cfg.n_repeats, batch, Hv, Dk, Dv),
+                               jnp.float32)}}
+
+    def moe_state():
+        # top-1: per-(row, expert) occupancy counts make decode slot
+        # assignment prefix-stable; dropless: each row's (token, held
+        # expert) pairs of the last call (see models.moe)
+        shp = ((cfg.n_repeats, batch) if cfg.moe_dropless
+               else (cfg.n_repeats, batch, cfg.n_experts))
+        return jnp.zeros(shp, jnp.int32)
+
     def slot_cache(kind, n):
         if kind == "attn+moe":
-            # MoE routing occupancy: per-(row, expert) counts make decode
-            # slot assignment prefix-stable (see models.moe)
             c = attn_cache(None)
-            c["moe"] = jnp.zeros((cfg.n_repeats, batch, cfg.n_experts),
-                                 jnp.int32)
+            c["moe"] = moe_state()
+        elif kind == "gdn+moe":
+            c = gdn_cache()
+            c["moe"] = moe_state()
         elif kind in ("attn", "attn_global", "shared_attn"):
             c = attn_cache(None)
         elif kind == "attn_local":
@@ -437,14 +478,15 @@ def _decode_block_attn(kind, p, x, cfg, cache, pos, dtype, moe_fn=None):
         h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
         if pos_a.ndim:  # per-row ring slots (continuous batching)
             slot = slot.reshape(-1).astype(jnp.int32)
-            q, k1, v1 = L._qkv(p["attn"], h, cfg,
-                               pos_a.reshape(-1)[:, None, None])
+            q, k1, v1, gate = L._qkv_gate(p["attn"], h, cfg,
+                                          pos_a.reshape(-1)[:, None, None])
             b_idx = jnp.arange(x.shape[0])
             knew = kc.at[b_idx, :, slot].set(k1[:, :, 0].astype(kc.dtype))
             vnew = cache["attn"]["v"].at[b_idx, :, slot].set(
                 v1[:, :, 0].astype(kc.dtype))
         else:
-            q, k1, v1 = L._qkv(p["attn"], h, cfg, jnp.full((1,), pos_a))
+            q, k1, v1, gate = L._qkv_gate(p["attn"], h, cfg,
+                                          jnp.full((1,), pos_a))
             knew = jax.lax.dynamic_update_slice_in_dim(
                 kc, k1.astype(kc.dtype), slot, axis=2)
             vnew = jax.lax.dynamic_update_slice_in_dim(
@@ -453,6 +495,8 @@ def _decode_block_attn(kind, p, x, cfg, cache, pos, dtype, moe_fn=None):
         a = decode_attention(q, knew, vnew,
                              kv_len=jnp.minimum(pos_a + 1, window))
         a = a.transpose(0, 2, 1, 3).reshape(x.shape[0], 1, cfg.n_heads * cfg.hd)
+        if gate is not None:
+            a = a * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(a.dtype)
         x = x + a @ p["attn"]["wo"].astype(a.dtype)
         h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
         # ring buffers exist only for attn_local layers, which are never MoE
@@ -536,6 +580,20 @@ def check_cache_fits(cache, pos, *, who: str = "decode_step",
             f"capacity {cap} (max_seq). The cache update would be silently "
             "clamped by XLA, corrupting the last cache slot and generating "
             "garbage tokens; grow max_seq or stop the sequence.")
+
+
+def moe_held_pairs(cfg: ArchConfig, cache) -> Optional[jax.Array]:
+    """The (token, held expert) pairs the call that made ``cache``
+    computed, summed over layers and rows (int32 scalar): what the dropless
+    expert share records in its ``moe`` leaves.  None for configurations
+    routed otherwise."""
+    if not cfg.moe_dropless:
+        return None
+    leaves = [c["moe"] for c in cache["slots"]
+              if isinstance(c, dict) and "moe" in c]
+    if "prologue" in cache and "moe" in cache["prologue"]:
+        leaves.append(cache["prologue"]["moe"])
+    return sum(jnp.sum(a, dtype=jnp.int32) for a in leaves)
 
 
 def decode_step(params: Params, cfg: ArchConfig, cache, pos, tokens_1,

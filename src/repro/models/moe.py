@@ -4,6 +4,19 @@ This is where the paper's technique is first-class in the LM stack: routing
 tokens to experts *is* a sparse-dense product, and the layer is split into
 the two stages that framing implies.
 
+Two routing rules, chosen by ``top_k`` (``cfg.moe_dropless``):
+
+* top-1 with a prefix capacity (below; Llama-4 style), the only rule the
+  two-phase bcsr path serves;
+* dropless top-k (:func:`apply_moe_dropless`; Qwen3-Next style): softmax
+  over all ``n_experts`` router logits in f32, the top ``top_k``, their
+  weights renormalised to sum 1.  A token meets an expert at most once, so
+  a per-(row, expert) queue of S slots never drops.  The layer is an
+  *expert share*: it holds experts ``[expert_offset, expert_offset +
+  experts_held)``, routes over all ``n_experts`` and computes only its own
+  experts' part of the result (the shared expert, which every chip computes
+  alike, is added in full).
+
 **Routing stage** (:func:`route_tokens`) -- prefix-stable by construction.
 The slot of a token in its expert's queue is a pure function of the token's
 own (batch row, position, expert) history: slots are assigned by cumsum
@@ -72,7 +85,9 @@ from repro.models.layers import init_mlp, apply_mlp
 
 
 def init_moe(key, cfg: ArchConfig):
-    d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router (d, n_experts) over every expert; expert weights only for the
+    ``n_held`` held here, at ``ff_expert`` wide."""
+    d, ff, E = cfg.d_model, cfg.ff_expert, cfg.n_held
     k_r, k_e, k_s = jax.random.split(key, 3)
     s = d ** -0.5
     n_w = 3 if cfg.mlp_type == "swiglu" else 2
@@ -88,10 +103,14 @@ def init_moe(key, cfg: ArchConfig):
             "w_up": jax.random.normal(keys[0], (E, d, ff), jnp.float32) * s,
             "w_down": jax.random.normal(keys[1], (E, ff, d), jnp.float32) * (ff ** -0.5),
         }
-    p = {"router": jax.random.normal(k_r, (d, E), jnp.float32) * s,
+    p = {"router": jax.random.normal(k_r, (d, cfg.n_experts),
+                                     jnp.float32) * s,
          "experts": experts}
     if cfg.moe_shared_expert:
-        p["shared"] = init_mlp(k_s, cfg)
+        p["shared"] = init_mlp(k_s, cfg, d_ff=cfg.ff_shared)
+        if cfg.moe_shared_gate:
+            p["shared_gate"] = jax.random.normal(
+                jax.random.fold_in(k_s, 1), (d, 1), jnp.float32) * s
     return p
 
 
@@ -217,7 +236,8 @@ def dispatch_capacity(S: int, cfg: ArchConfig, pos0=0) -> int:
 
 def route_tokens(router: jax.Array, x: jax.Array, cfg: ArchConfig, *,
                  counts: Optional[jax.Array] = None, pos0=0) -> Routing:
-    """Top-1 routing with prefix-stable slot assignment.
+    """Top-1 routing with prefix-stable slot assignment (the capacity
+    rule; top-k configurations route with :func:`route_topk` instead).
 
     x: (B, S, d); ``counts``: (B, E) int32 occupancy carried from previous
     calls on the same rows (None = fresh sequence); ``pos0``: absolute
@@ -231,7 +251,7 @@ def route_tokens(router: jax.Array, x: jax.Array, cfg: ArchConfig, *,
     E = cfg.n_experts
     logits = x.astype(jnp.float32) @ router.astype(jnp.float32)   # (B, S, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate, expert_id = jax.lax.top_k(probs, 1)                     # top-1 per pool spec
+    gate, expert_id = jax.lax.top_k(probs, 1)
     gate, expert_id = gate[..., 0], expert_id[..., 0].astype(jnp.int32)
 
     onehot = jax.nn.one_hot(expert_id, E, dtype=jnp.int32)        # (B, S, E)
@@ -247,6 +267,52 @@ def route_tokens(router: jax.Array, x: jax.Array, cfg: ArchConfig, *,
     keep = slot < (cap if cap.ndim == 2 else cap[None, :])
     new_counts = counts + onehot.sum(axis=1)
     return Routing(gate, expert_id, slot, within, keep, new_counts, logits)
+
+
+def route_topk(router: jax.Array, x: jax.Array, cfg: ArchConfig):
+    """Dropless top-k routing: ``(weights (B, S, k) f32, expert ids (B, S,
+    k) int32)``.  Softmax over all ``n_experts`` logits in f32 (the logits
+    at HIGHEST precision, so near-ties rank as in float32), the top
+    ``top_k``, and the k weights renormalised to sum 1."""
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, ids = jax.lax.top_k(probs, cfg.top_k)
+    return w / jnp.sum(w, axis=-1, keepdims=True), ids.astype(jnp.int32)
+
+
+def apply_moe_dropless(p, x, cfg: ArchConfig):
+    """The expert share of a dropless top-k layer.  x: (B, S, d) ->
+    ((B, S, d), held pairs (B,) int32).
+
+    A token routed to held expert e sits at its own position s in e's
+    queue: the dispatch buffer is (E_held, B, S, d), the token's row where
+    it is routed and zeros elsewhere, so no capacity can drop it.  The
+    output is the held experts' outputs weighted by their renormalised
+    router weights, plus the shared expert (gated by ``sigmoid(x @
+    shared_gate)`` where the configuration has the gate).  The second
+    output counts each row's (token, held expert) pairs: the routed work
+    this share did."""
+    B, S, d = x.shape
+    E, cd = cfg.n_held, x.dtype
+    w, ids = route_topk(p["router"], x, cfg)
+    eq = ids[..., :, None] == (cfg.expert_offset
+                               + jnp.arange(E, dtype=jnp.int32))
+    routed = jnp.any(eq, axis=2)                          # (B, S, E)
+    comb = jnp.sum(jnp.where(eq, w[..., None], 0.0), axis=2)
+    xe = jnp.where(jnp.moveaxis(routed, -1, 0)[..., None], x[None],
+                   jnp.zeros((), cd))                     # (E, B, S, d)
+    ye = _expert_ffn(p["experts"], xe.reshape(E, B * S, d),
+                     cfg.mlp_type).reshape(E, B, S, d)
+    out = jnp.sum(ye.astype(jnp.float32)
+                  * jnp.moveaxis(comb, -1, 0)[..., None], axis=0)
+    if cfg.moe_shared_expert:
+        sh = apply_mlp(p["shared"], x, cfg).astype(jnp.float32)
+        if cfg.moe_shared_gate:
+            sh = sh * jax.nn.sigmoid(
+                (x @ p["shared_gate"].astype(cd)).astype(jnp.float32))
+        out = out + sh
+    return out.astype(cd), jnp.sum(routed, axis=(1, 2), dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------- dispatch --
@@ -460,6 +526,8 @@ def apply_moe(p, x, cfg: ArchConfig, *, counts: Optional[jax.Array] = None,
     from repro.parallel import context as pctx
     from repro.parallel.sharding import constrain
 
+    if cfg.moe_dropless:
+        return apply_moe_dropless(p, x, cfg)
     B, S, d = x.shape
     E = cfg.n_experts
 
@@ -642,6 +710,11 @@ def route_moe(p, x, cfg: ArchConfig, *, counts: Optional[jax.Array] = None,
     from repro.parallel import context as pctx
     from repro.kernels import tuning
 
+    if cfg.moe_dropless:
+        raise ValueError(
+            "route_moe: the two-phase path routes top-1 with a capacity; "
+            f"{cfg.name} routes dropless top-{cfg.top_k} and is served by "
+            "the fused decode (moe.apply_moe_dropless)")
     if isinstance(x, jax.core.Tracer):
         raise TypeError(
             "route_moe is the eager phase of the two-phase serving loop; "

@@ -125,6 +125,19 @@ def test_moe_dispatch_spmm(topo, tpu_rows):
              S((1, sp, n_pad), jnp.bfloat16))
 
 
+def test_gdn_decode(one_chip, tpu_rows):
+    """The Gated DeltaNet decode kernel at qwen3-next-80b-a3b.longgen's
+    shapes: 64 rows, 32 value heads, a (128, 128) float32 state each, with
+    the state aliased in place."""
+    from repro.kernels.gdn import ops as gdn_ops
+    cfg = get_config("qwen3-next-80b-a3b")
+    B, H, D = 64, cfg.gdn_v_heads, cfg.gdn_v_head_dim
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+    _compile(jax.jit(gdn_ops.gdn_decode), S(B, H, D), S(B, H, D),
+             S(B, H, D), S(B, H), S(B, H), S(B, H, D, D))
+
+
 def _flash_args(one_chip):
     s = smoke.FLASH_SHAPE
     S = lambda h: jax.ShapeDtypeStruct((s["B"], h, s["S"], s["hd"]),
