@@ -70,7 +70,8 @@ scatter-blanked (``model.blank_cache_row``) and its slot refilled --
 co-batched survivors' tokens stay bit-identical to a fault-free run
 (per-row independence, the same law behind the batch-bucket contract).
 Failed prefills and decode steps retry under a bounded exponential-backoff
-``RetryPolicy`` (faults fire before any key split or cache write, so a
+``RetryPolicy`` (faults fire before any key split, and a fused decode
+step's retry samples again from the logits its one forward made, so a
 retry reproduces the fault-free step exactly); requests carry optional
 TTFT/total deadlines and the admission queue is bounded with an explicit
 shed policy.  Accumulated failures walk a ``DegradationLadder`` (quantized
@@ -83,9 +84,12 @@ keeps the scheduler's device sampling); everything is surfaced in
 points (``_ServeBase._span``), so a device trace shows what the host was
 doing in each gap.  A scheduler tick is ``step``, holding ``admit`` (a
 ``prefill`` per admission), ``decode`` (the forward through the health
-fetch, the device sampler inside it), ``writeback`` (the write-back of
-every per-row state the step made: KV cache, and for hybrid stacks the
-Gated DeltaNet conv tail and recurrent state) and ``sample`` (the per-row
+fetch, the device sampler inside it), ``writeback`` (the commit of every
+per-row state the step made -- KV cache, and for hybrid stacks the Gated
+DeltaNet conv tail and recurrent state: on the fused path the pool that
+``jit_decode_step`` updated in place becomes the scheduler's, on the
+layered path the rows are scattered back; ``extra["in_place"]`` says
+which) and ``sample`` (the per-row
 host bookkeeping: append, position, evict, fail); ``step`` carries the
 tick's host-sync count, and ``decode`` of a dropless expert share the
 step's (token, held expert) pairs as ``moe_held_pairs``, fetched with the
@@ -236,6 +240,40 @@ def _decode_program(cfg):
         logits, new_cache = M.decode_step(params, cfg, cache, pos, tokens)
         return logits, new_cache, M.moe_held_pairs(cfg, new_cache)
     return jax.jit(decode_step)
+
+
+def _put_rows(pool, rows, start):
+    """``rows`` (a cache of the pool's structure, fewer batch rows) written
+    into ``pool`` at batch row ``start``, each leaf cast to the pool's
+    dtype; every other row is left as it was."""
+    return jax.tree.map(
+        lambda big, small: jax.lax.dynamic_update_slice_in_dim(
+            big, small.astype(big.dtype), start, axis=1),
+        pool, rows)
+
+
+def _decode_inplace_program(cfg):
+    """The scheduler's fused tick as one program that owns the slot pool,
+    under the same stable name ``jit_decode_step``: ``(params, pool, pos,
+    tokens, *, bucket) -> (logits, pool, held)``.  ``pool`` is donated and
+    ``bucket`` static: the step runs on the pool's rows ``[0, bucket)`` and
+    writes the new rows back inside the program, so the returned pool takes
+    the donated one's buffers and rows ``bucket..`` come back bit for bit.
+    Once dispatched, the pool passed in is gone."""
+    def decode_step(params, pool, pos, tokens, bucket):
+        cache = jax.tree.map(lambda a: a[:, :bucket], pool)
+        logits, new_cache = M.decode_step(params, cfg, cache, pos, tokens)
+        return (logits, _put_rows(pool, new_cache, 0),
+                M.moe_held_pairs(cfg, new_cache))
+    return jax.jit(decode_step, static_argnames="bucket",
+                   donate_argnames="pool")
+
+
+@functools.partial(jax.jit, donate_argnames="pool")
+def _commit_row(pool, row_cache, slot):
+    """An admission's write-back: the single-request cache ``row_cache``
+    into row ``slot`` of the donated slot pool."""
+    return _put_rows(pool, row_cache, slot)
 
 
 class _ServeBase:
@@ -844,6 +882,15 @@ class ServeScheduler(_ServeBase):
     per-row health bits; ``pipeline_depth`` selects only the MoE
     route/execute pipelining.  An admission's first token is still sampled
     on host (``_sample_one``).
+
+    **Commit point.**  The slot pool is donated wherever one program owns
+    it.  On the fused path ``jit_decode_step`` takes the whole pool,
+    decodes rows ``[0, bucket)`` and writes them back inside the program;
+    the ``writeback`` span only makes the returned pool ``self.cache``.
+    An admission writes its row with one donated program after the poison
+    gate.  The layered two-phase path slices the pool eagerly and scatters
+    the step's rows back in ``writeback``: its cache flows through
+    per-layer programs with host yields.
     """
 
     def __init__(self, params, cfg, *, max_seq: int, max_slots: int = 8,
@@ -894,7 +941,12 @@ class ServeScheduler(_ServeBase):
         self._stat_step = -1
         self._next_uid = 0
         self.batch_buckets: set = set()
+        # the undonated step, for callers that keep the pool they pass
         self._decode_fused = _decode_program(cfg)
+        self._decode_inplace = _decode_inplace_program(cfg)
+        # (logits, advanced pool or None once committed, held) of a fused
+        # step whose forward ran and whose tick has not finished
+        self._advanced: Optional[Tuple[Any, Any, Any]] = None
 
     # -------------------------------------------------------------- admit --
 
@@ -1090,12 +1142,9 @@ class ServeScheduler(_ServeBase):
                               logits[0, -1, : self.cfg.vocab_size])
         if not np.isfinite(last_row).all():
             return False
-        # one scatter per cache leaf: row `slot` becomes this request, every
-        # other row's state is untouched
-        self.cache = jax.tree.map(
-            lambda big, small: big.at[:, slot].set(
-                small[:, 0].astype(big.dtype)),
-            self.cache, cache1)
+        # one donated program writes every cache leaf's row `slot`: it
+        # becomes this request, every other row's state is untouched
+        self.cache = _commit_row(self.cache, cache1, slot)
         req.slot, req.pos = slot, self._sync(int, pos)
         req.state = "active"
         self.slots[slot] = req
@@ -1136,11 +1185,15 @@ class ServeScheduler(_ServeBase):
 
         Failure handling (the per-request isolation contract,
         tests/test_resilience.py): a retryable host-side exception
-        (``resilience.RETRYABLE``) anywhere in the step aborts the stream
-        pipeline and retries the whole step under
-        the ``RetryPolicy`` -- nothing was committed (no cache write, no
-        key split, no token append happens before the failure can
-        surface), so the retry reproduces the fault-free step exactly.  A
+        (``resilience.RETRYABLE``) in the step aborts the stream pipeline
+        and retries under the ``RetryPolicy``.  No key split or token
+        append happens before the failure can surface.  The layered path
+        retries the whole step: it writes no cache before its write-back.
+        The fused path commits at its forward (the donated pool comes back
+        advanced), so its retry samples again from the logits in hand and
+        never runs the forward twice.  Either way the retry reproduces the
+        fault-free step exactly.  A step that exhausts its retries leaves
+        the advanced pool in ``self.cache`` and raises.  A
         *poisoned* row (non-finite sampled logits, detected by health bits
         piggybacked on the token fetch) fails only ITS request: the row is
         evicted and scatter-blanked, the token discarded, and every
@@ -1159,27 +1212,51 @@ class ServeScheduler(_ServeBase):
                     f"request {r.uid} at write position {r.pos} >= max_seq "
                     f"{self.max_seq}.")
         err: Optional[Exception] = None
-        for attempt in range(self.retry.max_retries + 1):
-            if attempt:
-                self.health.record("retry", stage="decode",
-                                   step=self.step_idx, attempt=attempt)
-                delay = self.retry.delay(attempt - 1)
-                if delay:
-                    self._sleep(delay)
-            try:
-                return self._decode_attempt(active)
-            except R.RETRYABLE as e:
-                self._pipe.abort()
-                err = e
-                self.health.record("decode_error", step=self.step_idx,
-                                   error=type(e).__name__)
-                self._note_failure()
-        raise RuntimeError(
-            f"ServeScheduler.decode_step: step {self.step_idx} failed "
-            f"after {self.retry.max_retries} retries") from err
+        try:
+            for attempt in range(self.retry.max_retries + 1):
+                if attempt:
+                    self.health.record("retry", stage="decode",
+                                       step=self.step_idx, attempt=attempt)
+                    delay = self.retry.delay(attempt - 1)
+                    if delay:
+                        self._sleep(delay)
+                try:
+                    return self._decode_attempt(active)
+                except R.RETRYABLE as e:
+                    self._pipe.abort()
+                    # a degradation rung may rebuild the live cache
+                    self._commit_advanced()
+                    err = e
+                    self.health.record("decode_error", step=self.step_idx,
+                                       error=type(e).__name__)
+                    self._note_failure()
+            raise RuntimeError(
+                f"ServeScheduler.decode_step: step {self.step_idx} failed "
+                f"after {self.retry.max_retries} retries") from err
+        finally:
+            self._commit_advanced()
+            self._advanced = None
+
+    def _commit_advanced(self):
+        """Make the pool a fused step's forward advanced ``self.cache``
+        ahead of its write-back, keeping the step's logits for a retry:
+        once the forward ran, the pool it was given is gone."""
+        if self._advanced is not None and self._advanced[1] is not None:
+            logits, pool, held = self._advanced
+            self.cache, self._advanced = pool, (logits, None, held)
 
     def _decode_attempt(self, active: List[Request]) -> List[Tuple[Request, int]]:
-        """One decode-step try over the occupied slot prefix."""
+        """One decode-step try over the occupied slot prefix.
+
+        The fused path commits at its forward: ``jit_decode_step`` takes
+        the whole slot pool donated and returns it with the step's rows
+        written, held in ``self._advanced`` until the ``writeback`` span
+        makes it ``self.cache`` (at once if the step fails after its
+        forward, so a degradation rung rebuilds the live pool).  A retry of
+        the same step samples again from those logits and never runs the
+        forward twice.  The layered path reads the step's rows eagerly and
+        scatters the new rows back in ``writeback``; it commits nothing
+        before that."""
         hi = max(i for i, r in enumerate(self.slots) if r is not None) + 1
         bucket = engine.batch_bucket(hi, minimum=self.batch_min_bucket,
                                      cap=self.n_slots)
@@ -1190,12 +1267,15 @@ class ServeScheduler(_ServeBase):
             if r is not None:
                 pos_vec[i] = r.pos
                 tok_vec[i, 0] = r.tokens[-1]
-        # quantize-stage faults corrupt live scale rows mid-stream
-        self.cache = self._fault_cache(
-            self.cache, step=self.step_idx,
-            uids=[r.uid if r is not None else None for r in self.slots],
-            nrows=self.n_slots)
-        step_cache = jax.tree.map(lambda a: a[:, :bucket], self.cache)
+        in_place = not self.two_phase
+        if self._advanced is None:
+            # quantize-stage faults corrupt live scale rows mid-stream
+            self.cache = self._fault_cache(
+                self.cache, step=self.step_idx,
+                uids=[r.uid if r is not None else None for r in self.slots],
+                nrows=self.n_slots)
+        step_cache = (self.cache if in_place else
+                      jax.tree.map(lambda a: a[:, :bucket], self.cache))
         self._stat_step = self.step_idx
         self._row_uids = [r.uid if r is not None else None
                           for r in self.slots[:bucket]]
@@ -1208,11 +1288,14 @@ class ServeScheduler(_ServeBase):
             if held is not None:
                 st.extra["moe_held_pairs"] = int(held)
         dt = st.seconds
-        with self._span("writeback", self.step_idx):
-            self.cache = jax.tree.map(
-                lambda big, small: big.at[:, :bucket].set(
-                    small.astype(big.dtype)),
-                self.cache, new_cache)
+        with self._span("writeback", self.step_idx, in_place=in_place):
+            if in_place:
+                self.cache, self._advanced = new_cache, None
+            else:
+                self.cache = jax.tree.map(
+                    lambda big, small: big.at[:, :bucket].set(
+                        small.astype(big.dtype)),
+                    self.cache, new_cache)
         emitted = []
         with self._span("sample", self.step_idx):
             for i, r in enumerate(self.slots[:bucket]):
@@ -1240,8 +1323,11 @@ class ServeScheduler(_ServeBase):
         ``(new_cache, toks, fin, held)``, ``toks`` the sampled ids, ``fin``
         the per-row isfinite bits and ``held`` the step's (token, held
         expert) pairs (None without a dropless expert share), all on the
-        host.  ``pipelined`` only selects the MoE route/execute pipelining
-        of the layered path."""
+        host.  ``new_cache`` is the step's rows on the layered path and the
+        whole advanced pool on the fused one, where ``step_cache`` is the
+        pool to donate (unused when this step's forward already ran).
+        ``pipelined`` only selects the MoE route/execute pipelining of the
+        layered path."""
         held = None
         try:
             if self.two_phase:
@@ -1250,10 +1336,14 @@ class ServeScheduler(_ServeBase):
                     jnp.asarray(tok_vec), moe_fn=self._moe_two_phase,
                     route_ahead=pipelined)
             else:
-                with self._dispatch_ctx():
-                    logits, new_cache, held = self._decode_fused(
-                        self.params, step_cache, jnp.asarray(pos_vec),
-                        jnp.asarray(tok_vec))
+                if self._advanced is None:
+                    with self._dispatch_ctx():
+                        self._advanced = self._decode_inplace(
+                            self.params, step_cache, jnp.asarray(pos_vec),
+                            jnp.asarray(tok_vec), bucket=bucket)
+                logits, new_cache, held = self._advanced
+                if new_cache is None:    # committed when the retry began
+                    new_cache = self.cache
             # the sample hook fires BEFORE any per-request key split below,
             # so a sample-stage exception retries with key chains intact
             logits = self._fault("sample", logits, step=self.step_idx)

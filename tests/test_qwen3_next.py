@@ -23,6 +23,7 @@ from repro.launch.serve import ServeScheduler
 from repro.models import model as M
 from repro.models import moe
 from repro.models import reference_qwen3_next as ref
+from repro.runtime import resilience as R
 
 CFG = dataclasses.replace(get_smoke("qwen3-next-80b-a3b"), policy="f32")
 MAX_SEQ = 24
@@ -182,3 +183,32 @@ def test_scheduler_evicts_and_admits_both_state_kinds(params):
     admits = {s.step for s in sched.stats if s.phase == "prefill"}
     assert all(t.extra["host_syncs"] == 1 for t in ticks
                if t.step not in admits)
+
+
+def test_sample_fault_retries_without_a_second_forward(params):
+    """A sample-stage exception fires after the tick's donated forward has
+    advanced every row's recurrent state and held-pair leaf.  The retry
+    samples again from the logits in hand, so the tokens and the pool left
+    behind equal a fault-free run's; a second forward would advance the
+    Gated DeltaNet state twice."""
+    rng = np.random.default_rng(5)
+    reqs = [(rng.integers(0, CFG.vocab_size, n), 6) for n in (7, 5)]
+
+    def serve(plan):
+        s = ServeScheduler(params, CFG, max_seq=MAX_SEQ, max_slots=2,
+                           cache_dtype=jnp.float32, fault_plan=plan)
+        for prompt, gen in reqs:
+            s.submit(prompt, gen)
+        return s, s.run()
+
+    base_s, base = serve(None)
+    plan = R.FaultPlan.single("sample", "exception", step=2)
+    sched, got = serve(plan)
+    assert plan.triggered and not sched.failed
+    assert sched.health.counters["retry"] == 1
+    assert sorted(got) == sorted(base) == [0, 1]
+    for uid, toks in base.items():
+        np.testing.assert_array_equal(got[uid], toks)
+    for a, b in zip(jax.tree.leaves(sched.cache),
+                    jax.tree.leaves(base_s.cache)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

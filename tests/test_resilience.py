@@ -70,17 +70,23 @@ def _run_sched(params, prompts, *, dispatch="bcsr", depth=1, plan=None,
 @pytest.fixture(scope="module")
 def baselines(params, prompts):
     """Fault-free token maps per (dispatch, depth, kv_quant) combo, computed
-    lazily so only combos a test actually compares against are run."""
+    lazily so only combos a test actually compares against are run;
+    ``pool(key)`` is the slot pool such a run leaves behind."""
     cache = {}
 
     class Lazy:
-        def __getitem__(self, key):
+        def _run(self, key):
             if key not in cache:
                 dispatch, depth, kvq = key
-                _, cache[key] = _run_sched(params, prompts,
-                                           dispatch=dispatch, depth=depth,
-                                           kv_quant=kvq)
+                cache[key] = _run_sched(params, prompts, dispatch=dispatch,
+                                        depth=depth, kv_quant=kvq)
             return cache[key]
+
+        def __getitem__(self, key):
+            return self._run(key)[1]
+
+        def pool(self, key):
+            return self._run(key)[0].cache
 
     return Lazy()
 
@@ -355,6 +361,10 @@ CROSS = [
     ("gather", 1, "prefill", "nan", dict(uid=0), None),
     ("gather", 0, "sample", "inf", dict(uid=0, step=1), None),
     ("gather", 0, "quantize", "inf", dict(uid=1, step=1), "int8"),
+    # the fused tick has advanced the donated pool when the sample hook
+    # fires: its retry must sample again, not run the forward twice
+    ("gather", 0, "sample", "exception", dict(step=2), None),
+    ("gather", 1, "sample", "exception", dict(step=2), None),
 ]
 
 
@@ -375,6 +385,14 @@ def test_fault_matrix_cross(params, prompts, baselines, dispatch, depth,
         assert failed
     _assert_survivors_identical(out, baselines[(dispatch, depth, kvq)],
                                 failed_uids=failed)
+    if not failed:
+        # the pool left behind is the fault-free run's: a retry that ran a
+        # step's forward twice would advance its state (MoE occupancy
+        # counts) twice, which the tokens alone may not show
+        for a, b in zip(jax.tree.leaves(sched.cache),
+                        jax.tree.leaves(baselines.pool((dispatch, depth,
+                                                        kvq)))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_loop_poison_isolated_per_row(params):
@@ -566,6 +584,25 @@ def test_ladder_integration_walks_rungs(params, prompts, baselines):
     degr = [e for e in sched.summary()["health"]["events"]
             if e["event"] == "degrade"]
     assert [e["rung"] for e in degr] == ["kv_wide", "pipeline_serial"]
+
+
+def test_kv_wide_rung_after_a_fused_forward(params, prompts, baselines):
+    """A sample-stage exception on the fused path fires after the donated
+    forward: the kv_wide rung it triggers rebuilds the pool that forward
+    advanced (the one it was given is gone), and the retry samples the
+    step's int8-KV logits, so tokens through that step match the int8
+    baseline and every request finishes."""
+    plan = R.FaultPlan.single("sample", "exception", step=2)
+    sched, out = _run_sched(params, prompts, dispatch="gather", depth=0,
+                            kv_quant="int8", plan=plan, fail_threshold=1)
+    assert sched.ladder.state()["applied"] == ["kv_wide"]
+    assert not sched.failed and len(sched.finished) == N_REQ
+    paths = [str(p) for p, _ in
+             jax.tree_util.tree_leaves_with_path(sched.cache)]
+    assert not any("scale" in p for p in paths)
+    base = baselines[("gather", 0, "int8")]
+    for uid in (0, 1):       # resident from tick 0: prefill + ticks 0..2
+        np.testing.assert_array_equal(out[uid][:4], base[uid][:4])
 
 
 def test_mask_ref_rung_rewrites_spec(params):

@@ -3,9 +3,12 @@
 Every scheduler tick is one ``step`` record (a :class:`StepStat` and a
 ``serve.step`` profiler annotation) holding, in order, ``admit`` (with a
 ``prefill`` per admission), ``decode`` (the forward through the health
-fetch, the device sampler inside it), ``writeback`` (the KV-cache
-write-back) and ``sample`` (the per-row host bookkeeping).  The ``step``
-record counts the host syncs made inside it.
+fetch, the device sampler inside it), ``writeback`` (the commit of the
+slot pool that ``jit_decode_step`` took donated and updated in place:
+only making the returned pool the scheduler's; the layered two-phase path
+still scatters the step's rows there) and ``sample`` (the per-row host
+bookkeeping).  A sample-stage fault retries from the committed logits.
+The ``step`` record counts the host syncs made inside it.
 Tier-1, tiny config on the CPU.
 """
 import glob
