@@ -109,11 +109,16 @@ def _spmm_sweep(*, smoke: bool) -> dict:
 
 def _serving_sweep(*, smoke: bool) -> dict:
     """Quantized experts + quantized KV through ServeLoop vs the f32 loop."""
-    from benchmarks.bench_serve import TINY
     from repro.models import model as M_
+    from repro.models.config import ArchConfig
     from repro.launch.serve import ServeLoop
 
-    cfg = TINY
+    cfg = ArchConfig(
+        name="tiny-precision-bench", family="moe", d_model=32, n_heads=2,
+        n_kv_heads=1, d_ff=48, vocab_size=64,
+        block_unit=("attn", "attn+moe"), n_repeats=2, head_dim=16,
+        n_experts=4, top_k=1, capacity_factor=1.0, moe_shared_expert=True,
+        policy="f32")
     B, P, G = (2, 8, 6) if smoke else (4, 16, 12)
     max_seq = P + G
     params = M_.init_params(jax.random.PRNGKey(0), cfg)
@@ -129,7 +134,7 @@ def _serving_sweep(*, smoke: bool) -> dict:
                                         cache_dtype=jnp.float32,
                                         kv_quant=kv_quant)
         tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        lg, _ = M_.decode_step_layered(params, cfg, cache, int(pos), tok)
+        lg, _ = M_.decode_step(params, cfg, cache, pos, tok)
         return np.asarray(lg)
 
     base_loop = ServeLoop(params, cfg, max_seq=max_seq)

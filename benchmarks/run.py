@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV rows (paper mapping in DESIGN.md S8):
   Fig. 6b -> bench_spmm         Fig. 6c -> bench_spmspm
-  Tab. 1  -> bench_precision    beyond-paper (MoE-as-SpMM) -> bench_moe
+  Tab. 1  -> bench_precision
 The stencil of Fig. 6a is measured on the chip by ``bench/run.py``
 (cell ``j3d27pt.jacobi``).
 """
@@ -15,12 +15,11 @@ import traceback
 def main() -> None:
     from repro.runtime.compile_cache import enable_compile_cache
     enable_compile_cache()
-    from benchmarks import bench_moe, bench_precision, bench_spmm, bench_spmspm
+    from benchmarks import bench_precision, bench_spmm, bench_spmspm
     sections = [
         ("Fig6b/spmm", bench_spmm),
         ("Fig6c/spmspm", bench_spmspm),
         ("Tab1/precision", bench_precision),
-        ("beyond/moe", bench_moe),
     ]
     print("name,us_per_call,derived")
     failures = 0

@@ -1,5 +1,5 @@
-"""Measured autotune sweep: (bn, nt, bucket) on real shapes, winners
-registered into the tile table.
+"""Measured autotune sweep: (bn, nt) and flash (bq, bk) on real shapes,
+winners registered into the tile table.
 
 ROADMAP "measured, not heuristic, autotune rows": the static table in
 ``repro.kernels.tuning`` was sized from the roofline model; this harness
@@ -15,10 +15,6 @@ Axes swept per op:
     stream-walk count rides along with each timing: on interpret-mode CPU
     the wall clock is emulation-dominated, so the winner is chosen by
     (walks, time) lexicographically on TPU and time-only on CPU.
-  * ``moe_dispatch``  -- min_bucket floors for the two-phase serving loop:
-    the bucket trades zero-block stream work against phase-2 recompiles, so
-    the sweep scores ``route+execute`` wall time of a decode-shaped step
-    per floor.
   * ``flash``         -- (bq, bk) on a causal prefill shape, dense grid and
     the block-sparse sliding-window walk.  The sparse rows carry their
     walked-tile counts (bk is also the mask's pattern resolution: narrower
@@ -34,7 +30,6 @@ Run modes:
 """
 from __future__ import annotations
 
-import dataclasses
 import sys
 
 import jax
@@ -42,12 +37,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit_bench, row, time_fn
-from repro.configs import get_smoke
 from repro.core.formats import bcsr_from_dense
 from repro.kernels import tuning
 from repro.kernels.spmm import ops as spmm_ops
 from repro.kernels.spmm.kernel import stream_walks
-from repro.models import moe as moe_mod
 
 
 def _block_uniform(rng, shape, density, block=(8, 8)):
@@ -96,50 +89,6 @@ def sweep_spmm(*, smoke: bool = False, register: bool = True) -> dict:
         tuning.register("spmm", jnp.float32,
                         {"bn": best["bn"], "nt": best["nt"]})
     return {"shape": {"M": M_, "K": K_, "N": N_, "nnzb": int(a.nnzb)},
-            "points": points, "winner": best, "registered": bool(register)}
-
-
-def sweep_moe_bucket(*, smoke: bool = False, register: bool = True) -> dict:
-    """Sweep the two-phase min_bucket floor on a decode-shaped MoE layer:
-    score = route + execute wall time at (B, S=1) after warmup, so both the
-    zero-block stream tax (large floors) and the recompile tax (small
-    floors, if the routed count wobbles across buckets) are in the
-    measurement."""
-    rng = np.random.default_rng(0)
-    E_, D_ = (4, 64) if smoke else (16, 128)
-    floors = (8,) if smoke else (8, 16, 32, 64)
-    cfg = dataclasses.replace(
-        get_smoke("llama4-scout-17b-a16e"), d_model=D_, d_ff=2 * D_,
-        n_experts=E_, capacity_factor=1.25, moe_shared_expert=False)
-    params = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
-    x1 = jnp.asarray(rng.standard_normal((2, 1, D_)), jnp.float32)
-    # snapshot the RAW table row (not the shape-clamped lookup): the sweep
-    # varies only min_bucket, and the restore below must not bake this
-    # benchmark's small-d_model bn/nt clamps into the global row
-    raw = tuning._row("moe_dispatch", jnp.float32)
-
-    points = []
-    for floor in floors:
-        tuning.register("moe_dispatch", jnp.float32,
-                        {**raw, "min_bucket": floor})
-
-        def step():
-            plan, _ = moe_mod.route_moe(params, x1, cfg, dispatch="bcsr",
-                                        pos=7)
-            return moe_mod.execute_moe_jit(params, x1, plan, cfg)[0]
-
-        t = time_fn(step)
-        _, info = moe_mod.route_moe(params, x1, cfg, dispatch="bcsr", pos=7)
-        points.append({"min_bucket": floor, "t_us": t * 1e6,
-                       "nnzb_stream": info["nnzb_stream"],
-                       "nnzb_covered": info["nnzb_covered"]})
-    best = min(points, key=lambda p: p["t_us"])
-    # leave the table on the winning row (or restore the raw row untouched
-    # when the caller asked for a measurement-only run)
-    tuning.register("moe_dispatch", jnp.float32,
-                    {**raw, "min_bucket": best["min_bucket"]} if register
-                    else raw)
-    return {"shape": {"experts": E_, "d_model": D_, "tokens": [2, 1]},
             "points": points, "winner": best, "registered": bool(register)}
 
 
@@ -219,7 +168,6 @@ def sweep_flash(*, smoke: bool = False, register: bool = True) -> dict:
 
 def run(*, smoke: bool = False, register: bool = True) -> dict:
     return {"spmm": sweep_spmm(smoke=smoke, register=register),
-            "moe_dispatch": sweep_moe_bucket(smoke=smoke, register=register),
             "flash": sweep_flash(smoke=smoke, register=register)}
 
 

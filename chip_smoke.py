@@ -14,8 +14,9 @@ phases; the first failure exits non-zero.
 3. dense   -- qwen3-1.7b (28 layers, published widths, vocab 151936) served
    by ``ServeScheduler`` and cross-checked against ``ServeLoop``.
 4. moe     -- llama4-scout at published widths cut to one layer, served by
-   the two-phase ``ServeScheduler`` with bcsr and with gather dispatch,
-   which must agree token for token.
+   ``ServeScheduler`` with bcsr dispatch (the SpMM kernel inside the fused
+   decode step) and with gather dispatch, which must agree token for
+   token.
 
 ``--chips 4`` runs only the sharded engine (``engine.shard_*``) on a
 4-device mesh, bit for bit against the same kernel on one device.
@@ -390,8 +391,8 @@ def run_moe(seed: int) -> None:
           flush=True)
     params = _init_params(cfg, seed)
     prompts = _prompts(cfg, seed)
-    runs = {d: _serve(params, cfg, prompts, dispatch=d, two_phase=True,
-                      pipeline_depth=1) for d in ("bcsr", "gather")}
+    runs = {d: _serve(params, cfg, prompts, dispatch=d)
+            for d in ("bcsr", "gather")}
     for uid, toks in runs["bcsr"].items():
         check(np.array_equal(toks, runs["gather"][uid]),
               f"{cfg.name}: request {uid} bcsr {toks[:8].tolist()}... != "

@@ -26,7 +26,6 @@ devices.  On CPU the kernels run in interpret mode automatically.
 """
 from __future__ import annotations
 
-import collections
 import functools
 import os
 import warnings
@@ -109,12 +108,11 @@ def auto_mesh(mesh: Optional[Mesh] = None) -> Tuple[Mesh, str]:
 def stream_bucket(nnzb: int, *, minimum: int = 8) -> int:
     """Snap a routed nonzero-block count to its power-of-two bucket.
 
-    Two-phase serving (route on host, execute under jit) pads the index
-    stream to ``stream_bucket(nnzb)`` entries before handing it to a
-    compiled step, so the compile cache is keyed on the bucket, not the raw
-    data-dependent count: recompiles are bounded by ``log2(grid)`` buckets
-    while the stream stays within ``max(2 * nnzb, minimum)`` -- the floor
-    dominates on tiny (decode-step) streams, the 2x law everywhere else."""
+    A stream padded to ``stream_bucket(nnzb)`` entries keys a compile cache
+    on the bucket, not the raw data-dependent count: recompiles are bounded
+    by ``log2(grid)`` buckets while the stream stays within
+    ``max(2 * nnzb, minimum)`` -- the floor dominates on tiny streams, the
+    2x law everywhere else."""
     n = max(int(nnzb), int(minimum), 1)
     return 1 << (n - 1).bit_length()
 
@@ -130,93 +128,6 @@ def batch_bucket(n: int, *, minimum: int = 1, cap: Optional[int] = None) -> int:
     allocation time, so the clamp never produces a non-bucket shape)."""
     b = stream_bucket(n, minimum=minimum)
     return min(b, cap) if cap is not None else b
-
-
-class StreamPipeline:
-    """Depth-bounded in-flight buffer for routed dispatch streams: the
-    serving-loop analogue of the SpMM kernel's double-buffered K-tiles.
-
-    The pipelined two-phase serving loop routes layer L+1 on host while
-    layer L's compiled execute phase is still in flight on the device.
-    This buffer is the explicit two-slot structure bounding that overlap:
-    :meth:`push` enqueues a freshly *dispatched* (not awaited) execute
-    result together with the routed plan/stream that produced it -- keeping
-    the stream's device buffers referenced while the kernel consumes them --
-    then blocks the oldest entry out whenever more than ``depth`` are in
-    flight.
-
-    * ``depth=0`` -- every push drains immediately: fully serial, the
-      pre-pipelining ``block_until_ready``-per-layer behavior bit-for-bit.
-    * ``depth=1`` -- one execute rides in flight behind the host's route
-      work for the next layer (double buffering); pushing the next execute
-      first waits out the previous one.
-
-    :meth:`busy` probes (``jax.Array.is_ready``) whether an in-flight execute
-    is still running on the device -- what the serving loop samples at
-    route entry to attribute the route fetch wait as *hidden* behind
-    device compute rather than serial with it."""
-
-    def __init__(self, depth: int = 0):
-        if depth not in (0, 1):
-            raise ValueError(
-                f"StreamPipeline depth must be 0 (serial) or 1 (double "
-                f"buffered), got {depth!r}")
-        self.depth = depth
-        self.pushes = 0
-        self._inflight: collections.deque = collections.deque()
-
-    def __len__(self) -> int:
-        return len(self._inflight)
-
-    def push(self, tag, handle) -> None:
-        """Enqueue a dispatched result; block the oldest out beyond depth.
-
-        If waiting an entry out raises (a deferred device error surfacing at
-        the sync point), every remaining in-flight entry is released via
-        :meth:`abort` before the exception propagates -- the pipeline never
-        wedges with a leaked slot."""
-        self._inflight.append((tag, handle))
-        self.pushes += 1
-        try:
-            while len(self._inflight) > self.depth:
-                _, h = self._inflight.popleft()
-                jax.block_until_ready(h)
-        except BaseException:
-            self.abort()
-            raise
-
-    def busy(self) -> bool:
-        """Is any in-flight entry still executing on the device?"""
-        for _, h in self._inflight:
-            for leaf in jax.tree.leaves(h):
-                if not leaf.is_ready():
-                    return True
-        return False
-
-    def drain(self) -> None:
-        """Block every in-flight entry out (phase boundary / loop reset).
-
-        Exception-safe like :meth:`push`: a failing wait aborts the rest of
-        the queue before re-raising, so the pipeline is empty either way."""
-        try:
-            while self._inflight:
-                _, h = self._inflight.popleft()
-                jax.block_until_ready(h)
-        except BaseException:
-            self.abort()
-            raise
-
-    def abort(self) -> None:
-        """Release every in-flight entry without raising: best-effort wait
-        (swallowing deferred device errors -- they already surfaced or are
-        being handled by the caller) and unconditionally empty the queue, so
-        the next ``decode_step`` starts from a clean pipeline."""
-        while self._inflight:
-            _, h = self._inflight.popleft()
-            try:
-                jax.block_until_ready(h)
-            except jax.errors.JaxRuntimeError:
-                pass
 
 
 def _pad_dim(x: jax.Array, dim: int, multiple: int, value=0) -> jax.Array:
@@ -339,9 +250,7 @@ def shard_spmm_batched_stream(a: BatchedBCSR, dense: jax.Array, *,
     preserves).  Unlike :func:`shard_spmm_batched` this never inspects the
     index stream host-side, so it can be called *under jit* with the stream
     arrays as traced arguments -- the compile cache then keys on the stream
-    *shape* (a bucketed capacity), never on the concrete index values.  This
-    is the phase-2 entry point of the two-phase route-then-compile serving
-    loop (see models.moe.execute_moe)."""
+    *shape* (a bucketed capacity), never on the concrete index values."""
     mesh, axis = auto_mesh(mesh)
     n_dev = mesh.shape[axis]
     interpret = _interpret_default(interpret)

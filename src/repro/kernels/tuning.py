@@ -92,11 +92,11 @@ _TABLE: Dict[Tuple[str, str, str], Dict[str, Any]] = {
     # 0/1 (slot, token) dispatch matrix -- small square blocks track the
     # one-nonzero-per-column structure; ``bn`` is the d_model N-tile of the
     # token operand streamed through the SpMM kernel.  ``min_bucket`` is the
-    # floor of the power-of-two nnzb bucket the two-phase serving loop pads
-    # routed index streams to (engine.stream_bucket): larger floors mean
-    # fewer phase-2 recompiles at the cost of more zero-block stream work,
-    # so the TPU row (compiles are expensive, streams are cheap) sits
-    # higher than the CPU/interpret row.
+    # floor of the power-of-two nnzb bucket a routed index stream is padded
+    # to for a compiled step (engine.stream_bucket): larger floors mean
+    # fewer recompiles at the cost of more zero-block stream work, so the
+    # TPU row (compiles are expensive, streams are cheap) sits higher than
+    # the CPU/interpret row.
     ("moe_dispatch", "f32", "tpu"): {"block": (8, 8), "bn": 256,
                                      "min_bucket": 32, "nt": 2},
     ("moe_dispatch", "bf16", "tpu"): {"block": (8, 8), "bn": 512,
@@ -248,8 +248,8 @@ def moe_dispatch_tiles(d_model: int, dtype=jnp.float32) -> Dict[str, Any]:
     MoE dispatch-as-SpMM path; ``bn`` (the d_model N-tile of the token
     operand) gets the same shape/VMEM clamp as :func:`spmm_bn` and ``nt``
     the residency clamp of :func:`spmm_tiles`; ``min_bucket`` feeds
-    ``engine.stream_bucket`` when the routed stream is bucketed for the
-    two-phase serving loop (rows registered without it fall back to 8)."""
+    ``engine.stream_bucket`` when a routed stream is bucketed for a
+    compiled step (rows registered without it fall back to 8)."""
     row = _row("moe_dispatch", dtype)
     bm, bk = row["block"]
     bn = _clamp_bn(int(row["bn"]), d_model, dtype, bk)

@@ -1,99 +1,63 @@
-"""Serving launcher: two-phase route-then-compile serving, single-run and
-continuous-batching multi-tenant.
+"""Serving launcher: single-run and continuous-batching multi-tenant serving.
 
-Two drivers share one phase machinery (:class:`_ServeBase`):
+Two drivers share one base (:class:`_ServeBase`: dispatch-backend override,
+fault hooks, spans, the degradation ladder), and both run the model as
+whole-stack compiled programs -- ``model.prefill`` and ``model.decode_step``,
+MoE layers included, on either dispatch backend ("gather" or the bcsr SpMM,
+traced over its full grid):
 
 * :class:`ServeLoop` -- the static-batch driver: one prefill over a fixed
-  (B, S) prompt batch, then lockstep decode.  Two modes:
-
-  * **fused** (default for gather dispatch) -- the whole one-token decode
-    step is one jit-compiled program (`model.decode_step`), the classic
-    serving loop.  Greedy (temperature 0) decoding is token-for-token
-    identical to the pre-ServeLoop smoke loop.
-  * **two-phase** (default when the arch has MoE layers and the "bcsr"
-    dispatch backend is selected) -- prefill AND each decode step run layer
-    by layer (`model.prefill_layered` / `model.decode_step_layered`, every
-    layer a cached jit-compiled step); at every attn+moe layer the loop
-    *routes on host* (``moe.route_moe``: jitted router matmul, then
-    compacts the dispatch matrix to its union nonzero-block stream, padded
-    to a power-of-two nnzb bucket) and then calls the jit-compiled
-    expert/combine phase (``moe.execute_moe_jit``) on that static-bucketed
-    stream.  Recompiles stay bounded by the bucket count (see
-    tests/README.md "two-phase serving contract").
+  (B, S) prompt batch, then lockstep decode, one jit-compiled
+  ``jit_decode_step`` per token, sampled on host.  Greedy (temperature 0)
+  decoding is token-for-token identical to the plain prefill-then-decode
+  loop.
 
 * :class:`ServeScheduler` -- the continuous-batching frontend: a request
   queue with admission, join/evict *between decode steps* (finished or
   EOS'd sequences free their slot, queued prompts prefill into it), and
   per-request position / routing-occupancy / sampling state carried
   through the batch dim of the prefix-stable decode cache.  Decode steps
-  run at a power-of-two *batch bucket* (``engine.batch_bucket`` -- the
-  PR-3 nnzb bucket law extended to the batch dimension), so batch
+  run at a power-of-two *batch bucket* (``engine.batch_bucket``), so batch
   composition changes never retrace: compiled-step shapes are bounded by
-  (batch buckets x nnzb buckets).  Per request the generated tokens are
-  token-identical to running that request alone through a sequential
-  :class:`ServeLoop` (enforced by tests/test_serve_scheduler.py) -- every
-  per-row computation (attention at per-row positions, prefix-stable MoE
-  occupancy, sampling keys) is independent of which neighbours share the
-  batch.
-
-Both drivers take a ``pipeline_depth`` knob (default 0):
-
-* ``pipeline_depth=0`` -- fully serial MoE: every route/execute phase
-  blocks on device results (``jax.block_until_ready``) before reading the
-  clock and *drains* pending device work before starting a phase clock, so
-  queued compute from the previous phase is never misattributed.
-  ``ServeLoop`` also samples eagerly on host after blocking on the logits.
-* ``pipeline_depth=1`` -- the pipelined hot path: each attn+moe layer's
-  route phase 1 is fused into its jitted attention step (dispatched one
-  program ahead; only the small slot stream is fetched to host, never the
-  hidden state), the compiled execute phase stays *in flight* on the device
-  behind the next layer's host route work (``engine.StreamPipeline``, the
-  serving-loop analogue of the kernels' double-buffered K-tiles), and
-  ``ServeLoop`` samples on device, so it syncs nothing until the final
-  drain.  Generated tokens are bit-identical to depth 0
-  (tests/test_serve_pipeline.py); ``summary()["timing"]`` reports how much
-  route time the overlap actually hid (``route_hidden_frac``).
-
-``ServeScheduler`` samples its decode ticks on the device at both depths:
-one compiled sampler over the step's logits, and one fetch of the token
-ids and health bits per tick.  For it ``pipeline_depth`` governs only the
-MoE route/execute pipelining.
+  the batch buckets.  Per request the generated tokens are token-identical
+  to running that request alone through a sequential :class:`ServeLoop`
+  (enforced by tests/test_serve_scheduler.py) -- every per-row computation
+  (attention at per-row positions, prefix-stable MoE occupancy, sampling
+  keys) is independent of which neighbours share the batch.  Each decode
+  tick is one donated program over the slot pool and one compiled sampler
+  on the device, with one fetch of the token ids and health bits.
 
 **Resilience** (``runtime.resilience``, tests/README.md "Resilience
 contract"): both drivers take a deterministic ``fault_plan`` whose staged
-hooks (prefill / route / execute / attention / sample / quantize) poison
-rows, corrupt quant scales, raise, or straggle on demand.  The scheduler
-isolates failures per request: cheap on-device ``isfinite`` health bits
-piggyback on the existing per-step token fetch (zero NEW host syncs), a
-poisoned row is moved to a FAILED state, its cache row
-scatter-blanked (``model.blank_cache_row``) and its slot refilled --
-co-batched survivors' tokens stay bit-identical to a fault-free run
-(per-row independence, the same law behind the batch-bucket contract).
-Failed prefills and decode steps retry under a bounded exponential-backoff
-``RetryPolicy`` (faults fire before any key split, and a fused decode
-step's retry samples again from the logits its one forward made, so a
-retry reproduces the fault-free step exactly); requests carry optional
-TTFT/total deadlines and the admission queue is bounded with an explicit
-shed policy.  Accumulated failures walk a ``DegradationLadder`` (quantized
-KV -> wide, sparse mask -> ref, pipeline depth 1 -> 0; the serial rung
-keeps the scheduler's device sampling); everything is surfaced in
-``summary()["health"]``.
+hooks (prefill / sample / quantize) poison rows, corrupt quant scales,
+raise, or straggle on demand.  The scheduler isolates failures per
+request: cheap on-device ``isfinite`` health bits piggyback on the
+existing per-step token fetch (zero NEW host syncs), a poisoned row is
+moved to a FAILED state, its cache row scatter-blanked
+(``model.blank_cache_row``) and its slot refilled -- co-batched survivors'
+tokens stay bit-identical to a fault-free run (per-row independence, the
+same law behind the batch-bucket contract).  Failed prefills and decode
+steps retry under a bounded exponential-backoff ``RetryPolicy`` (faults
+fire before any key split, and a decode step's retry samples again from
+the logits its one forward made, so a retry reproduces the fault-free
+step exactly); requests carry optional TTFT/total deadlines and the
+admission queue is bounded with an explicit shed policy.  Accumulated
+failures walk a ``DegradationLadder`` (quantized KV -> wide, sparse mask
+-> ref); everything is surfaced in ``summary()["health"]``.
 
 **Spans**: each timed phase is one :class:`StepStat` in ``stats`` and one
 ``serve.<phase>`` profiler annotation, opened and closed at the same two
 points (``_ServeBase._span``), so a device trace shows what the host was
 doing in each gap.  A scheduler tick is ``step``, holding ``admit`` (a
 ``prefill`` per admission), ``decode`` (the forward through the health
-fetch, the device sampler inside it), ``writeback`` (the commit of every
-per-row state the step made -- KV cache, and for hybrid stacks the Gated
-DeltaNet conv tail and recurrent state: on the fused path the pool that
-``jit_decode_step`` updated in place becomes the scheduler's, on the
-layered path the rows are scattered back; ``extra["in_place"]`` says
-which) and ``sample`` (the per-row
-host bookkeeping: append, position, evict, fail); ``step`` carries the
-tick's host-sync count, and ``decode`` of a dropless expert share the
-step's (token, held expert) pairs as ``moe_held_pairs``, fetched with the
-token ids.
+fetch, the device sampler inside it), ``writeback`` (the commit of the
+slot pool that ``jit_decode_step`` took donated and updated in place --
+KV cache, and for hybrid stacks the Gated DeltaNet conv tail and
+recurrent state -- as the scheduler's) and ``sample`` (the per-row host
+bookkeeping: append, position, evict, fail); ``step`` carries the tick's
+host-sync count, and ``decode`` of a dropless expert share the step's
+(token, held expert) pairs as ``moe_held_pairs``, fetched with the token
+ids.
 
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b --smoke \
@@ -128,10 +92,10 @@ from repro.runtime import resilience as R
 @dataclasses.dataclass
 class StepStat:
     """One timed phase of the loop, the program's span record; ``extra``
-    carries phase-specific detail (e.g. the route phase's nnzb stream
-    accounting).  ``start`` is the host monotonic clock at the span's open,
-    so ``start + seconds`` is its close."""
-    phase: str  # step|admit|prefill|route|execute|decode|writeback|sample|drain
+    carries phase-specific detail (e.g. a decode step's batch bucket).
+    ``start`` is the host monotonic clock at the span's open, so
+    ``start + seconds`` is its close."""
+    phase: str  # step|admit|prefill|decode|writeback|sample
     step: int           # decode step index (-1 for prefill)
     seconds: float
     tokens: int = 0
@@ -156,62 +120,36 @@ def _percentiles_ms(seconds: List[float]) -> Dict[str, float]:
             "mean": float(a.mean()), "n": int(a.size)}
 
 
-def _sampler_body(vocab: int, temperature: float, per_row_keys: bool):
-    """The sampling math shared by :func:`_sampler_jit` and
-    :func:`_sampler_health_jit`: vocab slice, argmax or categorical --
-    identical to the eager ``_sample``/``_sample_one``."""
+def _sampler_body(vocab: int, temperature: float):
+    """The scheduler's per-row sampling math: vocab slice, then argmax or
+    a categorical vmapped over a ``(B, 2)`` stack of per-request keys --
+    bit-identical per row to the eager ``_sample_one`` with that row's key
+    (the composition-independence law).  Returns ``(B,)`` int32."""
     if temperature > 0:
-        if per_row_keys:
-            def fn(logits, keys):
-                lg = logits[:, :vocab] / temperature
-                return jax.vmap(jax.random.categorical)(
-                    keys, lg).astype(jnp.int32)
-        else:
-            def fn(logits, key):
-                lg = logits[:, :vocab] / temperature
-                return jax.random.categorical(
-                    key, lg)[:, None].astype(jnp.int32)
+        def fn(logits, keys):
+            lg = logits[:, :vocab] / temperature
+            return jax.vmap(jax.random.categorical)(keys, lg).astype(
+                jnp.int32)
     else:
-        if per_row_keys:
-            def fn(logits, keys):
-                return jnp.argmax(logits[:, :vocab],
-                                  axis=-1).astype(jnp.int32)
-        else:
-            def fn(logits, key):
-                return jnp.argmax(logits[:, :vocab],
-                                  axis=-1)[:, None].astype(jnp.int32)
+        def fn(logits, keys):
+            return jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
     return fn
 
 
 @functools.lru_cache(maxsize=None)
-def _sampler_jit(vocab: int, temperature: float, per_row_keys: bool):
-    """On-device sampler for the pipelined hot path: the same math as the
-    eager ``_sample``/``_sample_one`` (vocab slice, argmax or categorical),
-    fused into one compiled program so the sampled token array can feed the
-    next step without any host fetch of the logits.
-
-    ``per_row_keys=False`` takes one key for the whole batch and returns
-    ``(B, 1)`` int32 (the ``ServeLoop`` shape); ``per_row_keys=True`` takes
-    a ``(B, 2)`` stack of per-request keys and vmaps the categorical over
-    rows, returning ``(B,)`` int32 -- bit-identical per row to sampling
-    that row alone with its own key (the scheduler's composition-
-    independence law).  Greedy (temperature 0) ignores the key operand."""
-    return jax.jit(_sampler_body(vocab, temperature, per_row_keys))
-
-
-@functools.lru_cache(maxsize=None)
-def _sampler_health_jit(vocab: int, temperature: float, per_row_keys: bool):
-    """:func:`_sampler_jit` + per-row health bits, one compiled program,
-    over a decode step's whole ``(B, S, V)`` logits: the last position is
-    sliced inside the program (the device trace shows ``jit_sample_step``).
+def _sampler_health_jit(vocab: int, temperature: float):
+    """The decode tick's sampler + per-row health bits, one compiled
+    program, over a decode step's whole ``(B, S, V)`` logits: the last
+    position is sliced inside the program (the device trace shows
+    ``jit_sample_step``).
 
     Returns ``(tokens, finite)`` where ``finite[b]`` is the
     ``all(isfinite)`` reduction of row ``b``'s vocab slice -- the poison
     detector.  The scheduler fetches both in the SAME ``jax.device_get``
     it already spends on the token ids, so per-request isolation costs
-    zero additional host syncs; token bits are untouched (the sampler body
-    is shared verbatim).  Greedy takes ``None`` for the key operand."""
-    body = _sampler_body(vocab, temperature, per_row_keys)
+    zero additional host syncs.  Greedy takes ``None`` for the key
+    operand."""
+    body = _sampler_body(vocab, temperature)
 
     def sample_step(logits, key):
         last = logits[:, -1]
@@ -224,8 +162,8 @@ def _sampler_health_jit(vocab: int, temperature: float, per_row_keys: bool):
 def _health_accum_jit(vocab: int):
     """Fold one decode step's last-position logits into a running per-row
     health mask, on device: ``acc & all(isfinite(row))``.  Dispatched (not
-    fetched) per step, read back once at the end-of-run drain -- the
-    ``ServeLoop`` health path stays sync-free."""
+    fetched) per step, read back once at the end of the run -- the
+    ``ServeLoop`` health path adds no per-step sync."""
     return jax.jit(lambda lg, acc: acc & jnp.all(
         jnp.isfinite(lg[:, :vocab]), axis=-1))
 
@@ -277,14 +215,12 @@ def _commit_row(pool, row_cache, slot):
 
 
 class _ServeBase:
-    """Phase machinery shared by the static-batch :class:`ServeLoop` and the
-    continuous-batching :class:`ServeScheduler`: dispatch-backend selection,
-    the two-phase route->execute MoE stage with honest per-phase timing, and
-    the phase-2 compile-signature accounting."""
+    """What the static-batch :class:`ServeLoop` and the continuous-batching
+    :class:`ServeScheduler` share: the dispatch-backend override, the fault
+    hooks, the spans and host-sync counter, and the degradation ladder."""
 
     def __init__(self, params, cfg, *, dispatch: Optional[str] = None,
-                 two_phase: Optional[bool] = None, temperature: float = 0.0,
-                 sample_seed: int = 3, pipeline_depth: int = 0,
+                 temperature: float = 0.0, sample_seed: int = 3,
                  quantize_experts: Optional[str] = None,
                  kv_quant: Optional[str] = None,
                  attn_mask: Optional[AttnMaskSpec] = None,
@@ -300,27 +236,19 @@ class _ServeBase:
         self._fallback_base = flash_ops.fallback_count()
         if quantize_experts:
             # opt-in narrow expert FFN weights: one-time host quantization,
-            # QuantTensor leaves then flow through every execute path
+            # QuantTensor leaves then flow through the compiled step
             self.params = moe.quantize_model_experts(params, quantize_experts)
         self.backend = dispatch or cfg.moe_dispatch
-        has_moe = any(k == "attn+moe" for k in cfg.block_unit)
-        self.two_phase = ((self.backend == "bcsr" and has_moe)
-                          if two_phase is None else two_phase)
         self.temperature = temperature
         self._sample_seed = sample_seed
         self._sample_key = jax.random.PRNGKey(sample_seed)
         self.stats: List[StepStat] = []
-        self._exec_keys: set = set()   # distinct phase-2 compile signatures
-        self.pipeline_depth = int(pipeline_depth)
-        # validates the depth (0 = serial, 1 = double-buffered)
-        self._pipe = engine.StreamPipeline(self.pipeline_depth)
         # -- resilience state (runtime.resilience) --------------------------
         self.fault_plan = fault_plan
         self.retry = retry if retry is not None else R.RetryPolicy()
         self.health = R.HealthTracker()
         self.ladder = R.DegradationLadder.for_serving(
             kv_quant=kv_quant, attn_mask=attn_mask,
-            pipeline_depth=self.pipeline_depth,
             fail_threshold=fail_threshold)
         self._row_uids: Optional[List[Optional[int]]] = None
         self._host_syncs = 0   # every wait on / fetch of a device value
@@ -345,8 +273,7 @@ class _ServeBase:
     def _sync(self, fetch, x):
         """``fetch(x)``, counted as one host sync: ``fetch`` is
         ``jax.block_until_ready``, ``jax.device_get``, ``np.asarray`` or
-        ``int`` of a device value.  Syncs inside other modules (the MoE
-        router's slot fetch, the stream pipeline's drain) are not counted."""
+        ``int`` of a device value."""
         self._host_syncs += 1
         return fetch(x)
 
@@ -379,24 +306,14 @@ class _ServeBase:
         if rung == "kv_wide":
             # quantized KV -> wide f32 KV: rebuild the live cache without
             # scale leaves; subsequent prefills/steps see kv_quant=None
-            self._pipe.abort()
             if getattr(self, "cache", None) is not None:
                 self.cache = R.dequantize_cache(self.cache, jnp.float32)
             self.kv_quant = None
         elif rung == "mask_ref":
             # sparse stream-walk attention -> the jnp reference path
             self.attn_mask = dataclasses.replace(self.attn_mask, impl="ref")
-        elif rung == "pipeline_serial":
-            # depth 1 -> 0: drain what's in flight, go fully serial
-            self._pipe.abort()
-            self.pipeline_depth = 0
-            self._pipe = engine.StreamPipeline(0)
 
     # ------------------------------------------------------------- phases --
-
-    def _step_label(self) -> int:
-        """Decode step index for phase stats (-1 = prefill)."""
-        raise NotImplementedError
 
     @contextlib.contextmanager
     def _dispatch_ctx(self):
@@ -413,130 +330,17 @@ class _ServeBase:
         finally:
             pctx.MOE_DISPATCH = prev
 
-    def _moe_two_phase(self, p_ffn, h, cfg, counts=None, pos=None,
-                       phase1=None, layer=None):
-        """The route -> execute stage injected at every attn+moe layer.
-
-        Serial mode (``pipeline_depth=0``): the drain on ``h`` happens
-        BEFORE the route clock starts -- ``h`` is the async result of the
-        attention half of the layer, and blocking on it inside the timer
-        would charge that queued device compute to "route" (the pre-PR-6
-        misattribution) -- and the execute result is blocked on, so every
-        phase wall is honest device time.
-
-        Pipelined mode (``pipeline_depth=1``): no drains anywhere.  The
-        model's fused attention+route program already dispatched this
-        layer's routing arrays (``phase1``), so the route stage is just the
-        small slot-stream fetch + host compaction
-        (``moe.plan_from_phase1``); the freshly dispatched execute is
-        pushed into the stream pipeline instead of blocked on, riding in
-        flight behind the *next* layer's host route work.  Route stats then
-        carry ``hidden_s``: the fetch wait observed while an execute was
-        genuinely still running on the device -- route time hidden behind
-        device compute (0 by construction at depth 0)."""
-        step = self._step_label()
-        # fault hooks: "attention" poisons the attention half's output
-        # feeding this layer, "route" fires before the host routing work
-        # (exception kind = the host route failure mode).  Poisons are one
-        # dispatched jnp.where each -- no sync, rows outside the spec's
-        # selection are bit-identical untouched.
-        h = self._fault("attention", h, step=step)
-        h = self._fault("route", h, step=step)
-        pipelined = self.pipeline_depth > 0
-        drain_s = 0.0
-        if not pipelined:
-            t_d = time.monotonic()
-            h = self._sync(jax.block_until_ready, h)
-            drain_s = time.monotonic() - t_d
-        busy = pipelined and self._pipe.busy()
-        tokens = h.shape[0] * h.shape[1]
-        with self._span("route", step, tokens=tokens) as st:
-            if phase1 is not None:
-                plan, info = moe.plan_from_phase1(phase1, cfg,
-                                                  dispatch=self.backend,
-                                                  dtype=h.dtype)
-            else:
-                plan, info = moe.route_moe(p_ffn, h, cfg, counts=counts,
-                                           pos=pos, dispatch=self.backend,
-                                           layer=layer)
-            st.extra.update(
-                info, drain_s=drain_s, pipelined=pipelined,
-                hidden_s=info.get("wait_s", 0.0) if busy else 0.0)
-        sig = (plan.capacity, plan.backend, tuple(h.shape),
-               None if plan.stream is None
-               else (plan.stream.nnzb,) + tuple(plan.stream.shape))
-        self._exec_keys.add(sig)
-        with self._span("execute", step, tokens=tokens,
-                        nnzb_stream=info.get("nnzb_stream"),
-                        compile_signatures=len(self._exec_keys),
-                        dispatch_only=pipelined):
-            out, new_counts = moe.execute_moe_jit(p_ffn, h, plan, cfg, layer)
-            out = self._fault("execute", out, step=step)
-            # depth 0: push blocks immediately (the serial execute wall);
-            # depth 1: the execute stays in flight behind the next host route
-            self._pipe.push(plan, out)
-        return out, new_counts
-
     def _phase_summary(self) -> Dict[str, Any]:
-        """Aggregate per-phase seconds / call counts.  The phases are NOT
-        disjoint in two-phase mode: each "decode" step stat (and every
-        "prefill" stat) times the whole layered pass, *inclusive* of the
-        "route" / "execute" layer calls made inside it.
-
-        ``timing`` is the attribution split: ``host_route_ms`` is the route
-        phase minus its device fetch wait (pure host routing work),
-        ``device_execute_ms`` / ``execute_dispatch_ms`` separate blocked
-        execute walls (serial mode) from dispatch-only walls (pipelined
-        mode) -- the pre-PR-7 summary folded the device-queue drain into
-        whichever phase blocked first.  ``route_hidden_ms`` /
-        ``route_hidden_frac`` report how much of the route phase ran while
-        an execute was in flight on the device: the overlap efficiency of
-        the pipelined mode, exactly 0 at depth 0."""
+        """Per-phase seconds / call counts of the prefill and decode spans,
+        the attention-fallback count, and the health surface."""
         out: Dict[str, Any] = {}
-        for phase in ("prefill", "route", "execute", "decode", "drain"):
+        for phase in ("prefill", "decode"):
             ss = [s for s in self.stats if s.phase == phase]
             if ss:
                 out[phase] = {"seconds": sum(s.seconds for s in ss),
                               "calls": len(ss)}
-        fallbacks = flash_ops.fallback_count() - self._fallback_base
-        routes = [s for s in self.stats if s.phase == "route"]
-        execs = [s for s in self.stats if s.phase == "execute"]
-        if routes or execs:
-            route_s = sum(s.seconds for s in routes)
-            wait_s = sum(s.extra.get("wait_s", 0.0) for s in routes)
-            hidden_s = sum(s.extra.get("hidden_s", 0.0) for s in routes)
-            out["timing"] = {
-                "host_route_ms": (route_s - wait_s) * 1e3,
-                "route_wait_ms": wait_s * 1e3,
-                "attn_drain_ms": sum(s.extra.get("drain_s", 0.0)
-                                     for s in routes) * 1e3,
-                "device_execute_ms": sum(
-                    s.seconds for s in execs
-                    if not s.extra.get("dispatch_only")) * 1e3,
-                "execute_dispatch_ms": sum(
-                    s.seconds for s in execs
-                    if s.extra.get("dispatch_only")) * 1e3,
-                "route_hidden_ms": hidden_s * 1e3,
-                "route_hidden_frac": (hidden_s / route_s
-                                      if route_s > 0 else 0.0),
-                "attention_ref_fallbacks": fallbacks,
-            }
-        else:
-            # non-MoE (no route/execute stats): the fallback count is still
-            # surfaced, zero included
-            out["timing"] = {"attention_ref_fallbacks": fallbacks}
-        if self.two_phase:
-            streams = [s for s in routes if "nnzb_stream" in s.extra]
-            if streams:
-                out["stream"] = {
-                    "nnzb_stream_mean": float(np.mean(
-                        [s.extra["nnzb_stream"] for s in streams])),
-                    "nnzb_routed_mean": float(np.mean(
-                        [s.extra["nnzb_routed"] for s in streams])),
-                    "grid_nnzb": streams[-1].extra["grid_nnzb"],
-                }
-            out["compile_signatures"] = len(self._exec_keys)
-        out["pipeline"] = {"depth": self.pipeline_depth}
+        out["timing"] = {"attention_ref_fallbacks":
+                         flash_ops.fallback_count() - self._fallback_base}
         # resilience surface: monotonic counters + bounded event log
         # (HealthTracker), the degradation-ladder position, and the exact
         # faults the plan fired (see tests/README.md "Resilience contract")
@@ -558,15 +362,7 @@ class ServeLoop(_ServeBase):
     max_seq : static decode-cache capacity (prompt + generation).
     dispatch : MoE dispatch backend override ("gather" | "bcsr");
         default is the config's ``moe_dispatch`` field.
-    two_phase : force the route-then-compile decode path on/off; default
-        (None) enables it exactly when the arch has attn+moe layers and the
-        backend is "bcsr" -- the combination where single-phase jit degrades
-        to full-grid streams.
     temperature : 0 = greedy argmax, > 0 = categorical sampling.
-    pipeline_depth : 0 = fully serial (every step blocks, the pre-PR-7
-        behavior bit-for-bit); 1 = pipelined hot path (route-ahead fused
-        programs, executes in flight behind host routing, on-device
-        sampling -- token-identical to depth 0, see module docstring).
     quantize_experts : narrow dtype name ("fp8_e4m3" | "fp8_e5m2" | "int8")
         to BlockQuant the expert FFN weights at construction
         (``moe.quantize_model_experts``); None (default) leaves params
@@ -575,28 +371,25 @@ class ServeLoop(_ServeBase):
         per-position narrow values + f32 scales (local ring buffers stay
         wide); None (default) keeps the wide cache bit-for-bit.
     fault_plan : optional ``resilience.FaultPlan`` whose staged hooks this
-        loop calls at every prefill / route / execute / attention / sample /
-        quantize boundary (identity when None).
+        loop calls at every prefill / sample / quantize boundary (identity
+        when None).
     retry, fail_threshold : the resilience knobs shared with the scheduler
         (here the retry policy is only carried for ``summary()`` symmetry;
-        the static-batch loop re-raises step failures after aborting the
-        pipeline -- per-request retry lives in :class:`ServeScheduler`).
+        the static-batch loop re-raises step failures -- per-request retry
+        lives in :class:`ServeScheduler`).
     """
 
     def __init__(self, params, cfg, *, max_seq: int,
                  dispatch: Optional[str] = None,
-                 two_phase: Optional[bool] = None,
                  temperature: float = 0.0, sample_seed: int = 3,
-                 pipeline_depth: int = 0,
                  quantize_experts: Optional[str] = None,
                  kv_quant: Optional[str] = None,
                  attn_mask: Optional[AttnMaskSpec] = None,
                  fault_plan: Optional[R.FaultPlan] = None,
                  retry: Optional[R.RetryPolicy] = None,
                  fail_threshold: int = 3):
-        super().__init__(params, cfg, dispatch=dispatch, two_phase=two_phase,
+        super().__init__(params, cfg, dispatch=dispatch,
                          temperature=temperature, sample_seed=sample_seed,
-                         pipeline_depth=pipeline_depth,
                          quantize_experts=quantize_experts,
                          kv_quant=kv_quant, attn_mask=attn_mask,
                          fault_plan=fault_plan, retry=retry,
@@ -613,41 +406,19 @@ class ServeLoop(_ServeBase):
 
     # ------------------------------------------------------------- phases --
 
-    def _step_label(self) -> int:
-        return len(self.generated) - 1
-
     def prefill(self, prompts: jax.Array,
                 embeddings: Optional[jax.Array] = None) -> jax.Array:
         """Run the prompt through the model, fill the decode cache, and
-        emit the first generated token (B, 1).
-
-        Resets the generation state up front: the two-phase moe stage
-        derives its step label from ``len(self.generated)``, which must
-        read -1 (prefill) here even when a previous ``run`` left tokens
-        behind.
-
-        In two-phase mode the prompt runs through the *layered* prefill
-        (``model.prefill_layered``) with the route->execute stage injected
-        at every attn+moe layer, so prefill streams the bucketed routed
-        dispatch stream too -- the fused ``model.prefill`` would trace the
-        bcsr dispatch back to the full ``E*C x T`` grid (the single-phase
-        fallback this loop exists to avoid)."""
+        emit the first generated token (B, 1).  Resets the generation
+        state up front, so a second ``run`` starts clean."""
         self.generated = []
         with self._span("prefill", -1, tokens=int(np.prod(prompts.shape))):
-            if self.two_phase:
-                logits, cache, pos = M.prefill_layered(
+            with self._dispatch_ctx():
+                logits, cache, pos = M.prefill(
                     self.params, prompts, self.cfg, max_seq=self.max_seq,
-                    embeddings=embeddings, moe_fn=self._moe_two_phase,
-                    route_ahead=self.pipeline_depth > 0,
-                    kv_quant=self.kv_quant, attn_mask=self.attn_mask)
-            else:
-                with self._dispatch_ctx():
-                    logits, cache, pos = M.prefill(
-                        self.params, prompts, self.cfg, max_seq=self.max_seq,
-                        embeddings=embeddings, kv_quant=self.kv_quant,
-                        attn_mask=self.attn_mask)
+                    embeddings=embeddings, kv_quant=self.kv_quant,
+                    attn_mask=self.attn_mask)
             logits, cache = jax.block_until_ready((logits, cache))
-            self._pipe.drain()   # prefill executes all completed with logits
             logits = self._fault("prefill", logits, step=-1)
             cache = self._fault_cache(cache, step=-1,
                                       nrows=int(prompts.shape[0]))
@@ -667,19 +438,6 @@ class ServeLoop(_ServeBase):
             nxt = jnp.argmax(lg, axis=-1)
         return nxt[:, None].astype(jnp.int32)
 
-    def _sample_device(self, last_logits: jax.Array) -> jax.Array:
-        """Pipelined-mode sampling: same math as :meth:`_sample` (same key
-        chain -- the split still happens eagerly on host), but the
-        argmax/categorical runs as one jitted program whose (B, 1) token
-        output feeds the next step's embedding lookup *on device* -- no
-        host sync anywhere in the decode chain."""
-        if self.temperature > 0:
-            self._sample_key, k = jax.random.split(self._sample_key)
-        else:
-            k = self._sample_key   # unused by the greedy program
-        return _sampler_jit(self.cfg.vocab_size, float(self.temperature),
-                            False)(last_logits, k)
-
     def decode_step(self) -> jax.Array:
         """Generate one token for every sequence in the batch."""
         if self.cache is None:
@@ -696,53 +454,30 @@ class ServeLoop(_ServeBase):
                 f"(prefill filled {self.pos}, this is generated token "
                 f"{step + 2}). Raise max_seq or generate fewer tokens.")
         tok = self.generated[-1]
-        pipelined = self.pipeline_depth > 0
         self.cache = self._fault_cache(self.cache, step=step,
                                        nrows=int(tok.shape[0]))
         t0 = time.monotonic()
-        if self.two_phase:
-            logits, self.cache = M.decode_step_layered(
-                self.params, self.cfg, self.cache, pos, tok,
-                moe_fn=self._moe_two_phase, route_ahead=pipelined)
-        else:
-            with self._dispatch_ctx():
-                logits, self.cache, _ = self._decode_fused(
-                    self.params, self.cache, jnp.asarray(pos, jnp.int32),
-                    tok)
+        with self._dispatch_ctx():
+            logits, self.cache, _ = self._decode_fused(
+                self.params, self.cache, jnp.asarray(pos, jnp.int32), tok)
         logits = self._fault("sample", logits, step=step)
         if self._health_dev is not None:
-            # dispatched, never fetched here: the run-end drain reads it
+            # dispatched, never fetched here: the run-end fetch reads it
             self._health_dev = _health_accum_jit(self.cfg.vocab_size)(
                 logits[:, -1], self._health_dev)
-        if pipelined:
-            # no host sync at all: the sampled token array feeds the next
-            # step's embedding on device; the step wall is dispatch time
-            # (the device drains at the end of decode() -- the drain stat)
-            nxt = self._sample_device(logits[:, -1])
-            self.stats.append(StepStat("decode", step,
-                                       time.monotonic() - t0,
-                                       tokens=tok.shape[0],
-                                       extra={"dispatch_only": True}))
-        else:
-            t_b = time.monotonic()
-            logits = jax.block_until_ready(logits)
-            t_done = time.monotonic()
-            self.stats.append(StepStat(
-                "decode", step, t_done - t0, tokens=tok.shape[0],
-                extra={"logits_wait_s": t_done - t_b}))
-            nxt = self._sample(logits[:, -1])
+        t_b = time.monotonic()
+        logits = jax.block_until_ready(logits)
+        t_done = time.monotonic()
+        self.stats.append(StepStat(
+            "decode", step, t_done - t0, tokens=tok.shape[0],
+            extra={"logits_wait_s": t_done - t_b}))
+        nxt = self._sample(logits[:, -1])
         self.generated.append(nxt)
         return nxt
 
     def decode(self, n: int):
         for _ in range(n):
             self.decode_step()
-        if self.pipeline_depth > 0 and self.generated:
-            # the one host sync of the pipelined decode phase: drain the
-            # whole dispatched chain (tokens + cache + in-flight executes)
-            with self._span("drain", len(self.generated) - 2):
-                jax.block_until_ready((self.generated[-1], self.cache))
-                self._pipe.drain()
 
     # -------------------------------------------------------------- drive --
 
@@ -753,28 +488,17 @@ class ServeLoop(_ServeBase):
 
         Every ``run`` starts from a *fresh* sampling key -- reseeded from
         the constructor's ``sample_seed`` (or ``sample_key`` when given) --
-        so consecutive runs with ``temperature > 0`` are reproducible:
-        before PR 6 the key advanced silently across runs, making every
-        ``run()`` after the first irreproducible."""
+        so consecutive runs with ``temperature > 0`` are reproducible."""
         self.stats.clear()
-        self._exec_keys.clear()
         self._fallback_base = flash_ops.fallback_count()
-        self._pipe.drain()
         self._sample_key = (jax.random.PRNGKey(self._sample_seed)
                             if sample_key is None else sample_key)
         self._health_dev = None
         self.health_rows = None
-        try:
-            self.prefill(prompts, embeddings=embeddings)
-            self.decode(gen - 1)
-        except BaseException:
-            # exception mid-run (host route failure, injected fault, ...):
-            # release every in-flight execute so the loop object stays
-            # usable -- a wedged StreamPipeline was the pre-resilience bug
-            self._pipe.abort()
-            raise
+        self.prefill(prompts, embeddings=embeddings)
+        self.decode(gen - 1)
         if self._health_dev is not None:
-            # the one health fetch of the run, at the existing drain point
+            # the one health fetch of the run
             self.health_rows = np.asarray(self._health_dev)
             bad = int((~self.health_rows).sum())
             if bad:
@@ -782,24 +506,13 @@ class ServeLoop(_ServeBase):
         return np.asarray(jnp.concatenate(self.generated, axis=1))
 
     def summary(self) -> Dict[str, Any]:
-        """Aggregate per-phase seconds / counts for the last ``run``.
-
-        Note the phases are NOT disjoint in two-phase mode: each "decode"
-        step stat (and the "prefill" stat) times the whole layered pass,
-        *inclusive* of the "route" / "execute" layer calls made inside it
-        (those entries break the pass down; do not sum them with "decode"
-        or "prefill").
-
-        In pipelined mode the decode-step stats are dispatch walls; the
-        final "drain" stat is the real device wait, so tok/s is computed
-        over decode + drain -- honest wall-clock either way."""
+        """Aggregate per-phase seconds / counts for the last ``run``; decode
+        tok/s is over the blocked decode-step walls."""
         out = self._phase_summary()
         dec = out.get("decode")
-        if dec:
-            wall = dec["seconds"] + out.get("drain", {}).get("seconds", 0.0)
-            if wall > 0:
-                batch = self.generated[0].shape[0] if self.generated else 0
-                out["decode"]["tok_per_s"] = batch * dec["calls"] / wall
+        if dec and dec["seconds"] > 0:
+            batch = self.generated[0].shape[0] if self.generated else 0
+            out["decode"]["tok_per_s"] = batch * dec["calls"] / dec["seconds"]
         if self.health_rows is not None:
             out["health"]["rows_finite"] = self.health_rows.tolist()
         return out
@@ -853,8 +566,8 @@ class ServeScheduler(_ServeBase):
     *slots* (batch rows of one shared decode cache).  Between decode steps
     the scheduler **evicts** finished sequences (token budget reached or
     EOS) and **admits** queued prompts into the freed rows: each admission
-    runs a single-request prefill (fused or layered two-phase, same as
-    :class:`ServeLoop`) and scatters the resulting cache into the slot row
+    runs a single-request prefill (the same program as :class:`ServeLoop`'s)
+    and scatters the resulting cache into the slot row
     -- attention KV, MoE routing occupancy, and recurrent state are all
     batch-row-indexed (see ``model.init_cache``), so neighbours are
     untouched.  Decode then advances *every* resident sequence one token in
@@ -862,10 +575,9 @@ class ServeScheduler(_ServeBase):
 
     **Batch-bucket law.**  The decode step runs on cache rows
     ``[0, batch_bucket(highest occupied slot + 1))`` --
-    ``engine.batch_bucket`` is the PR-3 power-of-two stream-bucket law
-    applied to the batch dim -- so the compiled decode-step shapes (and the
-    phase-2 execute signatures in two-phase mode) are bounded by
-    (batch buckets x nnzb buckets), never one per occupancy pattern.
+    ``engine.batch_bucket`` is the power-of-two stream-bucket law applied
+    to the batch dim -- so the compiled decode-step shapes are bounded by
+    the batch buckets, never one per occupancy pattern.
     Vacant rows inside the bucket still compute (their results are masked
     at sampling and their cache rows are fully overwritten at the next
     admission); per-row independence keeps them from perturbing residents.
@@ -876,26 +588,23 @@ class ServeScheduler(_ServeBase):
     generated tokens are token-identical to a sequential single-request
     :class:`ServeLoop` with the same ``max_seq``.
 
-    **Sampling.**  Every decode tick samples on the device at every
-    ``pipeline_depth``: one compiled program (``_sampler_health_jit``) over
-    the step's logits, and one ``jax.device_get`` of the token ids and the
-    per-row health bits; ``pipeline_depth`` selects only the MoE
-    route/execute pipelining.  An admission's first token is still sampled
-    on host (``_sample_one``).
+    **Sampling.**  Every decode tick samples on the device: one compiled
+    program (``_sampler_health_jit``) over the step's logits, and one
+    ``jax.device_get`` of the token ids and the per-row health bits.  An
+    admission's first token is still sampled on host (``_sample_one``).
 
-    **Commit point.**  The slot pool is donated wherever one program owns
-    it.  On the fused path ``jit_decode_step`` takes the whole pool,
-    decodes rows ``[0, bucket)`` and writes them back inside the program;
-    the ``writeback`` span only makes the returned pool ``self.cache``.
-    An admission writes its row with one donated program after the poison
-    gate.  The layered two-phase path slices the pool eagerly and scatters
-    the step's rows back in ``writeback``: its cache flows through
-    per-layer programs with host yields.
+    **Commit point.**  The slot pool is donated to every program that
+    writes it.  ``jit_decode_step`` takes the whole pool, decodes rows
+    ``[0, bucket)`` and writes them back inside the program; the
+    ``writeback`` span only makes the returned pool ``self.cache``.  An
+    admission writes its row with one donated program after the poison
+    gate.
+
+    ``pipeline_depth`` accepts only 0, the one serving mode.
     """
 
     def __init__(self, params, cfg, *, max_seq: int, max_slots: int = 8,
                  dispatch: Optional[str] = None,
-                 two_phase: Optional[bool] = None,
                  temperature: float = 0.0, sample_seed: int = 3,
                  batch_min_bucket: int = 1, cache_dtype=jnp.bfloat16,
                  pipeline_depth: int = 0,
@@ -908,9 +617,12 @@ class ServeScheduler(_ServeBase):
                  max_queue: Optional[int] = None,
                  shed_policy: str = "reject",
                  clock=None):
-        super().__init__(params, cfg, dispatch=dispatch, two_phase=two_phase,
+        if pipeline_depth != 0:
+            raise ValueError(
+                f"ServeScheduler: pipeline_depth must be 0, got "
+                f"{pipeline_depth!r}; decode ticks are not pipelined")
+        super().__init__(params, cfg, dispatch=dispatch,
                          temperature=temperature, sample_seed=sample_seed,
-                         pipeline_depth=pipeline_depth,
                          quantize_experts=quantize_experts,
                          kv_quant=kv_quant, attn_mask=attn_mask,
                          fault_plan=fault_plan, retry=retry,
@@ -938,20 +650,16 @@ class ServeScheduler(_ServeBase):
         self._clock = clock if clock is not None else time.monotonic
         self._sleep = time.sleep
         self.step_idx = 0
-        self._stat_step = -1
         self._next_uid = 0
         self.batch_buckets: set = set()
         # the undonated step, for callers that keep the pool they pass
         self._decode_fused = _decode_program(cfg)
         self._decode_inplace = _decode_inplace_program(cfg)
-        # (logits, advanced pool or None once committed, held) of a fused
-        # step whose forward ran and whose tick has not finished
+        # (logits, advanced pool or None once committed, held) of a step
+        # whose forward ran and whose tick has not finished
         self._advanced: Optional[Tuple[Any, Any, Any]] = None
 
     # -------------------------------------------------------------- admit --
-
-    def _step_label(self) -> int:
-        return self._stat_step
 
     def submit(self, prompt, max_new_tokens: int,
                eos_id: Optional[int] = None,
@@ -1073,12 +781,11 @@ class ServeScheduler(_ServeBase):
     def _prefill_into(self, req: Request, slot: int) -> bool:
         """Single-request prefill into cache row ``slot``, with bounded
         exponential-backoff retry (``RetryPolicy``).  Failed attempts --
-        a retryable host-side exception (``resilience.RETRYABLE``) anywhere
-        in the layered pass, or non-finite
-        first-token logits -- leave the shared cache and the request's key
-        chain untouched (the health check runs BEFORE the scatter and
-        before any key split), so a retry reproduces the fault-free
-        prefill bit-for-bit.  Returns False once retries are exhausted
+        a retryable host-side exception (``resilience.RETRYABLE``) or
+        non-finite first-token logits -- leave the shared cache and the
+        request's key chain untouched (the health check runs BEFORE the
+        scatter and before any key split), so a retry reproduces the
+        fault-free prefill bit-for-bit.  Returns False once retries are exhausted
         (the request is moved to FAILED and the slot stays free)."""
         last_reason = "prefill_failed"
         for attempt in range(self.retry.max_retries + 1):
@@ -1092,7 +799,6 @@ class ServeScheduler(_ServeBase):
             try:
                 ok = self._prefill_attempt(req, slot)
             except R.RETRYABLE as e:
-                self._pipe.abort()
                 last_reason = f"prefill_error:{type(e).__name__}"
                 self.health.record("prefill_error", uid=req.uid,
                                    error=type(e).__name__)
@@ -1108,29 +814,19 @@ class ServeScheduler(_ServeBase):
 
     def _prefill_attempt(self, req: Request, slot: int) -> bool:
         """One prefill try; False = non-finite logits (poisoned)."""
-        self._stat_step = -1
         self._row_uids = [req.uid]
         prompts = jnp.asarray(req.prompt[None, :])
         with self._span("prefill", self.step_idx, tokens=req.prompt_len,
                         uid=req.uid, slot=slot) as st:
             try:
-                if self.two_phase:
-                    logits, cache1, pos = M.prefill_layered(
-                        self.params, prompts, self.cfg, max_seq=self.max_seq,
+                with self._dispatch_ctx():
+                    logits, cache1, pos = M.prefill(
+                        self.params, prompts, self.cfg,
+                        max_seq=self.max_seq,
                         cache_dtype=self.cache_dtype,
-                        moe_fn=self._moe_two_phase,
-                        route_ahead=self.pipeline_depth > 0,
                         kv_quant=self.kv_quant, attn_mask=self.attn_mask)
-                else:
-                    with self._dispatch_ctx():
-                        logits, cache1, pos = M.prefill(
-                            self.params, prompts, self.cfg,
-                            max_seq=self.max_seq,
-                            cache_dtype=self.cache_dtype,
-                            kv_quant=self.kv_quant, attn_mask=self.attn_mask)
                 logits, cache1 = self._sync(jax.block_until_ready,
                                             (logits, cache1))
-                self._pipe.drain()  # prefill executes completed with logits
                 logits = self._fault("prefill", logits)
             finally:
                 self._row_uids = None
@@ -1185,15 +881,13 @@ class ServeScheduler(_ServeBase):
 
         Failure handling (the per-request isolation contract,
         tests/test_resilience.py): a retryable host-side exception
-        (``resilience.RETRYABLE``) in the step aborts the stream pipeline
-        and retries under the ``RetryPolicy``.  No key split or token
-        append happens before the failure can surface.  The layered path
-        retries the whole step: it writes no cache before its write-back.
-        The fused path commits at its forward (the donated pool comes back
-        advanced), so its retry samples again from the logits in hand and
-        never runs the forward twice.  Either way the retry reproduces the
-        fault-free step exactly.  A step that exhausts its retries leaves
-        the advanced pool in ``self.cache`` and raises.  A
+        (``resilience.RETRYABLE``) in the step retries under the
+        ``RetryPolicy``.  No key split or token append happens before the
+        failure can surface.  The step commits at its forward (the donated
+        pool comes back advanced), so its retry samples again from the
+        logits in hand and never runs the forward twice: the retry
+        reproduces the fault-free step exactly.  A step that exhausts its
+        retries leaves the advanced pool in ``self.cache`` and raises.  A
         *poisoned* row (non-finite sampled logits, detected by health bits
         piggybacked on the token fetch) fails only ITS request: the row is
         evicted and scatter-blanked, the token discarded, and every
@@ -1223,7 +917,6 @@ class ServeScheduler(_ServeBase):
                 try:
                     return self._decode_attempt(active)
                 except R.RETRYABLE as e:
-                    self._pipe.abort()
                     # a degradation rung may rebuild the live cache
                     self._commit_advanced()
                     err = e
@@ -1238,7 +931,7 @@ class ServeScheduler(_ServeBase):
             self._advanced = None
 
     def _commit_advanced(self):
-        """Make the pool a fused step's forward advanced ``self.cache``
+        """Make the pool a step's forward advanced ``self.cache``
         ahead of its write-back, keeping the step's logits for a retry:
         once the forward ran, the pool it was given is gone."""
         if self._advanced is not None and self._advanced[1] is not None:
@@ -1248,15 +941,13 @@ class ServeScheduler(_ServeBase):
     def _decode_attempt(self, active: List[Request]) -> List[Tuple[Request, int]]:
         """One decode-step try over the occupied slot prefix.
 
-        The fused path commits at its forward: ``jit_decode_step`` takes
-        the whole slot pool donated and returns it with the step's rows
+        The step commits at its forward: ``jit_decode_step`` takes the
+        whole slot pool donated and returns it with the step's rows
         written, held in ``self._advanced`` until the ``writeback`` span
         makes it ``self.cache`` (at once if the step fails after its
         forward, so a degradation rung rebuilds the live pool).  A retry of
         the same step samples again from those logits and never runs the
-        forward twice.  The layered path reads the step's rows eagerly and
-        scatters the new rows back in ``writeback``; it commits nothing
-        before that."""
+        forward twice."""
         hi = max(i for i, r in enumerate(self.slots) if r is not None) + 1
         bucket = engine.batch_bucket(hi, minimum=self.batch_min_bucket,
                                      cap=self.n_slots)
@@ -1267,35 +958,23 @@ class ServeScheduler(_ServeBase):
             if r is not None:
                 pos_vec[i] = r.pos
                 tok_vec[i, 0] = r.tokens[-1]
-        in_place = not self.two_phase
         if self._advanced is None:
             # quantize-stage faults corrupt live scale rows mid-stream
             self.cache = self._fault_cache(
                 self.cache, step=self.step_idx,
                 uids=[r.uid if r is not None else None for r in self.slots],
                 nrows=self.n_slots)
-        step_cache = (self.cache if in_place else
-                      jax.tree.map(lambda a: a[:, :bucket], self.cache))
-        self._stat_step = self.step_idx
         self._row_uids = [r.uid if r is not None else None
                           for r in self.slots[:bucket]]
-        pipelined = self.pipeline_depth > 0
         with self._span("decode", self.step_idx, tokens=len(active),
-                        batch_bucket=bucket, active=len(active),
-                        pipelined=pipelined) as st:
-            new_cache, toks, fin, held = self._decode_forward(
-                step_cache, pos_vec, tok_vec, bucket, pipelined)
+                        batch_bucket=bucket, active=len(active)) as st:
+            pool, toks, fin, held = self._decode_forward(pos_vec, tok_vec,
+                                                         bucket)
             if held is not None:
                 st.extra["moe_held_pairs"] = int(held)
         dt = st.seconds
-        with self._span("writeback", self.step_idx, in_place=in_place):
-            if in_place:
-                self.cache, self._advanced = new_cache, None
-            else:
-                self.cache = jax.tree.map(
-                    lambda big, small: big.at[:, :bucket].set(
-                        small.astype(big.dtype)),
-                    self.cache, new_cache)
+        with self._span("writeback", self.step_idx):
+            self.cache, self._advanced = pool, None
         emitted = []
         with self._span("sample", self.step_idx):
             for i, r in enumerate(self.slots[:bucket]):
@@ -1317,33 +996,22 @@ class ServeScheduler(_ServeBase):
                 self._finish_or_keep(r, tok)
         return emitted
 
-    def _decode_forward(self, step_cache, pos_vec, tok_vec, bucket: int,
-                        pipelined: bool):
+    def _decode_forward(self, pos_vec, tok_vec, bucket: int):
         """The step's forward through the health fetch: returns
-        ``(new_cache, toks, fin, held)``, ``toks`` the sampled ids, ``fin``
-        the per-row isfinite bits and ``held`` the step's (token, held
-        expert) pairs (None without a dropless expert share), all on the
-        host.  ``new_cache`` is the step's rows on the layered path and the
-        whole advanced pool on the fused one, where ``step_cache`` is the
-        pool to donate (unused when this step's forward already ran).
-        ``pipelined`` only selects the MoE route/execute pipelining of the
-        layered path."""
-        held = None
+        ``(pool, toks, fin, held)``, ``pool`` the advanced slot pool,
+        ``toks`` the sampled ids, ``fin`` the per-row isfinite bits and
+        ``held`` the step's (token, held expert) pairs (None without a
+        dropless expert share), the last three on the host.  The forward
+        donates ``self.cache`` unless this step's forward already ran."""
         try:
-            if self.two_phase:
-                logits, new_cache = M.decode_step_layered(
-                    self.params, self.cfg, step_cache, pos_vec,
-                    jnp.asarray(tok_vec), moe_fn=self._moe_two_phase,
-                    route_ahead=pipelined)
-            else:
-                if self._advanced is None:
-                    with self._dispatch_ctx():
-                        self._advanced = self._decode_inplace(
-                            self.params, step_cache, jnp.asarray(pos_vec),
-                            jnp.asarray(tok_vec), bucket=bucket)
-                logits, new_cache, held = self._advanced
-                if new_cache is None:    # committed when the retry began
-                    new_cache = self.cache
+            if self._advanced is None:
+                with self._dispatch_ctx():
+                    self._advanced = self._decode_inplace(
+                        self.params, self.cache, jnp.asarray(pos_vec),
+                        jnp.asarray(tok_vec), bucket=bucket)
+            logits, pool, held = self._advanced
+            if pool is None:    # committed when the retry began
+                pool = self.cache
             # the sample hook fires BEFORE any per-request key split below,
             # so a sample-stage exception retries with key chains intact
             logits = self._fault("sample", logits, step=self.step_idx)
@@ -1366,10 +1034,9 @@ class ServeScheduler(_ServeBase):
                     keys.append(dummy)
             key_arr = jnp.stack(keys)
         toks, fin = _sampler_health_jit(
-            self.cfg.vocab_size, float(self.temperature), True)(
-                logits, key_arr)
+            self.cfg.vocab_size, float(self.temperature))(logits, key_arr)
         toks, fin, held = self._sync(jax.device_get, (toks, fin, held))
-        return new_cache, toks, fin, held
+        return pool, toks, fin, held
 
     # -------------------------------------------------------------- drive --
 
@@ -1401,9 +1068,8 @@ class ServeScheduler(_ServeBase):
                 for r in self.finished}
 
     def summary(self) -> Dict[str, Any]:
-        """Aggregate serving stats: per-phase seconds (decode inclusive of
-        route/execute in two-phase mode, as in :class:`ServeLoop`), decode
-        tok/s over *emitted* tokens, per-token and first-token latency
+        """Aggregate serving stats: per-phase seconds, decode tok/s over
+        *emitted* tokens, per-token and first-token latency
         percentiles, and the bucket accounting that bounds recompiles."""
         out = self._phase_summary()
         dec = out.get("decode")
@@ -1429,10 +1095,6 @@ class ServeScheduler(_ServeBase):
         out["health"]["shed"] = [
             {"uid": r.uid, "reason": r.fail_reason} for r in self.shed]
         out["batch_buckets"] = sorted(self.batch_buckets)
-        if self.two_phase:
-            out["nnzb_buckets"] = sorted(
-                {sig[3][0] for sig in self._exec_keys
-                 if sig[3] is not None})
         return out
 
 
@@ -1449,13 +1111,6 @@ def main():
     ap.add_argument("--dispatch", choices=["config", "gather", "bcsr"],
                     default="config",
                     help="MoE dispatch backend (config = the arch's field)")
-    ap.add_argument("--two-phase", choices=["auto", "on", "off"],
-                    default="auto",
-                    help="route-then-compile decode (auto = when moe+bcsr)")
-    ap.add_argument("--pipeline-depth", type=int, choices=[0, 1], default=0,
-                    help="0 = serial (block every phase), 1 = pipelined "
-                         "(route-ahead + in-flight executes + on-device "
-                         "sampling; token-identical to 0)")
     ap.add_argument("--continuous", action="store_true",
                     help="drive the continuous-batching scheduler on a "
                          "synthetic multi-user trace instead of one static "
@@ -1492,7 +1147,6 @@ def main():
         cfg.frontend_tokens if cfg.frontend != "none" else 0)
 
     dispatch = None if args.dispatch == "config" else args.dispatch
-    two_phase = None if args.two_phase == "auto" else args.two_phase == "on"
     attn_mask = None
     if args.attn_mask != "none":
         pattern = None if args.attn_mask == "sliding" else args.attn_mask
@@ -1503,9 +1157,7 @@ def main():
         rng = np.random.default_rng(0)
         sched = ServeScheduler(
             params, cfg, max_seq=max_seq, max_slots=args.slots,
-            dispatch=dispatch, two_phase=two_phase,
-            temperature=args.temperature,
-            pipeline_depth=args.pipeline_depth,
+            dispatch=dispatch, temperature=args.temperature,
             quantize_experts=args.quantize_experts,
             kv_quant=args.kv_quant, attn_mask=attn_mask)
         for _ in range(args.requests):
@@ -1518,20 +1170,11 @@ def main():
         s = sched.summary()
         dec = s.get("decode", {"seconds": 0.0, "calls": 0})
         print(f"served {len(gen)} requests in {sched.step_idx} steps "
-              f"({dec.get('tok_per_s', 0.0):.1f} decode tok/s)"
-              + (" [two-phase]" if sched.two_phase else ""))
+              f"({dec.get('tok_per_s', 0.0):.1f} decode tok/s)")
         lat = s["token_latency_ms"]
         print(f"per-token latency: p50 {lat['p50']:.1f} ms, "
               f"p99 {lat['p99']:.1f} ms over {lat['n']} tokens")
-        print(f"batch buckets: {s['batch_buckets']}"
-              + (f"; nnzb buckets: {s['nnzb_buckets']}; "
-                 f"{s['compile_signatures']} phase-2 signature(s)"
-                 if sched.two_phase else ""))
-        if args.pipeline_depth and "timing" in s:
-            tm = s["timing"]
-            print(f"overlap: {tm['route_hidden_ms']:.1f} ms of route hidden "
-                  f"behind in-flight execute "
-                  f"({100 * tm['route_hidden_frac']:.0f}% of route)")
+        print(f"batch buckets: {s['batch_buckets']}")
         for uid in sorted(gen)[:2]:
             print(f"  [{uid}] {gen[uid][:16].tolist()}")
         return
@@ -1546,8 +1189,8 @@ def main():
             (args.batch, cfg.frontend_tokens, cfg.d_model))
 
     loop = ServeLoop(
-        params, cfg, max_seq=max_seq, dispatch=dispatch, two_phase=two_phase,
-        temperature=args.temperature, pipeline_depth=args.pipeline_depth,
+        params, cfg, max_seq=max_seq, dispatch=dispatch,
+        temperature=args.temperature,
         quantize_experts=args.quantize_experts, kv_quant=args.kv_quant,
         attn_mask=attn_mask)
     gen = loop.run(prompts, args.gen, embeddings=emb)
@@ -1558,22 +1201,7 @@ def main():
           f"{args.batch}x{args.prompt_len}")
     dec = s.get("decode", {"seconds": 0.0, "calls": 0})  # --gen 1: no steps
     print(f"decode:  {dec['seconds']*1e3:.1f} ms for {dec['calls']} steps "
-          f"({dec.get('tok_per_s', 0.0):.1f} tok/s)"
-          + (" [two-phase]" if loop.two_phase else ""))
-    for phase in ("route", "execute"):
-        if phase in s:
-            print(f"{phase}:   {s[phase]['seconds']*1e3:.1f} ms over "
-                  f"{s[phase]['calls']} layer calls (within prefill+decode)")
-    if "stream" in s:
-        st = s["stream"]
-        print(f"stream:  nnzb {st['nnzb_stream_mean']:.1f} (bucketed) vs "
-              f"{st['grid_nnzb']} full-grid blocks; "
-              f"{s['compile_signatures']} phase-2 compile signature(s)")
-    if args.pipeline_depth and "timing" in s:
-        tm = s["timing"]
-        print(f"overlap: {tm['route_hidden_ms']:.1f} ms of route hidden "
-              f"behind in-flight execute "
-              f"({100 * tm['route_hidden_frac']:.0f}% of route)")
+          f"({dec.get('tok_per_s', 0.0):.1f} tok/s)")
     print("sample generations (token ids):")
     for b in range(min(args.batch, 2)):
         print(f"  [{b}] {gen[b, :16].tolist()}")

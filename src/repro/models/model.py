@@ -12,16 +12,18 @@ ring buffers bounded by the window (what makes gemma-3 long_500k decodable).
 A hybrid stack keeps two kinds of per-row state side by side: ``gdn+moe``
 layers a conv tail and a float32 recurrent state, attention layers a KV
 cache; both are indexed by batch row.
+
+Serving runs :func:`prefill` and :func:`decode_step` as whole-stack programs,
+MoE layers included: the model has one execution path.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.masks import NEG_INF, AttnMaskSpec
+from repro.core.masks import NEG_INF
 from repro.core.precision import policy as precision_policy
 from repro.models.config import ArchConfig
 from repro.models import layers as L
@@ -94,12 +96,10 @@ def _window_for(kind: str, cfg: ArchConfig) -> Optional[int]:
 
 
 def apply_block(kind: str, p: Params, x, cfg: ArchConfig, *, impl="chunked",
-                cache=None, pos=None, collect_kv: int = 0, moe_fn=None,
+                cache=None, pos=None, collect_kv: int = 0,
                 kv_quant: Optional[str] = None, attn_mask=None):
     """One sub-layer. Returns (x, new_cache). ``collect_kv`` > 0 makes the
-    prefill path emit a decode cache of that capacity.  ``moe_fn`` overrides
-    ``moe.apply_moe`` for attn+moe blocks (same signature/returns) -- the
-    two-phase serving loop injects its route-then-execute stage here.
+    prefill path emit a decode cache of that capacity.
     ``kv_quant`` (prefill only) collects full-context attention caches as
     per-position narrow values + f32 scales (see ``layers.apply_attention``);
     decode detects a quantized cache by its scale leaves, no flag needed.
@@ -118,7 +118,7 @@ def apply_block(kind: str, p: Params, x, cfg: ArchConfig, *, impl="chunked",
         if kind == "attn+moe":
             # thread the routing occupancy (prefix-stable slots): decode
             # passes the cached per-(row, expert) counts + absolute position
-            f, moe_counts = (moe_fn or moe.apply_moe)(
+            f, moe_counts = moe.apply_moe(
                 p["ffn"], h, cfg, counts=cache.get("moe") if cache else None,
                 pos=pos)
         else:
@@ -140,7 +140,7 @@ def apply_block(kind: str, p: Params, x, cfg: ArchConfig, *, impl="chunked",
                                  collect=bool(collect_kv))
         x = x + a
         h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        f, moe_state = (moe_fn or moe.apply_moe)(p["ffn"], h, cfg, pos=pos)
+        f, moe_state = moe.apply_moe(p["ffn"], h, cfg, pos=pos)
         x = x + f
         if new_g is None:
             return x, None
@@ -462,7 +462,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
     return out
 
 
-def _decode_block_attn(kind, p, x, cfg, cache, pos, dtype, moe_fn=None):
+def _decode_block_attn(kind, p, x, cfg, cache, pos, dtype):
     """Attention decode with ring-buffer handling for local layers.
 
     ``pos`` is an int32 scalar (whole-batch decode) or a ``(B,)`` vector of
@@ -502,7 +502,7 @@ def _decode_block_attn(kind, p, x, cfg, cache, pos, dtype, moe_fn=None):
         # ring buffers exist only for attn_local layers, which are never MoE
         f = L.apply_mlp(p["ffn"], h, cfg)
         return x + f, {"attn": {"k": knew, "v": vnew}}
-    return apply_block(kind, p, x, cfg, cache=cache, pos=pos, moe_fn=moe_fn)
+    return apply_block(kind, p, x, cfg, cache=cache, pos=pos)
 
 
 def blank_cache_row(cache, row: int):
@@ -524,62 +524,6 @@ def blank_cache_row(cache, row: int):
         return a.at[:, row].set(fill(a.shape[2:], a.dtype))
 
     return jax.tree_util.tree_map_with_path(blank, cache)
-
-
-def cache_capacity(cache, *, ring_window: Optional[int] = None) -> Optional[int]:
-    """Static sequence capacity of a decode cache: the minimum cache length
-    over its full (non-ring) attention slots, or None for cache-free /
-    attention-free stacks.  Ring buffers (``attn_local``) wrap by
-    construction and never overflow, so when ``ring_window`` is given
-    (``cfg.local_window``) leaves of exactly that length are excluded --
-    decode identifies rings the same way (``Lc == window`` in
-    ``_decode_block_attn``).  This is what callers must host-check ``pos``
-    against before a decode write: the cache update is a
-    ``dynamic_update_slice`` / scatter, and XLA *clamps / drops*
-    out-of-bounds writes instead of failing, which silently corrupts the
-    last cache slot (see ``ServeLoop.decode_step``)."""
-    caps = []
-
-    def visit(node):
-        if isinstance(node, dict):
-            if "attn" in node and isinstance(node["attn"], dict) \
-                    and "k" in node["attn"]:
-                caps.append(node["attn"]["k"].shape[3])
-            else:
-                for v in node.values():
-                    visit(v)
-        elif isinstance(node, (tuple, list)):
-            for v in node:
-                visit(v)
-
-    visit(cache)
-    if ring_window is not None:
-        caps = [c for c in caps if c != ring_window]
-    return min(caps) if caps else None
-
-
-def check_cache_fits(cache, pos, *, who: str = "decode_step",
-                     cfg: Optional[ArchConfig] = None):
-    """Raise (host-side) when a concrete ``pos`` would write past the decode
-    cache capacity.  ``pos`` may be a scalar or a per-row vector; traced
-    positions are the caller's responsibility (the fused jit path cannot
-    host-check -- ``ServeLoop`` checks before dispatching).  Pass ``cfg`` so
-    local-layer ring buffers (capacity = ``cfg.local_window``, wrap forever)
-    are not mistaken for the overflow bound."""
-    if isinstance(pos, jax.core.Tracer):
-        return
-    ring = cfg.local_window if cfg is not None else None
-    cap = cache_capacity(cache, ring_window=ring)
-    if cap is None:
-        return
-    import numpy as _np
-    top = int(_np.max(_np.asarray(pos)))
-    if top >= cap:
-        raise ValueError(
-            f"{who}: KV-cache overflow -- write position {top} >= cache "
-            f"capacity {cap} (max_seq). The cache update would be silently "
-            "clamped by XLA, corrupting the last cache slot and generating "
-            "garbage tokens; grow max_seq or stop the sequence.")
 
 
 def moe_held_pairs(cfg: ArchConfig, cache) -> Optional[jax.Array]:
@@ -646,344 +590,3 @@ def decode_step(params: Params, cfg: ArchConfig, cache, pos, tokens_1,
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     unemb = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
     return (x @ unemb.astype(cd)).astype(jnp.float32), new_cache
-
-
-# --- cached jitted per-layer steps -------------------------------------------
-#
-# The layered decode/prefill paths below interleave *host* work (two-phase
-# MoE routing) between layers, which rules out one whole-model jit.  Running
-# every layer op-by-op instead taxes each decode step with hundreds of eager
-# dispatches (the PR-3 "host-dispatch tax").  Middle ground: one jitted
-# program per (cfg, layer kind) -- lru-cached here, while jit's own cache
-# keys the (x, cache, pos) *shapes* -- so a whole decode phase reuses a
-# handful of compiled programs and the only eager seams left are the
-# intentional host routing yields.  Each program takes the repeat-stacked
-# params and the layer index and slices the layer inside (:func:`_layer`):
-# an eager ``stack[i]`` would copy the layer's weights on every call, and
-# for a layer as large as the free device memory it cannot be made at all.
-
-@functools.lru_cache(maxsize=None)
-def _layer_decode_jit(cfg: ArchConfig, kind: str):
-    """Whole-layer one-token decode step (any kind; attn+moe dispatches its
-    MoE in-trace, i.e. without the two-phase host yield)."""
-    def fn(stack, i, x, cache, pos):
-        p = _layer(stack, i)
-        if kind in ATTN_KINDS:
-            return _decode_block_attn(kind, p, x, cfg, cache, pos, None)
-        return apply_block(kind, p, x, cfg, cache=cache, pos=pos)
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=None)
-def _layer_decode_attn_head_jit(cfg: ArchConfig):
-    """The attention half of an attn+moe decode layer, up to the host MoE
-    yield: ln1 + attention + residual + ln2.  Returns (x_mid, h, new_attn).
-    attn+moe layers never use ring buffers (see _decode_block_attn)."""
-    def fn(stack, i, x, attn_cache, pos):
-        p = _layer(stack, i)
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, new_attn = L.apply_attention(
-            p["attn"], h, cfg, window=None, impl="chunked", cache=attn_cache,
-            cache_len=pos, collect_kv=0)
-        x = x + a
-        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        return x, h, new_attn
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=None)
-def _layer_decode_attn_route_jit(cfg: ArchConfig, capacity: int):
-    """The attention half of an attn+moe decode layer FUSED with MoE route
-    phase 1 (``moe.route_phase1``): ln1 + attention + residual + ln2 +
-    router matmul + prefix-stable slot cumsums, one program.  The pipelined
-    serving loop (``pipeline_depth=1``) uses this so each layer's routing
-    arrays are dispatched *with* its attention -- one program ahead of the
-    host route stage -- and the host then fetches only the small ``(B, S)``
-    slot stream (``moe.plan_from_phase1``), never the hidden state.
-    ``capacity`` is the static dispatch capacity the slot encoding assumes
-    (always 1 for single-token decode, see ``moe.dispatch_capacity``)."""
-    def fn(stack, i, x, attn_cache, counts, pos):
-        p = _layer(stack, i)
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, new_attn = L.apply_attention(
-            p["attn"], h, cfg, window=None, impl="chunked", cache=attn_cache,
-            cache_len=pos, collect_kv=0)
-        x = x + a
-        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        ph1 = moe.route_phase1(p["ffn"]["router"], h, cfg, counts, pos,
-                               capacity)
-        return x, h, new_attn, ph1
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=None)
-def _layer_prefill_jit(cfg: ArchConfig, kind: str, collect_kv: int,
-                       impl: str, kv_quant: Optional[str] = None,
-                       attn_mask: Optional[AttnMaskSpec] = None):
-    """Whole-layer prefill step (cache-collecting forward).  ``attn_mask``
-    is a frozen (hashable) AttnMaskSpec so mask-routed prefills share this
-    cache; the concrete BlockMask is built at trace time from the static
-    sequence length."""
-    def fn(stack, i, x):
-        return apply_block(kind, _layer(stack, i), x, cfg, impl=impl,
-                           collect_kv=collect_kv,
-                           kv_quant=kv_quant, attn_mask=attn_mask)
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=None)
-def _layer_prefill_attn_head_jit(cfg: ArchConfig, kind: str, collect_kv: int,
-                                 impl: str, kv_quant: Optional[str] = None,
-                                 attn_mask: Optional[AttnMaskSpec] = None):
-    """Prefill attention half of an attn+moe layer (up to the MoE yield)."""
-    def fn(stack, i, x):
-        p = _layer(stack, i)
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, new_attn = L.apply_attention(
-            p["attn"], h, cfg, window=_window_for(kind, cfg), impl=impl,
-            cache=None, cache_len=None, collect_kv=collect_kv,
-            kv_quant=kv_quant, attn_mask=attn_mask)
-        x = x + a
-        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        return x, h, new_attn
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=None)
-def _layer_prefill_attn_route_jit(cfg: ArchConfig, kind: str,
-                                  collect_kv: int, impl: str, capacity: int,
-                                  kv_quant: Optional[str] = None,
-                                  attn_mask: Optional[AttnMaskSpec] = None):
-    """Prefill twin of :func:`_layer_decode_attn_route_jit`: attention half
-    fused with MoE route phase 1 for a fresh sequence (zero occupancy,
-    position 0); ``capacity`` is static per prompt length."""
-    def fn(stack, i, x):
-        p = _layer(stack, i)
-        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, new_attn = L.apply_attention(
-            p["attn"], h, cfg, window=_window_for(kind, cfg), impl=impl,
-            cache=None, cache_len=None, collect_kv=collect_kv,
-            kv_quant=kv_quant, attn_mask=attn_mask)
-        x = x + a
-        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        ph1 = moe.route_phase1(p["ffn"]["router"], h, cfg, None, 0, capacity)
-        return x, h, new_attn, ph1
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=None)
-def _final_logits_jit(cfg: ArchConfig, last_only: bool):
-    """final rmsnorm + unembed matmul as one program (``last_only`` takes
-    the trailing position first, the prefill contract)."""
-    def fn(norm_p, emb_or_unemb, x):
-        if last_only:
-            x = x[:, -1:]
-        x = L.rmsnorm(norm_p, x, cfg.norm_eps)
-        unemb = emb_or_unemb.T if cfg.tie_embeddings else emb_or_unemb
-        return (x @ unemb.astype(x.dtype)).astype(jnp.float32)
-    return jax.jit(fn)
-
-
-def _unemb_param(params: Params, cfg: ArchConfig):
-    return params["embed"] if cfg.tie_embeddings else params["unembed"]
-
-
-def _tree_take(tree, i):
-    """Slice index ``i`` off every leaf's leading (repeat) dim."""
-    return jax.tree.map(lambda a: a[i], tree)
-
-
-def _layer(stack, i):
-    """Layer ``i`` of a repeat-stacked param tree; ``i=None`` means
-    ``stack`` is a single layer already (the shared attention block)."""
-    return stack if i is None else _tree_take(stack, i)
-
-
-def _tree_stack(per_step):
-    """Re-stack per-repeat cache trees along a new leading dim."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *per_step)
-
-
-def decode_step_layered(params: Params, cfg: ArchConfig, cache, pos,
-                        tokens_1, dtype=jnp.bfloat16, *, moe_fn=None,
-                        route_ahead: bool = False
-                        ) -> Tuple[jax.Array, Any]:
-    """One-token decode with the repeat loop unrolled at the Python level.
-
-    Computes the same function as :func:`decode_step`, but layer by layer
-    instead of one ``lax.scan`` -- which is what lets a serving loop
-    interleave *host-side* work between layers: the two-phase MoE stage
-    (``launch.serve.ServeLoop``) routes each attn+moe layer on host and runs
-    only the expert/combine phase compiled, something a scan body can never
-    yield back for.  Every layer runs as a cached jitted step
-    (:func:`_layer_decode_jit` / :func:`_layer_decode_attn_head_jit`, keyed
-    on (cfg, kind) here and on the x/cache shapes by jit itself), so the
-    host-dispatch tax is one call per layer, not one per op.  ``moe_fn`` is
-    threaded to every attn+moe block with the repeat-stacked ffn params and
-    ``layer=i`` (signature of ``moe.route_moe`` + ``moe.execute_moe``, which
-    slice the layer themselves);
-    ``pos`` should be concrete here (a Python int, or an int ``(B,)``
-    numpy vector for continuous batching -- per-row positions ride through
-    attention writes, RoPE, and the prefix-stable MoE occupancy exactly like
-    the scalar path does per row) so host routing sees real positions -- it
-    rides into the jitted steps as a traced scalar/vector, so new positions
-    do NOT retrace.  Being concrete, ``pos`` is also host-checked against
-    the cache capacity here (:func:`check_cache_fits`) -- the layered guard
-    against the silent out-of-bounds write clamp.  ``dtype`` is accepted for
-    signature parity with :func:`decode_step` and (like there) unused: cache
-    dtypes follow the cache arrays themselves.
-
-    ``route_ahead=True`` (the pipelined serving path) fuses MoE route
-    phase 1 into each attn+moe layer's jitted attention step
-    (:func:`_layer_decode_attn_route_jit`) and hands the resulting
-    ``moe.Phase1`` to ``moe_fn`` as the ``phase1`` keyword -- the routing
-    arrays are dispatched one program ahead of the host route stage, so the
-    host only ever fetches the small slot stream, never the hidden state.
-    The computed values are identical to ``route_ahead=False``.
-    """
-    check_cache_fits(cache, pos, who="decode_step_layered", cfg=cfg)
-    pol = precision_policy(cfg.policy)
-    cd = pol.compute_dtype
-    x = jnp.take(params["embed"], tokens_1, axis=0).astype(cd)
-    shared_p = params.get("shared_attn")
-    new_cache = dict(cache)
-    pos_t = jnp.asarray(pos, jnp.int32)  # traced side; host moe keeps `pos`
-    take, restack = _tree_take, _tree_stack
-    if route_ahead:
-        # same capacity route_moe would compute (C = 1 for S = 1 decode)
-        route_cap = moe.dispatch_capacity(tokens_1.shape[1], cfg, pos0=pos)
-
-    def layered_block(kind, stack, i, x, c_i):
-        if kind == "attn+moe" and moe_fn is not None:
-            if route_ahead:
-                x, h, new_attn, ph1 = _layer_decode_attn_route_jit(
-                    cfg, route_cap)(stack, i, x, c_i["attn"], c_i["moe"],
-                                    pos_t)
-                f, moe_counts = moe_fn(
-                    stack["ffn"], h, cfg, counts=c_i.get("moe"), pos=pos,
-                    layer=i, phase1=moe.Phase1(*ph1, route_cap))
-            else:
-                x, h, new_attn = _layer_decode_attn_head_jit(cfg)(
-                    stack, i, x, c_i["attn"], pos_t)
-                f, moe_counts = moe_fn(stack["ffn"], h, cfg,
-                                       counts=c_i.get("moe"), pos=pos,
-                                       layer=i)
-            return x + f, {"attn": new_attn, "moe": moe_counts}
-        return _layer_decode_jit(cfg, kind)(stack, i, x, c_i, pos_t)
-
-    if "prologue" in params:
-        pro = []
-        for i in range(cfg.n_prologue):
-            x, nc = layered_block(cfg.block_unit[0], params["prologue"], i,
-                                  x, take(cache["prologue"], i))
-            pro.append(nc)
-        new_cache["prologue"] = restack(pro)
-
-    per_step = []
-    for i in range(cfg.n_repeats):
-        new_slots = []
-        for slot, kind in enumerate(cfg.block_unit):
-            c_i = take(cache["slots"][slot], i)
-            x, nc = layered_block(kind, params["blocks"][slot], i, x, c_i)
-            new_slots.append(nc)
-        if cfg.shared_attn_every:
-            c_i = take(cache["slots"][-1], i)
-            # step index is concrete here, so the fire test is plain Python
-            if (i % cfg.shared_attn_every) == (cfg.shared_attn_every - 1):
-                x, nc = _layer_decode_jit(cfg, "shared_attn")(
-                    shared_p, None, x, c_i, pos_t)
-            else:
-                nc = c_i
-            new_slots.append(nc)
-        per_step.append(tuple(new_slots))
-    new_cache["slots"] = tuple(
-        restack([step[s] for step in per_step])
-        for s in range(len(per_step[0])))
-
-    logits = _final_logits_jit(cfg, False)(params["final_norm"],
-                                           _unemb_param(params, cfg), x)
-    return logits, new_cache
-
-
-def prefill_layered(params: Params, tokens: jax.Array, cfg: ArchConfig, *,
-                    max_seq: int, embeddings: Optional[jax.Array] = None,
-                    impl: str = "chunked", cache_dtype=jnp.bfloat16,
-                    moe_fn=None, route_ahead: bool = False,
-                    kv_quant: Optional[str] = None,
-                    attn_mask: Optional[AttnMaskSpec] = None):
-    """Serving prefill, layer by layer: same function as :func:`prefill`
-    but with the repeat loop unrolled in Python so a serving loop can
-    interleave host work (two-phase MoE routing) between layers.  This is
-    what lets prefill ride the *bucketed routed stream* instead of tracing
-    the full ``E*C x T`` dispatch grid (the single-phase jit fallback).
-    Each layer runs as a cached jitted step; ``moe_fn`` (as in
-    :func:`decode_step_layered`) is injected at every attn+moe block with
-    ``counts=None, pos=None`` -- a fresh sequence at position 0, exactly the
-    fused prefill's routing state.  ``route_ahead=True`` fuses route
-    phase 1 into each attn+moe layer's jitted attention step and passes the
-    resulting ``moe.Phase1`` to ``moe_fn`` (see
-    :func:`decode_step_layered`); values are identical either way."""
-    pol = precision_policy(cfg.policy)
-    cd = pol.compute_dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cd)
-    if embeddings is not None:
-        x = jnp.concatenate([embeddings.astype(cd), x], axis=1)
-    S_total = x.shape[1]
-    shared_p = params.get("shared_attn")
-    cache: Dict[str, Any] = {}
-    take, restack = _tree_take, _tree_stack
-    if route_ahead:
-        route_cap = moe.dispatch_capacity(S_total, cfg, pos0=0)
-
-    def layered_block(kind, stack, i, x):
-        if kind == "attn+moe" and moe_fn is not None:
-            if route_ahead:
-                x, h, new_attn, ph1 = _layer_prefill_attn_route_jit(
-                    cfg, kind, max_seq, impl, route_cap, kv_quant,
-                    attn_mask)(stack, i, x)
-                f, moe_counts = moe_fn(stack["ffn"], h, cfg, counts=None,
-                                       pos=None, layer=i,
-                                       phase1=moe.Phase1(*ph1, route_cap))
-            else:
-                x, h, new_attn = _layer_prefill_attn_head_jit(
-                    cfg, kind, max_seq, impl, kv_quant, attn_mask)(stack, i,
-                                                                   x)
-                f, moe_counts = moe_fn(stack["ffn"], h, cfg, counts=None,
-                                       pos=None, layer=i)
-            return x + f, {"attn": new_attn, "moe": moe_counts}
-        return _layer_prefill_jit(cfg, kind, max_seq, impl, kv_quant,
-                                  attn_mask)(stack, i, x)
-
-    if "prologue" in params:
-        pro = []
-        for i in range(cfg.n_prologue):
-            x, nc = layered_block(cfg.block_unit[0], params["prologue"], i,
-                                  x)
-            pro.append(nc)
-        cache["prologue"] = restack(pro)
-
-    per_step = []
-    for i in range(cfg.n_repeats):
-        new_slots = []
-        for slot, kind in enumerate(cfg.block_unit):
-            x, nc = layered_block(kind, params["blocks"][slot], i, x)
-            new_slots.append(nc)
-        if cfg.shared_attn_every:
-            # cache is collected every repeat (like the fused prefill); the
-            # residual only advances on fire steps
-            fire = (i % cfg.shared_attn_every) == (cfg.shared_attn_every - 1)
-            y2, c2 = _layer_prefill_jit(cfg, "shared_attn", max_seq,
-                                        impl, kv_quant, attn_mask)(shared_p,
-                                                                   None, x)
-            if fire:
-                x = y2
-            new_slots.append(c2)
-        per_step.append(tuple(new_slots))
-    cache["slots"] = tuple(
-        restack([step[s] for step in per_step])
-        for s in range(len(per_step[0])))
-
-    logits = _final_logits_jit(cfg, True)(params["final_norm"],
-                                          _unemb_param(params, cfg), x)
-    cache = _cache_to_dtype(cache, cd, cache_dtype)
-    return logits, cache, jnp.asarray(S_total, jnp.int32)

@@ -6,8 +6,7 @@ the two stages that framing implies.
 
 Two routing rules, chosen by ``top_k`` (``cfg.moe_dropless``):
 
-* top-1 with a prefix capacity (below; Llama-4 style), the only rule the
-  two-phase bcsr path serves;
+* top-1 with a prefix capacity (below; Llama-4 style);
 * dropless top-k (:func:`apply_moe_dropless`; Qwen3-Next style): softmax
   over all ``n_experts`` router logits in f32, the top ``top_k``, their
   weights renormalised to sum 1.  A token meets an expert at most once, so
@@ -55,30 +54,18 @@ multiplies by exact 0/1 blocks with f32 accumulation), so the backends are
 interchangeable mid-deployment.  The grouped expert GEMM consumes dense
 (E, B*C, d) tiles and combine gathers results back by the same index stream.
 
-**Two-phase serving** (:func:`route_moe` / :func:`execute_moe`) -- the
-route-then-compile split that keeps the bcsr stream sparse *under jit*:
-phase 1 routes eagerly and compacts the dispatch stream to its union
-nonzero-block pattern on host, padded to a power-of-two nnzb bucket
-(``engine.stream_bucket``); phase 2 is a jit-compiled dispatch+FFN+combine
-whose compile cache keys on the bucket, so recompiles are bounded while
-the streamed work tracks the *routed* blocks, not the ``E*C x T`` grid.
-``launch.serve.ServeLoop`` drives this per decode step.
-
 Expert-parallel: the leading E dim of expert weights shards over the
 "model" axis; the gather/scatter becomes an all-to-all under pjit.
 """
 from __future__ import annotations
 
-import functools
-import time
 import warnings
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.formats import _pytree_dataclass
 from repro.core.precision import QuantTensor, quantize_tensor
 from repro.models.config import ArchConfig
 from repro.models.layers import init_mlp, apply_mlp
@@ -145,11 +132,10 @@ def quantize_expert_weights(params, dtype, *, rounding: str = "nearest",
     the quantization error of one input channel never leaks across output
     channels.  The negative axis makes the QuantTensor *slice-stable*: a
     repeat-stacked leaf ``(n_repeats, E, d_in, d_out)`` keeps a valid axis
-    after ``lax.scan`` / ``_tree_take`` strip the leading dim.  Router /
-    shared-expert / non-MoE params are untouched, and the QuantTensor
-    leaves flow through ``execute_moe[_jit]`` / ``apply_moe`` transparently
-    (pytree); :func:`_wcast` dequantizes at the einsum boundary.  Returns a
-    new params dict (input unchanged)."""
+    after ``lax.scan`` strips the leading dim.  Router / shared-expert /
+    non-MoE params are untouched, and the QuantTensor leaves flow through
+    ``apply_moe`` transparently (pytree); :func:`_wcast` dequantizes at the
+    einsum boundary.  Returns a new params dict (input unchanged)."""
     if "experts" not in params:
         raise ValueError(
             f"quantize_expert_weights: params has no 'experts' subtree "
@@ -336,7 +322,7 @@ def _dispatch_grid(S: int, E: int, C: int, bm: int, bk: int):
     """The padded block geometry of the (slot, token) dispatch matrix:
     (M, Mp, Sp, gm, gn).  Single source of truth shared by the traced
     full-grid path and the routed-stream builder -- these two must agree or
-    the eager/traced/two-phase dispatch paths stop being bit-identical."""
+    the eager and traced dispatch paths stop being bit-identical."""
     M = E * C
     Mp = -(-M // bm) * bm
     Sp = -(-S // bk) * bk
@@ -360,26 +346,17 @@ def _dispatch_matrix_tiles(flat_slot: jax.Array, S: int, E: int, C: int,
 
 
 def _build_routed_stream(flat_slot, S: int, E: int, C: int, bm: int,
-                         bk: int, dtype, min_bucket: Optional[int] = None):
-    """Compacted dispatch stream straight from *concrete* slots, host-side.
-
-    The single construction site for the routed-stream semantics shared by
-    the eager bcsr backend and phase 1 of the two-phase loop: union
-    nonzero-block pattern over the batch, every-block-row-appears coverage
-    (kernel contract, zero block at col 0), (row, col)-sorted stream.
-    Cost is O(B*S + nnzb*bm*bk) -- it never touches the dense E*C x T
-    grid, only the one (slot, token) entry each kept token contributes.
-
-    ``min_bucket`` set (the two-phase path) pads the stream to its
-    power-of-two bucket *here*, while everything is still host numpy --
-    one device allocation/transfer at final size, instead of transferring
-    exact-size then concatenating on device (``with_capacity``).  Pad
-    entries repeat the last coordinate with zero blocks, same semantics.
+                         bk: int, dtype):
+    """Compacted dispatch stream straight from *concrete* slots, host-side,
+    for the eager bcsr backend: union nonzero-block pattern over the batch,
+    every-block-row-appears coverage (kernel contract, zero block at col 0),
+    (row, col)-sorted stream.  Cost is O(B*S + nnzb*bm*bk) -- it never
+    touches the dense E*C x T grid, only the one (slot, token) entry each
+    kept token contributes.
 
     Returns (BatchedBCSR, nnzb_routed, nnzb_covered): data blocks before
-    row coverage, and the covered (pre-bucket) stream length."""
+    row coverage, and the covered stream length."""
     from repro.core.formats import BatchedBCSR
-    from repro.kernels import engine
 
     fs = np.asarray(flat_slot)
     B = fs.shape[0]
@@ -403,15 +380,10 @@ def _build_routed_stream(flat_slot, S: int, E: int, C: int, bm: int,
     coords = np.union1d(coords,
                         np.nonzero(~present)[0].astype(np.int64) * gn)
     nnzb_covered = len(coords)
-    idx = np.searchsorted(coords, keys)       # before any bucket padding
-    cap = nnzb_covered
-    if min_bucket is not None:
-        cap = engine.stream_bucket(nnzb_covered, minimum=min_bucket)
-        coords = np.concatenate(
-            [coords, np.full(cap - nnzb_covered, coords[-1])])
+    idx = np.searchsorted(coords, keys)
     brows = (coords // gn).astype(np.int32)
     bcols = (coords % gn).astype(np.int32)
-    blocks = np.zeros((B, cap, bm, bk), np.dtype(dtype))
+    blocks = np.zeros((B, nnzb_covered, bm, bk), np.dtype(dtype))
     blocks[b_idx, idx, slots % bm, s_idx % bk] = 1
     indptr = np.zeros(gm + 1, np.int32)
     np.cumsum(np.bincount(brows, minlength=gm), out=indptr[1:])
@@ -428,9 +400,8 @@ def _dispatch_bcsr(xt: jax.Array, flat_slot: jax.Array, E: int, C: int):
     (shared index stream) through the sharded SpMM Pallas kernel.
 
     Eagerly the stream compacts to the union nonzero-block pattern; under
-    tracing the pattern is the full grid (static shapes) -- serving callers
-    avoid that cost by routing eagerly first (:func:`route_moe`) and running
-    the compiled phase on the compacted stream (:func:`execute_moe`).
+    tracing (the fused serving step) the pattern is the full grid, since
+    data-dependent sparsity cannot change static shapes.
     Returns (E, B, C, d), bit-identical to :func:`_dispatch_gather` (0/1
     blocks, f32 accumulate).
     """
@@ -466,24 +437,6 @@ def _dispatch_bcsr(xt: jax.Array, flat_slot: jax.Array, E: int, C: int):
     out = engine.shard_spmm_batched(ab, xt_p, bn=tiles["bn"],
                                     nt=tiles["nt"],
                                     out_dtype=xt.dtype)      # (B, Mp, d)
-    return out[:, :M].reshape(B, E, C, d).transpose(1, 0, 2, 3)
-
-
-def _dispatch_stream(xt: jax.Array, stream, E: int, C: int):
-    """Phase-2 dispatch: a pre-built (route_moe) BatchedBCSR stream through
-    the trace-safe engine entry.  Safe under jit -- the index arrays are
-    traced arguments, so the compile cache keys on the *bucketed* stream
-    shape, never on the concrete routing."""
-    from repro.kernels import engine, tuning
-
-    B, S, d = xt.shape
-    _, Mp, Sp = stream.shape
-    tiles = tuning.moe_dispatch_tiles(d, xt.dtype)
-    xt_p = jnp.pad(xt, ((0, 0), (0, Sp - S), (0, 0)))
-    out = engine.shard_spmm_batched_stream(stream, xt_p, bn=tiles["bn"],
-                                           nt=tiles["nt"],
-                                           out_dtype=xt.dtype)  # (B, Mp, d)
-    M = E * C
     return out[:, :M].reshape(B, E, C, d).transpose(1, 0, 2, 3)
 
 
@@ -588,9 +541,7 @@ def _check_groups(B: int, cfg: ArchConfig, G: Optional[int], who: str):
 def _moe_tail(p, x, xe, gate, keep, flat_slot, cfg: ArchConfig, E: int,
               C: int):
     """Expert FFN + combine (+ shared expert): everything downstream of the
-    dispatch buffer.  Shared verbatim by :func:`apply_moe` and the two-phase
-    :func:`execute_moe`, so the phases can never drift from the fused layer.
-    """
+    dispatch buffer."""
     from repro.parallel import context as pctx
     from repro.parallel.sharding import constrain
 
@@ -616,216 +567,6 @@ def _moe_tail(p, x, xe, gate, keep, flat_slot, cfg: ArchConfig, E: int,
         out = out + apply_mlp(p["shared"], x.reshape(B * S, d),
                               cfg).reshape(B, S, d)
     return out
-
-
-# ------------------------------------------------- two-phase serving API --
-
-def route_phase1(router, x, cfg: ArchConfig, counts, pos0, capacity: int):
-    """Traceable phase-1 body: router matmul + softmax/top-k + the
-    prefix-stable slot cumsums, returning only the small per-token routing
-    arrays -- never the hidden state.  Standalone it is jitted as
-    :func:`_route_phase1_jit`; the pipelined serving path instead inlines it
-    into the model's fused attention+route layer programs
-    (``model._layer_*_attn_route_jit``) so the router output of a layer is
-    dispatched one program ahead of the host route stage."""
-    r = route_tokens(router, x, cfg, counts=counts, pos0=pos0)
-    flat_slot = jnp.where(r.keep, r.expert_id * capacity + r.within,
-                          cfg.n_experts * capacity)
-    return r.gate, r.keep, r.new_counts, flat_slot
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "capacity"))
-def _route_phase1_jit(router, x, cfg: ArchConfig, counts, pos0, capacity):
-    """The compiled half of phase 1: :func:`route_phase1` as one fused
-    program instead of an op-by-op eager chain.  ``pos0`` rides as a traced
-    scalar so every decode step reuses one compiled program; only the token
-    shape and the static dispatch capacity key the cache.  The host-side
-    remainder of phase 1 (stream compaction) needs the *values*, which it
-    reads off the returned concrete arrays (:func:`plan_from_phase1`)."""
-    return route_phase1(router, x, cfg, counts, pos0, capacity)
-
-
-class Phase1(NamedTuple):
-    """Phase-1 routing outputs plus the static dispatch capacity their slot
-    encoding assumed.  Produced by :func:`_route_phase1_jit` (via
-    :func:`route_moe`) or by the model's fused attention+route layer
-    programs; consumed by :func:`plan_from_phase1`."""
-    gate: jax.Array        # (B, S) f32 top-1 router probability
-    keep: jax.Array        # (B, S) bool prefix-capacity keep set
-    new_counts: jax.Array  # (B, E) int32 occupancy after this call
-    flat_slot: jax.Array   # (B, S) int32 in [0, E*C]  (E*C = dropped)
-    capacity: int          # static dispatch capacity C the slots encode
-
-
-@_pytree_dataclass(static=("capacity", "backend"))
-class MoEPlan:
-    """Phase-1 output of the two-phase route-then-compile serving loop.
-
-    Carries exactly what phase 2 consumes -- not the full
-    :class:`Routing` (its logits / slot / expert-id arrays are dead weight
-    in the compiled step and would ride the host->device argument path
-    every decode step).  Array fields are pytree children, so a
-    jit-compiled :func:`execute_moe` takes them as *traced arguments*; the
-    static aux -- the dispatch capacity ``C`` and the backend name -- plus
-    the (bucketed) stream shape are all that key the compile cache.  Two
-    plans with the same token shape, capacity, and nnzb bucket therefore
-    reuse one compiled program no matter how differently their tokens
-    routed."""
-
-    gate: jax.Array          # (B, S) f32 top-1 router probability
-    keep: jax.Array          # (B, S) bool prefix-capacity keep set
-    new_counts: jax.Array    # (B, E) int32 occupancy after this call
-    flat_slot: jax.Array     # (B, S) int32 in [0, E*C]  (E*C = dropped)
-    stream: Optional[object]  # BatchedBCSR dispatch stream ("bcsr") | None
-    capacity: int            # static per-(row, expert) dispatch capacity C
-    backend: str             # "gather" | "bcsr"
-
-
-def route_moe(p, x, cfg: ArchConfig, *, counts: Optional[jax.Array] = None,
-              pos=None, dispatch: Optional[str] = None,
-              groups: Optional[int] = None,
-              layer: Optional[int] = None) -> Tuple[MoEPlan, dict]:
-    """Phase 1: route on a *concrete* ``x``, materialize the dispatch stream.
-
-    The router matmul + slot cumsums run as one jit-compiled program
-    (:func:`_route_phase1_jit`; ``pos0`` traced, so a decode phase compiles
-    it once) and, for the "bcsr" backend, the 0/1 dispatch matrix is then
-    compacted to its union nonzero-block stream on host -- the thing tracing
-    fundamentally cannot do, because data-dependent sparsity cannot produce
-    static shapes.
-    The stream is then padded to its power-of-two nnzb bucket
-    (``engine.stream_bucket``, floor from the ``"moe_dispatch"`` autotune
-    row), so the phase-2 compile cache sees a bounded set of stream shapes.
-
-    Returns ``(plan, info)``: ``plan`` feeds :func:`execute_moe` /
-    :func:`execute_moe_jit`; ``info`` is host-side stats -- ``nnzb_routed``
-    (data blocks in the union pattern), ``nnzb_covered`` (+ the kernel's
-    every-row-appears coverage blocks), ``nnzb_stream`` (after bucketing),
-    ``grid_nnzb`` (what the single-phase jit fallback would stream), and
-    ``bucket``.
-
-    ``layer`` indexes a repeat-stacked ``p`` (only the small router is
-    sliced, here).
-    """
-    from repro.parallel import context as pctx
-    from repro.kernels import tuning
-
-    if cfg.moe_dropless:
-        raise ValueError(
-            "route_moe: the two-phase path routes top-1 with a capacity; "
-            f"{cfg.name} routes dropless top-{cfg.top_k} and is served by "
-            "the fused decode (moe.apply_moe_dropless)")
-    if isinstance(x, jax.core.Tracer):
-        raise TypeError(
-            "route_moe is the eager phase of the two-phase serving loop; "
-            "call it outside jit and feed its plan to execute_moe (the "
-            "compiled phase). Tracing the router would force the dispatch "
-            "stream back to the full grid.")
-    backend = dispatch or pctx.MOE_DISPATCH or cfg.moe_dispatch
-    if backend not in ("gather", "bcsr"):
-        raise ValueError(f"unknown moe_dispatch backend {backend!r}")
-    B, S, d = x.shape
-    E = cfg.n_experts
-    _check_groups(B, cfg, groups or pctx.MOE_GROUPS, "route_moe")
-
-    # concrete by contract: an int, or an int (B,) vector under continuous
-    # batching (per-row positions; the dispatch capacity then takes the
-    # position-independent S bound)
-    if pos is None:
-        pos0 = 0
-    elif np.ndim(pos) == 0:
-        pos0 = int(pos)
-    else:
-        pos0 = np.asarray(pos, np.int32)
-    C = dispatch_capacity(S, cfg, pos0=pos0)
-    # router + slot assignment run as ONE jitted program (pos0 traced, so a
-    # whole decode phase reuses a single compile); the stream compaction
-    # stays host-side (plan_from_phase1) -- the data-dependent step jit
-    # cannot do.
-    router = p["router"] if layer is None else p["router"][layer]
-    gate, keep, new_counts, flat_slot = _route_phase1_jit(
-        router, x, cfg, counts, jnp.asarray(pos0, jnp.int32), C)
-    return plan_from_phase1(Phase1(gate, keep, new_counts, flat_slot, C),
-                            cfg, dispatch=backend, dtype=x.dtype)
-
-
-def plan_from_phase1(phase1: Phase1, cfg: ArchConfig, *,
-                     dispatch: Optional[str] = None,
-                     dtype=jnp.float32) -> Tuple[MoEPlan, dict]:
-    """The host half of phase 1: fetch the ``(B, S)`` slot stream -- the
-    ONLY device->host transfer; the hidden state never crosses -- compact it
-    to the union nonzero-block :class:`BatchedBCSR` stream, and pad to its
-    power-of-two nnzb bucket.  Shared by :func:`route_moe` (which computes
-    phase 1 itself) and the pipelined serving loop (which receives phase 1
-    from the model's fused attention+route layer program, dispatched a
-    program ahead so the routing arrays are already materializing when the
-    host arrives here).
-
-    ``info`` carries the stream accounting of :func:`route_moe` plus the
-    timing split the serving loop's phase attribution wants: ``wait_s``
-    (time blocked fetching the slot stream off the device -- in pipelined
-    mode this is the window that overlaps the in-flight execute of the
-    previous layer) and ``host_s`` (pure host compaction/bucketing work)."""
-    from repro.parallel import context as pctx
-    from repro.kernels import tuning
-
-    backend = dispatch or pctx.MOE_DISPATCH or cfg.moe_dispatch
-    if backend not in ("gather", "bcsr"):
-        raise ValueError(f"unknown moe_dispatch backend {backend!r}")
-    gate, keep, new_counts, flat_slot, C = phase1
-    S = flat_slot.shape[1]
-    E = cfg.n_experts
-    stream = None
-    info = {"backend": backend, "capacity": C, "tokens": S,
-            "wait_s": 0.0, "host_s": 0.0}
-    if backend == "bcsr":
-        t0 = time.monotonic()
-        fs = np.asarray(flat_slot)      # (B, S) int32: the whole fetch
-        t1 = time.monotonic()
-        tiles = tuning.moe_dispatch_tiles(cfg.d_model, dtype)
-        bm, bk = tiles["block"]
-        stream, nnzb_routed, nnzb_covered = _build_routed_stream(
-            fs, S, E, C, bm, bk, dtype, min_bucket=tiles["min_bucket"])
-        gm, gn = stream.grid_shape
-        info.update(nnzb_routed=nnzb_routed, nnzb_covered=nnzb_covered,
-                    nnzb_stream=stream.nnzb, grid_nnzb=gm * gn,
-                    bucket=stream.nnzb, block=(bm, bk),
-                    wait_s=t1 - t0, host_s=time.monotonic() - t1)
-    plan = MoEPlan(gate=gate, keep=keep, new_counts=new_counts,
-                   flat_slot=flat_slot, stream=stream, capacity=C,
-                   backend=backend)
-    return plan, info
-
-
-def execute_moe(p, x, plan: MoEPlan, cfg: ArchConfig,
-                layer: Optional[int] = None):
-    """Phase 2: dispatch + expert FFN + combine from a phase-1 plan.
-
-    Pure and jit-friendly: all data-dependence is frozen into ``plan``'s
-    arrays, whose shapes are bucketed, so compiling this (see
-    :func:`execute_moe_jit`) retraces only per (token shape, capacity,
-    nnzb-bucket) -- never per routing pattern.  Bit-identical to
-    ``apply_moe(..., dispatch=plan.backend)`` on the same inputs: the
-    dispatch buffer is built from the same 0/1 blocks and everything
-    downstream is the shared :func:`_moe_tail`.
-
-    ``layer`` indexes a repeat-stacked ``p``; under :func:`execute_moe_jit`
-    the slice is part of the compiled program, so the expert weights are
-    never copied out of the stack eagerly."""
-    if layer is not None:
-        p = jax.tree.map(lambda a: a[layer], p)
-    E, C = cfg.n_experts, plan.capacity
-    if plan.backend == "bcsr":
-        xe = _dispatch_stream(x, plan.stream, E, C)
-    else:
-        xe = _dispatch_gather(x, plan.flat_slot, E, C)
-    out = _moe_tail(p, x, xe, plan.gate, plan.keep, plan.flat_slot, cfg, E,
-                    C)
-    return out, plan.new_counts
-
-
-execute_moe_jit = functools.partial(jax.jit, static_argnames=("cfg",))(
-    execute_moe)
 
 
 def load_balance_loss(logits: jax.Array, expert_id: jax.Array, E: int):

@@ -3,18 +3,18 @@ health tracking, and the graceful-degradation ladder.
 
 Occamy's system story is *latency tolerance* -- the fabric keeps computing
 while individual transfers stall or straggle.  This module is the serving
-translation of that discipline: every failure path in the two-phase serving
-stack (``launch.serve``) is (a) injectable deterministically so it can be
+translation of that discipline: every failure path in the serving stack
+(``launch.serve``) is (a) injectable deterministically so it can be
 tested and reproduced bit-for-bit, and (b) survivable per-request, so a
 poisoned row never takes down its co-batched neighbours.
 
 Pieces
 ------
 * :class:`FaultSpec` / :class:`FaultPlan` -- a seeded registry of faults
-  keyed by pipeline stage (``prefill / route / execute / attention /
-  sample / quantize``).  Activation poisons (NaN/Inf) are injected with
-  :func:`poison_rows` -- a single eager ``jnp.where`` on a host-built row
-  mask, so injection adds **no host sync**; host-side faults raise
+  keyed by serving stage (``prefill / sample / quantize``).  Activation
+  poisons (NaN/Inf) are injected with :func:`poison_rows` -- a single
+  eager ``jnp.where`` on a host-built row mask, so injection adds **no
+  host sync**; host-side faults raise
   :class:`InjectedFault`; stragglers sleep.  Every trigger is logged in
   ``plan.triggered`` so tests can assert exactly which faults fired.
 * :class:`RetryPolicy` -- bounded exponential backoff for failed prefills
@@ -22,8 +22,8 @@ Pieces
 * :class:`HealthTracker` -- monotonic counters + a bounded event log,
   surfaced in ``summary()["health"]``.
 * :class:`DegradationLadder` -- the ordered fallback rungs (quantized KV
-  -> wide KV, sparse mask -> ``impl="ref"``, pipeline depth 1 -> 0) a
-  driver walks down when health counters cross ``fail_threshold``.
+  -> wide KV, sparse mask -> ``impl="ref"``) a driver walks down when
+  health counters cross ``fail_threshold``.
 * :func:`dequantize_cache` / :func:`corrupt_quant_scales` -- cache-level
   helpers for the ``kv_wide`` rung and the ``quantize``-stage fault.
 """
@@ -40,8 +40,10 @@ import jax.numpy as jnp
 
 from repro.core import precision
 
-STAGES: Tuple[str, ...] = (
-    "prefill", "route", "execute", "attention", "sample", "quantize")
+# Where a driver calls a FaultPlan: ``prefill`` on an admission's logits,
+# ``sample`` on a decode step's logits before it is sampled, ``quantize`` on
+# the KV cache's scale leaves.
+STAGES: Tuple[str, ...] = ("prefill", "sample", "quantize")
 
 # Fault kinds: activation stages take nan/inf poisons plus host-side
 # exception/straggler; the quantize stage corrupts cache scale leaves.
@@ -149,15 +151,13 @@ def dequantize_cache(cache: Any, dtype=jnp.float32) -> Any:
 class FaultSpec:
     """One deterministic fault: fires at ``stage`` when every non-None
     selector matches (``uid`` the request, ``row`` the batch row, ``step``
-    the decode step counter, ``layer`` the per-step call index for stages
-    hooked once per layer), at most ``times`` times total."""
+    the decode step counter), at most ``times`` times total."""
 
     stage: str
     kind: str
     uid: Optional[int] = None
     row: Optional[int] = None
     step: Optional[int] = None
-    layer: Optional[int] = None
     times: int = 1
     delay_s: float = 0.05
 
@@ -187,7 +187,6 @@ class FaultPlan:
         self.triggered: List[Tuple[str, str, Optional[int], Tuple[int, ...]]] = []
         self._remaining: Dict[int, int] = {
             i: s.times for i, s in enumerate(self.specs)}
-        self._calls: Counter = Counter()
 
     # -- construction helpers ------------------------------------------------
     @classmethod
@@ -196,7 +195,7 @@ class FaultPlan:
 
     @classmethod
     def random(cls, seed: int, uids: Sequence[int], rate: float, *,
-               stages: Sequence[str] = ("prefill", "execute", "sample"),
+               stages: Sequence[str] = ("prefill", "sample"),
                kinds: Sequence[str] = ("nan", "inf", "exception"),
                max_step: int = 8) -> "FaultPlan":
         """Seeded random plan: each uid independently faults with
@@ -215,21 +214,13 @@ class FaultPlan:
     def reset(self) -> None:
         self.triggered = []
         self._remaining = {i: s.times for i, s in enumerate(self.specs)}
-        self._calls = Counter()
 
     # -- matching ------------------------------------------------------------
-    def _armed(self, stage: str, *, step: Optional[int],
-               layer: Optional[int]) -> List[Tuple[int, FaultSpec]]:
-        out = []
-        for i, s in enumerate(self.specs):
-            if s.stage != stage or self._remaining.get(i, 0) <= 0:
-                continue
-            if s.step is not None and s.step != step:
-                continue
-            if s.layer is not None and s.layer != layer:
-                continue
-            out.append((i, s))
-        return out
+    def _armed(self, stage: str, *,
+               step: Optional[int]) -> List[Tuple[int, FaultSpec]]:
+        return [(i, s) for i, s in enumerate(self.specs)
+                if s.stage == stage and self._remaining.get(i, 0) > 0
+                and (s.step is None or s.step == step)]
 
     def _rows_for(self, spec: FaultSpec, uids: Optional[Sequence[Optional[int]]],
                   nrows: int) -> List[int]:
@@ -244,15 +235,8 @@ class FaultPlan:
     # -- application ---------------------------------------------------------
     def apply(self, stage: str, x: jax.Array, *, step: Optional[int] = None,
               uids: Optional[Sequence[Optional[int]]] = None) -> jax.Array:
-        """Stage hook for batched activations ``x`` of shape ``(B, ...)``.
-
-        Tracks a per-(stage, step) call counter so ``layer=`` selectors can
-        target the Nth hook invocation within one step.
-        """
-        key = (stage, step)
-        layer = self._calls[key]
-        self._calls[key] += 1
-        for i, spec in self._armed(stage, step=step, layer=layer):
+        """Stage hook for batched activations ``x`` of shape ``(B, ...)``."""
+        for i, spec in self._armed(stage, step=step):
             if spec.kind == "straggler":
                 self._remaining[i] -= 1
                 self.triggered.append((stage, "straggler", step, ()))
@@ -275,9 +259,7 @@ class FaultPlan:
                     uids: Optional[Sequence[Optional[int]]] = None,
                     nrows: int = 0) -> Any:
         """Quantize-stage hook: corrupt cache scale leaves for matching rows."""
-        layer = self._calls[("quantize", step)]
-        self._calls[("quantize", step)] += 1
-        for i, spec in self._armed("quantize", step=step, layer=layer):
+        for i, spec in self._armed("quantize", step=step):
             rows = self._rows_for(spec, uids, nrows)
             if not rows:
                 continue
@@ -332,12 +314,12 @@ class DegradationLadder:
     Each :meth:`note_failure` increments a counter; every time it crosses a
     multiple of ``fail_threshold`` the next pending rung is returned for the
     driver to apply (``kv_wide`` -> dequantize the KV cache and decode wide,
-    ``mask_ref`` -> rebuild the sparse attention spec with ``impl='ref'``,
-    ``pipeline_serial`` -> drop StreamPipeline depth to 0).  Rungs that
-    don't apply to the driver's configuration are skipped at construction.
+    ``mask_ref`` -> rebuild the sparse attention spec with ``impl='ref'``).
+    Rungs that don't apply to the driver's configuration are skipped at
+    construction.
     """
 
-    RUNGS: Tuple[str, ...] = ("kv_wide", "mask_ref", "pipeline_serial")
+    RUNGS: Tuple[str, ...] = ("kv_wide", "mask_ref")
 
     def __init__(self, rungs: Sequence[str], *, fail_threshold: int = 3):
         unknown = set(rungs) - set(self.RUNGS)
@@ -351,15 +333,13 @@ class DegradationLadder:
         self.failures = 0
 
     @classmethod
-    def for_serving(cls, *, kv_quant, attn_mask, pipeline_depth: int,
+    def for_serving(cls, *, kv_quant, attn_mask,
                     fail_threshold: int = 3) -> "DegradationLadder":
         rungs = []
         if kv_quant is not None:
             rungs.append("kv_wide")
         if attn_mask is not None and getattr(attn_mask, "impl", "ref") != "ref":
             rungs.append("mask_ref")
-        if pipeline_depth > 0:
-            rungs.append("pipeline_serial")
         return cls(rungs, fail_threshold=fail_threshold)
 
     def note_failure(self) -> Optional[str]:

@@ -38,13 +38,6 @@ def test_sweep_smoke_points_are_bit_identical(sweep_results):
         assert by_nt[max(by_nt)] < by_nt[min(by_nt)]
 
 
-def test_sweep_smoke_bucket_points(sweep_results):
-    moe = sweep_results["moe_dispatch"]
-    assert moe["points"] and "min_bucket" in moe["winner"]
-    for p in moe["points"]:
-        assert p["nnzb_stream"] >= p["nnzb_covered"]
-
-
 def test_sweep_smoke_flash_points(sweep_results):
     """The flash (bq, bk) sweep: every dense and sparse point oracle-parity,
     winner carries both the sparse and dense tile picks, nothing
@@ -70,52 +63,6 @@ def test_emit_bench_schema(tmp_path, sweep_results):
     assert doc["bench"] == "smoke_test"
     assert {"backend", "device_count", "jax_version"} <= set(doc)
     assert doc["spmm"]["points"]
-
-
-@pytest.fixture(scope="module")
-def serve_results():
-    sys.path.insert(0, REPO_ROOT)
-    try:
-        from benchmarks import bench_serve
-    finally:
-        sys.path.pop(0)
-    return bench_serve.run(smoke=True, fault_rate=0.5)
-
-
-def test_bench_serve_smoke(serve_results):
-    """The continuous-batching bench drains its trace on both backends and
-    reports sane throughput/latency numbers."""
-    for backend in ("gather", "bcsr"):
-        e = serve_results[backend]
-        t = e["trace"]
-        assert e["requests_finished"] == t["requests"]
-        assert t["generated_tokens"] > 0
-        assert e["decode_tok_per_s"] > 0
-        lat = e["token_latency_ms"]
-        assert lat["n"] == t["generated_tokens"]
-        assert 0 < lat["p50"] <= lat["p99"]
-        ftl = e["first_token_ms"]
-        assert ftl["n"] == t["requests"] and ftl["p50"] > 0
-
-
-def test_bench_serve_pipelined_ab(serve_results):
-    """The serial-vs-pipelined A/B row (PR 7): the pipelined run drains the
-    same trace, emits the *same tokens*, and the hidden-route fraction is a
-    valid fraction.  (Speedup itself is not asserted at smoke shapes --
-    interpret-mode executes finish before the next route can overlap.)"""
-    for backend in ("gather", "bcsr"):
-        e = serve_results[backend]
-        assert e["pipeline_depth"] == 0        # top level stays the serial run
-        pip, ab = e["pipelined"], e["ab"]
-        assert pip["pipeline_depth"] == 1
-        assert pip["requests_finished"] == pip["trace"]["requests"]
-        assert pip["trace"]["generated_tokens"] == e["trace"]["generated_tokens"]
-        assert ab["tokens_match"] is True
-        assert ab["pipelined_tok_per_s"] > 0 and ab["serial_tok_per_s"] > 0
-        assert ab["decode_speedup"] > 0
-        assert 0.0 <= ab["route_hidden_frac"] <= 1.0
-        if e["two_phase"]:   # gather is fused: no route/execute stats
-            assert pip["timing"]["execute_dispatch_ms"] >= 0.0
 
 
 @pytest.fixture(scope="module")
@@ -170,29 +117,3 @@ def test_bench_precision_smoke(precision_results):
     assert serving["int8"]["tokens_match_frac"] == 1.0
     for name in ("fp8_e4m3", "fp8_e5m2", "int8"):
         assert serving[name]["first_decode_logit_rel_err"] < 0.2
-
-
-def test_bench_serve_fault_ab(serve_results):
-    """The healthy-vs-faulty A/B row (PR 10): the faulty run drains under a
-    seeded random fault plan, every request reaches a terminal state, and
-    surviving requests emit tokens bit-identical to the healthy pipelined
-    run (per-request isolation)."""
-    for backend in ("gather", "bcsr"):
-        fl = serve_results[backend]["fault"]
-        assert fl["fault_rate"] == 0.5
-        assert fl["faults_injected"] > 0
-        assert fl["survivor_tokens_match"] is True
-        n_req = serve_results[backend]["trace"]["requests"]
-        assert fl["finished"] + fl["failed"] + fl["shed"] == n_req
-        assert fl["faulty_tok_per_s"] > 0
-
-
-def test_bench_serve_signature_bound(serve_results):
-    """The batch-bucket law holds under the synthetic trace: phase-2
-    recompiles stay within the (batch-bucket x nnzb-bucket x token-shape)
-    budget, and every observed batch bucket is a power of two."""
-    e = serve_results["bcsr"]
-    assert e["two_phase"]
-    assert e["compile_signatures"] <= e["signature_bound"]
-    for b in e["batch_buckets"]:
-        assert b & (b - 1) == 0 and b > 0

@@ -326,7 +326,7 @@ def test_kv_quant_first_decode_step_error_bounded(tiny_model, name):
                                    cache_dtype=jnp.float32,
                                    kv_quant=kv_quant)
         tok = jnp.argmax(lg[:, -1:], axis=-1).astype(jnp.int32)
-        out, _ = M.decode_step_layered(params, cfg, cache, int(pos), tok)
+        out, _ = M.decode_step(params, cfg, cache, pos, tok)
         return np.asarray(out)
 
     ref = first_step(None)

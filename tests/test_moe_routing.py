@@ -10,7 +10,6 @@ These run on a tiny config with capacity_factor=1.0 so drops actually
 happen (the old in-batch-cumsum formulation fails all of these).
 """
 import dataclasses
-import functools
 import warnings
 
 import jax
@@ -137,123 +136,6 @@ def test_moe_group_misalignment_warns_and_strict_raises():
     with warnings.catch_warnings():
         warnings.simplefilter("error")          # G | B: no warning
         moe.apply_moe(p, x, TINY, groups=2)
-
-
-# --------------------------------------------------------------- two-phase --
-
-@pytest.mark.parametrize("dispatch", BACKENDS)
-def test_route_execute_matches_apply_moe(dispatch):
-    """Phase-1 + phase-2 == the fused layer, bit-for-bit, eager AND with
-    phase 2 jit-compiled (the serving configuration) against the fused
-    layer jit-compiled; jitted phase 2 == the eager layer to an ulp (under
-    jit the combine's multiply and the shared expert's add round once)."""
-    p, x = _layer()
-    fused = functools.partial(moe.apply_moe, cfg=TINY, dispatch=dispatch)
-    eager, _ = fused(p, x)
-    plan, info = moe.route_moe(p, x, TINY, dispatch=dispatch)
-    for ex, layer in ((moe.execute_moe, fused),
-                      (moe.execute_moe_jit, jax.jit(fused))):
-        want, want_counts = layer(p, x)
-        out, counts = ex(p, x, plan, TINY)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
-        np.testing.assert_array_equal(np.asarray(counts),
-                                      np.asarray(want_counts))
-        np.testing.assert_allclose(np.asarray(out), np.asarray(eager),
-                                   rtol=1e-6, atol=1e-6)
-    assert info["backend"] == dispatch
-
-
-def test_route_moe_rejects_tracers():
-    """Routing under jit would force the stream back to the full grid, so
-    phase 1 refuses to trace."""
-    p, x = _layer()
-    with pytest.raises(TypeError, match="eager phase"):
-        jax.jit(lambda x: moe.route_moe(p, x, TINY, dispatch="bcsr"))(x)
-
-
-def test_two_phase_stepwise_decode_matches_prefill():
-    """route+execute one token at a time (counts threaded) reproduces the
-    fused full-sequence layer -- the ServeLoop decode path.  Same tolerance
-    as the fused stepwise test: the shared-expert MLP is evaluated on
-    (B*S, d) vs (B*1, d) shapes, so bit-identity holds per-call, not
-    across the step split."""
-    p, x = _layer()
-    want, want_counts = moe.apply_moe(p, x, TINY, dispatch="bcsr")
-    counts, outs = None, []
-    for t in range(x.shape[1]):
-        plan, _ = moe.route_moe(p, x[:, t:t + 1], TINY, counts=counts,
-                                pos=t, dispatch="bcsr")
-        o, counts = moe.execute_moe_jit(p, x[:, t:t + 1], plan, TINY)
-        outs.append(o[:, 0])
-    np.testing.assert_allclose(np.asarray(jnp.stack(outs, 1)),
-                               np.asarray(want), atol=1e-6, rtol=1e-6)
-    np.testing.assert_array_equal(np.asarray(counts),
-                                  np.asarray(want_counts))
-
-
-def test_two_phase_stream_is_compacted_under_jit():
-    """THE tentpole property: with phase 2 under jit, the bcsr dispatch
-    stream length tracks the *routed* nonzero blocks (<= 2x, via the
-    power-of-two bucket), not the E*C x T full grid the single-phase jit
-    fallback pays.  Output stays bit-identical to the gather backend."""
-    import dataclasses as dc
-    from repro.kernels import engine, tuning
-
-    # Long sequence, small expert capacity: most of the (slot, token) grid
-    # is structurally empty, so compaction has something to win.
-    cfg = dc.replace(TINY, n_experts=4, capacity_factor=1.0,
-                     moe_shared_expert=False)
-    p = moe.init_moe(KEY, cfg)
-    S = 256
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, S, cfg.d_model),
-                          jnp.float32)
-    plan, info = moe.route_moe(p, x, cfg, dispatch="bcsr")
-    assert plan.stream is not None
-
-    # compaction: bucketed stream <= 2x covered blocks, and a real reduction
-    # vs the full grid (which is what scales with E*C and T)
-    assert info["nnzb_stream"] == plan.stream.nnzb
-    assert info["nnzb_stream"] <= 2 * max(
-        info["nnzb_covered"],
-        tuning.moe_dispatch_tiles(cfg.d_model)["min_bucket"])
-    assert info["nnzb_stream"] <= info["grid_nnzb"] // 2, (
-        "bucketed stream should be well under the full grid here")
-    assert info["nnzb_stream"] == engine.stream_bucket(
-        info["nnzb_covered"],
-        minimum=tuning.moe_dispatch_tiles(cfg.d_model)["min_bucket"])
-
-    # independence of E*C: vary the expert count (4 -> 8 -> 16; the
-    # capacity law keeps E*C ~ S*f, so the grid is unchanged) -- the
-    # bucketed stream must track the routed blocks, staying within one
-    # bucket step of the E=4 stream rather than scaling with the grid.
-    for E2 in (8, 16):
-        cfg2 = dc.replace(cfg, n_experts=E2)
-        p2 = moe.init_moe(KEY, cfg2)
-        _, info2 = moe.route_moe(p2, x, cfg2, dispatch="bcsr")
-        assert info2["nnzb_stream"] <= 2 * info["nnzb_stream"]
-        assert info2["nnzb_stream"] <= info2["grid_nnzb"] // 2
-
-    # bit-identity with gather, phase 2 jitted
-    want, _ = moe.apply_moe(p, x, cfg, dispatch="gather")
-    got, _ = moe.execute_moe_jit(p, x, plan, cfg)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_two_phase_compile_cache_is_bucketed():
-    """Decode steps with different routings but one nnzb bucket share one
-    phase-2 compile: the cache grows with buckets, not with steps."""
-    p, x = _layer()
-    n0 = moe.execute_moe_jit._cache_size()
-    counts, sizes = None, set()
-    for t in range(x.shape[1]):
-        plan, info = moe.route_moe(p, x[:, t:t + 1], TINY, counts=counts,
-                                   pos=t, dispatch="bcsr")
-        _, counts = moe.execute_moe_jit(p, x[:, t:t + 1], plan, TINY)
-        sizes.add((plan.capacity, plan.stream.nnzb))
-    grew = moe.execute_moe_jit._cache_size() - n0
-    assert grew <= len(sizes), (
-        f"phase-2 recompiled {grew}x for {len(sizes)} distinct bucket "
-        "signatures")
 
 
 # ------------------------------------------------------------ model parity --

@@ -5,17 +5,20 @@ The contract under test (see "Resilience contract" in ``tests/README.md``):
 
 * **Survivor bit-identity.**  With any single injected fault (any stage x
   any kind), every surviving request's generated tokens are bit-identical
-  to the same trace run fault-free -- on both dispatch backends, at
-  pipeline depth 0 and 1.  Poison stays in its batch row (per-row
-  independence of attention, prefix-stable MoE, bcsr dispatch, and
-  per-request sampling keys), and host-side failures retry from untouched
-  state (faults fire before any key split or cache commit).
-* **Zero new host syncs.**  At both depths the scheduler samples on the
-  device and the health bits ride the per-step token fetch: exactly one
+  to the same trace run fault-free -- on the fused, donated decode tick,
+  with gather dispatch and with the bcsr SpMM traced inside it.  Poison
+  stays in its batch row (per-row independence of attention, prefix-stable
+  MoE, bcsr dispatch, and per-request sampling keys), and host-side
+  failures retry from untouched state (faults fire before any key split;
+  a decode retry samples again from the logits its one forward made).
+* **Zero new host syncs.**  The scheduler samples on the device and the
+  health bits ride the per-step token fetch: exactly one
   ``jax.device_get`` per decode step.
 * **Policy.**  Bounded exponential-backoff retries, TTFT/total deadlines
   on a fake clock, a bounded admission queue with reject / drop-oldest
-  shed policies, and the kv_wide -> mask_ref -> pipeline_serial ladder.
+  shed policies, and the kv_wide -> mask_ref ladder.
+* **Refusals.**  A fault stage the serving path never calls, and a
+  pipeline depth other than 0, are errors at construction.
 """
 import dataclasses
 
@@ -26,13 +29,13 @@ import pytest
 
 from repro.core import precision
 from repro.core.masks import AttnMaskSpec
-from repro.kernels import engine
-from repro.launch import serve
 from repro.launch.serve import ServeLoop, ServeScheduler, _percentiles_ms
 from repro.models import model as M
 from repro.models import moe
 from repro.models.config import ArchConfig
 from repro.runtime import resilience as R
+
+from test_serve_inplace import reuse_programs
 
 TINY = ArchConfig(
     name="tiny-resilience", family="moe", d_model=32, n_heads=2,
@@ -55,13 +58,16 @@ def prompts():
     return [rng.integers(0, TINY.vocab_size, PROMPT) for _ in range(N_REQ)]
 
 
-def _run_sched(params, prompts, *, dispatch="bcsr", depth=1, plan=None,
-               kv_quant=None, temperature=0.0, **kw):
-    sched = ServeScheduler(
+def _sched(params, *, dispatch="bcsr", plan=None, kv_quant=None,
+           temperature=0.0, **kw):
+    return reuse_programs(ServeScheduler(
         params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch=dispatch,
-        two_phase=dispatch == "bcsr", temperature=temperature,
-        cache_dtype=jnp.float32, pipeline_depth=depth, kv_quant=kv_quant,
-        fault_plan=plan, **kw)
+        temperature=temperature, cache_dtype=jnp.float32, kv_quant=kv_quant,
+        fault_plan=plan, **kw))
+
+
+def _run_sched(params, prompts, **kw):
+    sched = _sched(params, **kw)
     for p in prompts:
         sched.submit(p, GEN)
     return sched, sched.run()
@@ -69,7 +75,7 @@ def _run_sched(params, prompts, *, dispatch="bcsr", depth=1, plan=None,
 
 @pytest.fixture(scope="module")
 def baselines(params, prompts):
-    """Fault-free token maps per (dispatch, depth, kv_quant) combo, computed
+    """Fault-free token maps per (dispatch, kv_quant) combo, computed
     lazily so only combos a test actually compares against are run;
     ``pool(key)`` is the slot pool such a run leaves behind."""
     cache = {}
@@ -77,9 +83,9 @@ def baselines(params, prompts):
     class Lazy:
         def _run(self, key):
             if key not in cache:
-                dispatch, depth, kvq = key
+                dispatch, kvq = key
                 cache[key] = _run_sched(params, prompts, dispatch=dispatch,
-                                        depth=depth, kv_quant=kvq)
+                                        kv_quant=kvq)
             return cache[key]
 
         def __getitem__(self, key):
@@ -101,6 +107,12 @@ def _assert_survivors_identical(out, base, *, failed_uids=()):
             err_msg=f"survivor {uid} tokens diverged under fault")
 
 
+def _assert_pools_equal(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 # --------------------------------------------------------- fault registry --
 
 class TestFaultPlan:
@@ -111,6 +123,12 @@ class TestFaultPlan:
             R.FaultSpec(stage="sample", kind="nope")
         with pytest.raises(ValueError, match="quantize"):
             R.FaultSpec(stage="quantize", kind="exception")
+
+    @pytest.mark.parametrize("stage", ["attention", "route", "execute"])
+    def test_stage_the_serving_path_never_calls_is_refused(self, stage):
+        """A spec that could never fire is an error, not a silent no-op."""
+        with pytest.raises(ValueError, match="stage must be one of"):
+            R.FaultSpec(stage=stage, kind="nan")
 
     def test_poison_rows(self):
         x = jnp.ones((4, 3))
@@ -128,28 +146,28 @@ class TestFaultPlan:
         assert len(plan.triggered) == 2
         plan.reset()
         assert plan.triggered == [] and len(plan._armed(
-            "sample", step=None, layer=0)) == 1
+            "sample", step=None)) == 1
 
     def test_selectors(self):
-        plan = R.FaultPlan.single("execute", "nan", uid=7, step=3)
+        plan = R.FaultPlan.single("sample", "nan", uid=7, step=3)
         x = jnp.ones((2, 4))
         # wrong step: no fire
-        assert plan.apply("execute", x, step=2, uids=[7, None]) is x
+        assert plan.apply("sample", x, step=2, uids=[7, None]) is x
         # right step, uid not resident: no fire, stays armed
-        assert plan.apply("execute", x, step=3, uids=[1, 2]) is x
-        y = plan.apply("execute", x, step=3, uids=[1, 7])
+        assert plan.apply("sample", x, step=3, uids=[1, 2]) is x
+        y = plan.apply("sample", x, step=3, uids=[1, 7])
         assert np.isnan(np.asarray(y)[1]).all()
-        assert plan.triggered == [("execute", "nan", 3, (1,))]
+        assert plan.triggered == [("sample", "nan", 3, (1,))]
 
     def test_exception_and_straggler(self):
-        plan = R.FaultPlan([R.FaultSpec("route", "exception", step=1),
-                            R.FaultSpec("route", "straggler", step=2,
+        plan = R.FaultPlan([R.FaultSpec("sample", "exception", step=1),
+                            R.FaultSpec("sample", "straggler", step=2,
                                         delay_s=0.0)])
         x = jnp.ones((1, 2))
-        plan.apply("route", x, step=0)
+        plan.apply("sample", x, step=0)
         with pytest.raises(R.InjectedFault):
-            plan.apply("route", x, step=1)
-        plan.apply("route", x, step=2)   # sleeps 0s, logs
+            plan.apply("sample", x, step=1)
+        plan.apply("sample", x, step=2)   # sleeps 0s, logs
         kinds = [t[1] for t in plan.triggered]
         assert kinds == ["exception", "straggler"]
 
@@ -170,28 +188,26 @@ class TestPolicies:
         assert R.RetryPolicy(base_delay_s=0.0).schedule() == [0.0, 0.0]
 
     def test_ladder_order_and_threshold(self):
-        lad = R.DegradationLadder(["pipeline_serial", "kv_wide", "mask_ref"],
-                                  fail_threshold=2)
-        rungs = [lad.note_failure() for _ in range(7)]
+        lad = R.DegradationLadder(["mask_ref", "kv_wide"], fail_threshold=2)
+        rungs = [lad.note_failure() for _ in range(5)]
         # canonical order regardless of construction order, every 2 failures
-        assert rungs == [None, "kv_wide", None, "mask_ref", None,
-                         "pipeline_serial", None]
+        assert rungs == [None, "kv_wide", None, "mask_ref", None]
         st = lad.state()
-        assert st["applied"] == ["kv_wide", "mask_ref", "pipeline_serial"]
-        assert st["pending"] == [] and st["failures"] == 7
+        assert st["applied"] == ["kv_wide", "mask_ref"]
+        assert st["pending"] == [] and st["failures"] == 5
+        with pytest.raises(ValueError, match="unknown ladder rungs"):
+            R.DegradationLadder(["serial"])
 
     def test_ladder_for_serving_filters(self):
-        lad = R.DegradationLadder.for_serving(
-            kv_quant=None, attn_mask=None, pipeline_depth=0)
+        lad = R.DegradationLadder.for_serving(kv_quant=None, attn_mask=None)
         assert lad.pending == []
         spec = AttnMaskSpec(local=True, impl="sparse")
+        lad = R.DegradationLadder.for_serving(kv_quant="int8",
+                                              attn_mask=spec)
+        assert lad.pending == ["kv_wide", "mask_ref"]
         lad = R.DegradationLadder.for_serving(
-            kv_quant="int8", attn_mask=spec, pipeline_depth=1)
-        assert lad.pending == ["kv_wide", "mask_ref", "pipeline_serial"]
-        lad = R.DegradationLadder.for_serving(
-            kv_quant=None, attn_mask=dataclasses.replace(spec, impl="ref"),
-            pipeline_depth=1)
-        assert lad.pending == ["pipeline_serial"]
+            kv_quant="int8", attn_mask=dataclasses.replace(spec, impl="ref"))
+        assert lad.pending == ["kv_wide"]
 
     def test_percentiles_empty_and_dirty(self):
         z = _percentiles_ms([])
@@ -202,38 +218,6 @@ class TestPolicies:
 
 
 # ----------------------------------------------------- satellite fixes ----
-
-class TestStreamPipelineAbort:
-    def test_failing_wait_releases_all_slots(self, monkeypatch):
-        pipe = engine.StreamPipeline(1)
-        orig, calls = jax.block_until_ready, []
-
-        def boom(h):
-            calls.append(h)
-            if len(calls) == 1:
-                raise RuntimeError("deferred device error")
-            return orig(h)
-
-        pipe.push("a", jnp.zeros(3))
-        monkeypatch.setattr(engine.jax, "block_until_ready", boom)
-        with pytest.raises(RuntimeError, match="deferred device error"):
-            pipe.push("b", jnp.zeros(3))   # waits "a" out -> raises
-        assert len(pipe) == 0              # nothing leaked, nothing wedged
-        monkeypatch.setattr(engine.jax, "block_until_ready", orig)
-        pipe.push("c", jnp.zeros(3))       # still usable
-        pipe.drain()
-        assert len(pipe) == 0
-
-    def test_failing_drain_empties(self, monkeypatch):
-        pipe = engine.StreamPipeline(1)
-        pipe.push("a", jnp.zeros(2))
-        monkeypatch.setattr(
-            engine.jax, "block_until_ready",
-            lambda h: (_ for _ in ()).throw(RuntimeError("boom")))
-        with pytest.raises(RuntimeError):
-            pipe.drain()
-        assert len(pipe) == 0
-
 
 class TestQuantizeNonFinite:
     def test_raises_by_default(self):
@@ -311,18 +295,12 @@ def test_dequantize_cache_round_trip():
 # ------------------------------------------------------------ fault matrix --
 
 # (stage, kind, selector-kwargs, needs_kv_quant). uid 0 is resident from
-# step 0; full stage x kind coverage runs on the bcsr/depth-1 flagship,
-# cross-checks on the other backend/depth combos keep tier-1 runtime sane.
+# step 0; full stage x kind coverage runs with the bcsr SpMM traced inside
+# the fused tick, cross-checks on both backends keep tier-1 runtime sane.
 MATRIX = [
     ("prefill", "nan", dict(uid=1), None),
     ("prefill", "inf", dict(uid=0), None),
     ("prefill", "exception", dict(uid=1), None),
-    ("attention", "inf", dict(uid=0, step=1), None),
-    ("route", "nan", dict(uid=0, step=1), None),
-    ("route", "exception", dict(step=2), None),
-    ("route", "straggler", dict(step=1, delay_s=0.0), None),
-    ("execute", "nan", dict(uid=1, step=1), None),
-    ("execute", "exception", dict(step=0), None),
     ("sample", "nan", dict(uid=0, step=2), None),
     ("sample", "inf", dict(uid=1, step=0), None),
     ("quantize", "nan", dict(uid=0, step=1), "int8"),
@@ -334,10 +312,11 @@ MATRIX = [
                          MATRIX, ids=[f"{s}-{k}" for s, k, _, _ in MATRIX])
 def test_fault_matrix_bcsr_depth1(params, prompts, baselines,
                                   stage, kind, sel, kvq):
-    """Flagship combo: every stage x kind keeps survivors bit-identical."""
+    """Every stage x kind keeps survivors bit-identical, on the fused tick
+    with bcsr dispatch (the configuration this matrix has always swept)."""
     plan = R.FaultPlan.single(stage, kind, **sel)
-    sched, out = _run_sched(params, prompts, dispatch="bcsr", depth=1,
-                            plan=plan, kv_quant=kvq)
+    sched, out = _run_sched(params, prompts, dispatch="bcsr", plan=plan,
+                            kv_quant=kvq)
     assert plan.triggered, "fault never fired -- dead test"
     failed = {r.uid for r in sched.failed}
     if kind in ("exception", "straggler") or stage == "prefill":
@@ -346,7 +325,7 @@ def test_fault_matrix_bcsr_depth1(params, prompts, baselines,
         assert not failed
     else:
         assert failed, "activation poison must fail its request"
-    _assert_survivors_identical(out, baselines[("bcsr", 1, kvq)],
+    _assert_survivors_identical(out, baselines[("bcsr", kvq)],
                                 failed_uids=failed)
     # the poisoned/retried paths surface in the health summary
     h = sched.summary()["health"]
@@ -354,78 +333,58 @@ def test_fault_matrix_bcsr_depth1(params, prompts, baselines,
 
 
 CROSS = [
-    ("bcsr", 0, "execute", "inf", dict(uid=0, step=1), None),
-    ("bcsr", 0, "route", "exception", dict(step=1), None),
-    ("bcsr", 0, "quantize", "nan", dict(uid=0, step=0), "int8"),
-    ("gather", 1, "sample", "nan", dict(uid=1, step=2), None),
-    ("gather", 1, "prefill", "nan", dict(uid=0), None),
-    ("gather", 0, "sample", "inf", dict(uid=0, step=1), None),
-    ("gather", 0, "quantize", "inf", dict(uid=1, step=1), "int8"),
-    # the fused tick has advanced the donated pool when the sample hook
-    # fires: its retry must sample again, not run the forward twice
-    ("gather", 0, "sample", "exception", dict(step=2), None),
-    ("gather", 1, "sample", "exception", dict(step=2), None),
+    ("bcsr", "quantize", "nan", dict(uid=0, step=0), "int8"),
+    ("bcsr", "sample", "nan", dict(uid=1, step=2), None),
+    ("bcsr", "prefill", "nan", dict(uid=0), None),
+    ("gather", "sample", "inf", dict(uid=0, step=1), None),
+    ("gather", "quantize", "inf", dict(uid=1, step=1), "int8"),
+    # the tick has advanced the donated pool when the sample hook fires:
+    # its retry must sample again, not run the forward twice
+    ("gather", "sample", "exception", dict(step=2), None),
+    ("bcsr", "sample", "exception", dict(step=2), None),
 ]
 
 
 @pytest.mark.parametrize(
-    "dispatch,depth,stage,kind,sel,kvq", CROSS,
-    ids=[f"{d}-d{p}-{s}-{k}" for d, p, s, k, _, _ in CROSS])
-def test_fault_matrix_cross(params, prompts, baselines, dispatch, depth,
-                            stage, kind, sel, kvq):
-    """The other backend/depth combos hold the same isolation contract."""
+    "dispatch,stage,kind,sel,kvq", CROSS,
+    ids=[f"{d}-{s}-{k}" for d, s, k, _, _ in CROSS])
+def test_fault_matrix_cross(params, prompts, baselines, dispatch, stage,
+                            kind, sel, kvq):
+    """Both dispatch backends hold the same isolation contract."""
     plan = R.FaultPlan.single(stage, kind, **sel)
-    sched, out = _run_sched(params, prompts, dispatch=dispatch, depth=depth,
-                            plan=plan, kv_quant=kvq)
+    sched, out = _run_sched(params, prompts, dispatch=dispatch, plan=plan,
+                            kv_quant=kvq)
     assert plan.triggered
     failed = {r.uid for r in sched.failed}
     if kind == "exception" or stage == "prefill":
         assert not failed
     else:
         assert failed
-    _assert_survivors_identical(out, baselines[(dispatch, depth, kvq)],
+    _assert_survivors_identical(out, baselines[(dispatch, kvq)],
                                 failed_uids=failed)
     if not failed:
         # the pool left behind is the fault-free run's: a retry that ran a
         # step's forward twice would advance its state (MoE occupancy
         # counts) twice, which the tokens alone may not show
-        for a, b in zip(jax.tree.leaves(sched.cache),
-                        jax.tree.leaves(baselines.pool((dispatch, depth,
-                                                        kvq)))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        _assert_pools_equal(sched.cache, baselines.pool((dispatch, kvq)))
 
 
 def test_loop_poison_isolated_per_row(params):
-    """ServeLoop: a poisoned batch row is flagged in health_rows while the
-    other row's tokens stay bit-identical (per-row independence)."""
+    """ServeLoop: a row poisoned at the sample stage is flagged in
+    health_rows while the other row's tokens stay bit-identical (per-row
+    independence)."""
     prompts = jax.random.randint(jax.random.PRNGKey(1), (2, PROMPT), 0,
                                  TINY.vocab_size)
-    loop = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="bcsr",
-                     two_phase=True, pipeline_depth=1)
+    loop = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="bcsr")
     base = loop.run(prompts, GEN)
     assert loop.health_rows.all()
-    plan = R.FaultPlan.single("execute", "nan", row=1, step=2)
+    plan = R.FaultPlan.single("sample", "nan", row=1, step=2)
     fl = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="bcsr",
-                   two_phase=True, pipeline_depth=1, fault_plan=plan)
+                   fault_plan=plan)
     out = fl.run(prompts, GEN)
     assert list(fl.health_rows) == [True, False]
     np.testing.assert_array_equal(out[0], base[0])
     assert fl.summary()["health"]["rows_finite"] == [True, False]
-
-
-def test_loop_exception_aborts_pipeline_and_stays_usable(params):
-    prompts = jax.random.randint(jax.random.PRNGKey(1), (2, PROMPT), 0,
-                                 TINY.vocab_size)
-    base = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="bcsr",
-                     two_phase=True, pipeline_depth=1).run(prompts, GEN)
-    plan = R.FaultPlan.single("route", "exception", step=1)
-    loop = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="bcsr",
-                     two_phase=True, pipeline_depth=1, fault_plan=plan)
-    with pytest.raises(R.InjectedFault):
-        loop.run(prompts, GEN)
-    assert len(loop._pipe) == 0      # no leaked in-flight execute
-    out = loop.run(prompts, GEN)     # plan spent: clean rerun, same loop
-    np.testing.assert_array_equal(out, base)
 
 
 # --------------------------------------------------- retry / deadlines ----
@@ -437,7 +396,7 @@ class TestRetryPolicyIntegration:
         assert not sched.failed
         req0 = next(r for r in sched.finished if r.uid == 0)
         assert req0.retries == 1
-        _assert_survivors_identical(out, baselines[("bcsr", 1, None)])
+        _assert_survivors_identical(out, baselines[("bcsr", None)])
 
     def test_prefill_retry_exhaustion(self, params, prompts, baselines):
         plan = R.FaultPlan.single("prefill", "nan", uid=0, times=99)
@@ -449,17 +408,14 @@ class TestRetryPolicyIntegration:
         assert req0.state == "failed" and req0.retries == 2
         assert req0.fail_reason == "prefill_poisoned"
         assert req0.slot is None         # slot freed for the next admit
-        _assert_survivors_identical(out, baselines[("bcsr", 1, None)],
+        _assert_survivors_identical(out, baselines[("bcsr", None)],
                                     failed_uids=failed)
 
     def test_backoff_delays_follow_schedule(self, params, prompts):
         plan = R.FaultPlan.single("prefill", "nan", uid=0, times=99)
         retry = R.RetryPolicy(max_retries=3, base_delay_s=0.01,
                               multiplier=2.0, max_delay_s=0.03)
-        sched = ServeScheduler(
-            params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch="bcsr",
-            two_phase=True, cache_dtype=jnp.float32, pipeline_depth=1,
-            fault_plan=plan, retry=retry)
+        sched = _sched(params, plan=plan, retry=retry)
         slept = []
         sched._sleep = slept.append
         for p in prompts:
@@ -468,25 +424,28 @@ class TestRetryPolicyIntegration:
         assert slept == pytest.approx([0.01, 0.02, 0.03])
 
     def test_decode_retry_exhaustion_raises(self, params, prompts):
-        plan = R.FaultPlan.single("route", "exception", step=1, times=99)
-        retry = R.RetryPolicy(max_retries=1)
-        sched = ServeScheduler(
-            params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch="bcsr",
-            two_phase=True, cache_dtype=jnp.float32, pipeline_depth=1,
-            fault_plan=plan, retry=retry)
+        """A decode step that exhausts its retries raises and leaves the
+        pool its one forward advanced in ``.cache``: the pool a fault-free
+        run holds after the same tick."""
+        plan = R.FaultPlan.single("sample", "exception", step=1, times=99)
+        sched = _sched(params, plan=plan, retry=R.RetryPolicy(max_retries=1))
         for p in prompts:
             sched.submit(p, GEN)
         with pytest.raises(RuntimeError, match="failed after 1 retries"):
             sched.run()
-        assert len(sched._pipe) == 0     # aborted clean, not wedged
+        assert sched.step_idx == 1 and sched._advanced is None
+        clean = _sched(params)
+        for p in prompts:
+            clean.submit(p, GEN)
+        clean.step()
+        clean.step()
+        _assert_pools_equal(sched.cache, clean.cache)
 
     @pytest.mark.parametrize("stage", ["prefill", "decode"])
     def test_program_error_propagates_unretried(self, params, prompts, stage):
         """Only ``R.RETRYABLE`` is retried: any other exception is a fault
         of the program and leaves ``run`` as itself, no request FAILED."""
-        sched = ServeScheduler(
-            params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch="bcsr",
-            two_phase=True, cache_dtype=jnp.float32, pipeline_depth=1)
+        sched = _sched(params)
 
         def bug(*a, **k):
             raise ZeroDivisionError("a bug, not a flaky request")
@@ -501,9 +460,9 @@ class TestRetryPolicyIntegration:
 
 class TestDeadlinesAndShedding:
     def _sched(self, params, **kw):
-        return ServeScheduler(params, TINY, max_seq=MAX_SEQ, max_slots=1,
-                              dispatch="gather", two_phase=False,
-                              cache_dtype=jnp.float32, **kw)
+        return reuse_programs(ServeScheduler(
+            params, TINY, max_seq=MAX_SEQ, max_slots=1, dispatch="gather",
+            cache_dtype=jnp.float32, **kw))
 
     def test_deadlines_fake_clock(self, params, prompts):
         t = [0.0]
@@ -563,44 +522,45 @@ class TestDeadlinesAndShedding:
 
 # ------------------------------------------------------------- ladder -----
 
-def test_ladder_integration_walks_rungs(params, prompts, baselines):
+def test_ladder_integration_walks_rungs(params, prompts):
     """fail_threshold=1: each failure applies the next applicable rung --
-    kv_wide flips the live cache to scale-free wide f32, pipeline_serial
-    drops to depth 0 -- and the scheduler keeps serving afterwards."""
+    kv_wide flips the live cache to scale-free wide f32, mask_ref sends
+    masked prefill attention to the reference path -- and the scheduler
+    keeps serving afterwards."""
     plan = R.FaultPlan([
-        R.FaultSpec("execute", "nan", uid=0, step=0),
-        R.FaultSpec("execute", "nan", uid=1, step=1),
+        R.FaultSpec("sample", "nan", uid=0, step=0),
+        R.FaultSpec("sample", "nan", uid=1, step=1),
     ])
-    sched, out = _run_sched(params, prompts, depth=1, kv_quant="int8",
-                            plan=plan, fail_threshold=1)
+    spec = AttnMaskSpec(local=True, impl="sparse")
+    sched, out = _run_sched(params, prompts, kv_quant="int8", plan=plan,
+                            attn_mask=spec, fail_threshold=1)
     st = sched.ladder.state()
-    assert st["applied"] == ["kv_wide", "pipeline_serial"]
-    assert sched.kv_quant is None and sched.pipeline_depth == 0
-    assert sched._pipe.depth == 0
+    assert st["applied"] == ["kv_wide", "mask_ref"]
+    assert sched.kv_quant is None and sched.attn_mask.impl == "ref"
     paths = [str(p) for p, _ in
              jax.tree_util.tree_leaves_with_path(sched.cache)]
     assert not any("scale" in p for p in paths)
     assert len(sched.finished) == 1      # the non-faulted request completed
     degr = [e for e in sched.summary()["health"]["events"]
             if e["event"] == "degrade"]
-    assert [e["rung"] for e in degr] == ["kv_wide", "pipeline_serial"]
+    assert [e["rung"] for e in degr] == ["kv_wide", "mask_ref"]
 
 
 def test_kv_wide_rung_after_a_fused_forward(params, prompts, baselines):
-    """A sample-stage exception on the fused path fires after the donated
-    forward: the kv_wide rung it triggers rebuilds the pool that forward
-    advanced (the one it was given is gone), and the retry samples the
-    step's int8-KV logits, so tokens through that step match the int8
-    baseline and every request finishes."""
+    """A sample-stage exception fires after the donated forward: the
+    kv_wide rung it triggers rebuilds the pool that forward advanced (the
+    one it was given is gone), and the retry samples the step's int8-KV
+    logits, so tokens through that step match the int8 baseline and every
+    request finishes."""
     plan = R.FaultPlan.single("sample", "exception", step=2)
-    sched, out = _run_sched(params, prompts, dispatch="gather", depth=0,
+    sched, out = _run_sched(params, prompts, dispatch="gather",
                             kv_quant="int8", plan=plan, fail_threshold=1)
     assert sched.ladder.state()["applied"] == ["kv_wide"]
     assert not sched.failed and len(sched.finished) == N_REQ
     paths = [str(p) for p, _ in
              jax.tree_util.tree_leaves_with_path(sched.cache)]
     assert not any("scale" in p for p in paths)
-    base = baselines[("gather", 0, "int8")]
+    base = baselines[("gather", "int8")]
     for uid in (0, 1):       # resident from tick 0: prefill + ticks 0..2
         np.testing.assert_array_equal(out[uid][:4], base[uid][:4])
 
@@ -608,43 +568,26 @@ def test_kv_wide_rung_after_a_fused_forward(params, prompts, baselines):
 def test_mask_ref_rung_rewrites_spec(params):
     spec = AttnMaskSpec(local=True, impl="sparse")
     loop = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="gather",
-                     two_phase=False, attn_mask=spec)
+                     attn_mask=spec)
     assert "mask_ref" in loop.ladder.pending
     loop._apply_rung("mask_ref")
     assert loop.attn_mask.impl == "ref"
     assert loop.attn_mask.local == spec.local   # only impl changes
 
 
+def test_pipeline_depth_other_than_zero_is_refused(params):
+    with pytest.raises(ValueError, match="pipeline_depth must be 0"):
+        _sched(params, pipeline_depth=1)
+
+
 # ------------------------------------------------------- sync accounting --
-
-def test_depth1_health_adds_no_syncs(params, prompts, baselines,
-                                     monkeypatch):
-    """The healthy pipelined path performs exactly ONE device fetch per
-    decode step (the token ids) -- the isfinite health bits ride inside
-    it, not beside it."""
-    sched = ServeScheduler(
-        params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch="bcsr",
-        two_phase=True, cache_dtype=jnp.float32, pipeline_depth=1)
-    for p in prompts:
-        sched.submit(p, GEN)
-    fetches = []
-    orig = jax.device_get
-    monkeypatch.setattr(jax, "device_get", lambda x: fetches.append(1)
-                        or orig(x))
-    out = sched.run()
-    decode_steps = sum(1 for s in sched.stats if s.phase == "decode")
-    assert len(fetches) == decode_steps
-    _assert_survivors_identical(out, baselines[("bcsr", 1, None)])
-
 
 def test_depth0_samples_on_device_with_one_fetch(params, prompts, baselines,
                                                 monkeypatch):
-    """Depth 0 on the fused gather path samples each decode tick on the
-    device too: one ``jax.device_get`` per decode step, and no eager
-    argmax beyond each admission's first token."""
-    sched = ServeScheduler(
-        params, TINY, max_seq=MAX_SEQ, max_slots=SLOTS, dispatch="gather",
-        two_phase=False, cache_dtype=jnp.float32, pipeline_depth=0)
+    """The fused gather tick samples on the device: one
+    ``jax.device_get`` per decode step, and no eager argmax beyond each
+    admission's first token."""
+    sched = _sched(params, dispatch="gather")
     for p in prompts:
         sched.submit(p, GEN)
     fetches, eager_argmax = [], []
@@ -663,7 +606,7 @@ def test_depth0_samples_on_device_with_one_fetch(params, prompts, baselines,
     assert decode_steps and prefills == len(prompts)
     assert len(fetches) == decode_steps
     assert len(eager_argmax) == prefills
-    _assert_survivors_identical(out, baselines[("gather", 0, None)])
+    _assert_survivors_identical(out, baselines[("gather", None)])
 
 
 # ------------------------------------------------------------- stress -----
@@ -682,8 +625,7 @@ def test_randomized_fault_stress(params):
     def drive(plan):
         sched = ServeScheduler(
             params, TINY, max_seq=MAX_SEQ, max_slots=4, dispatch="bcsr",
-            two_phase=True, cache_dtype=jnp.float32, pipeline_depth=1,
-            fault_plan=plan)
+            cache_dtype=jnp.float32, fault_plan=plan)
         pending = list(zip(prompts, gens))
         i = 0
         while pending or sched.has_work():

@@ -1,11 +1,11 @@
-"""ServeLoop: the two-phase route-then-compile serving loop.
+"""ServeLoop: the static-batch serving loop.
 
 Tier-1 coverage runs on a tiny MoE config (seconds, CPU): token-for-token
-parity of the ServeLoop against the pre-refactor serving loop (fused jit
-decode), token parity of the two-phase bcsr path against the gather
-baseline, and the bucket law on the phase-2 compile cache.  The full
-smoke-arch loop is ``@pytest.mark.serve`` -- tiered out of the default
-selection like ``slow`` (enable with ``--run-serve`` or ``-m serve``).
+parity of the ServeLoop against the plain prefill-then-decode loop (fused
+jit decode) and reproducible temperature sampling.  The full smoke-arch
+loop on both dispatch backends is ``@pytest.mark.serve`` -- tiered out of
+the default selection like ``slow`` (enable with ``--run-serve`` or
+``-m serve``).
 """
 import dataclasses
 
@@ -57,72 +57,11 @@ def test_serve_loop_fused_matches_old_loop(tiny_model):
     params, prompts = tiny_model
     want = _old_style_loop(params, TINY, prompts, GEN)
     loop = ServeLoop(params, TINY, max_seq=MAX_SEQ)
-    assert not loop.two_phase  # gather default = fused mode
     got = loop.run(prompts, GEN)
     np.testing.assert_array_equal(got, want)
     s = loop.summary()
     assert s["decode"]["calls"] == GEN - 1
     assert s["prefill"]["seconds"] > 0 and s["decode"]["seconds"] > 0
-
-
-def test_serve_loop_two_phase_token_parity(tiny_model):
-    """bcsr two-phase decode generates the same tokens as the gather fused
-    loop (the backends are bit-identical per layer), while streaming
-    bucketed -- not full-grid -- index streams and compiling phase 2 a
-    bounded number of times."""
-    params, prompts = tiny_model
-    want = _old_style_loop(params, TINY, prompts, GEN)
-    loop = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="bcsr")
-    assert loop.two_phase  # auto-enabled: moe arch + bcsr backend
-    got = loop.run(prompts, GEN)
-    np.testing.assert_array_equal(got, want)
-
-    s = loop.summary()
-    # prefill AND every decode step routed + executed every attn+moe layer
-    # (prefill rides the layered bucketed-stream path too since PR 5)
-    n_moe_layers = sum(k == "attn+moe" for k in TINY.block_unit) * TINY.n_repeats
-    assert s["route"]["calls"] == GEN * n_moe_layers
-    assert s["execute"]["calls"] == s["route"]["calls"]
-    # phase-2 compiles are keyed on the bucket: one signature for the whole
-    # single-token decode phase plus one for the prefill token shape, never
-    # one per step
-    assert s["compile_signatures"] < s["execute"]["calls"]
-    assert s["compile_signatures"] <= 3
-    prefill_routes = [st for st in loop.stats
-                      if st.phase == "route" and st.step == -1]
-    assert len(prefill_routes) == n_moe_layers  # prefill streamed, not grid
-
-    # a second run on the same loop resets generation state: its prefill
-    # routes are labeled step -1 again, not with the stale last step index
-    got2 = loop.run(prompts, GEN)
-    np.testing.assert_array_equal(got2, want)
-    prefill_routes2 = [st for st in loop.stats
-                       if st.phase == "route" and st.step == -1]
-    assert len(prefill_routes2) == n_moe_layers
-    routes = [st for st in loop.stats if st.phase == "route"]
-    for st in routes:
-        assert st.extra["nnzb_stream"] <= max(
-            2 * st.extra["nnzb_covered"], st.extra["bucket"])
-
-
-def test_serve_loop_two_phase_decode_equals_layered_reference(tiny_model):
-    """The layered decode path (what two-phase mode drives) reproduces the
-    scanned decode_step logits."""
-    params, prompts = tiny_model
-    logits, cache, pos = M.prefill(params, prompts, TINY, max_seq=MAX_SEQ,
-                                   cache_dtype=jnp.float32)
-    tok = jnp.argmax(logits[:, -1, :TINY.vocab_size],
-                     axis=-1)[:, None].astype(jnp.int32)
-    want, want_cache = M.decode_step(params, TINY, cache, pos, tok,
-                                     dtype=jnp.float32)
-    got, got_cache = M.decode_step_layered(params, TINY, cache, int(pos),
-                                           tok, dtype=jnp.float32)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5),
-        got_cache, want_cache)
 
 
 def test_serve_loop_temperature_sampling_runs(tiny_model):
@@ -140,8 +79,8 @@ def test_serve_loop_temperature_sampling_runs(tiny_model):
 @pytest.mark.serve
 @pytest.mark.parametrize("dispatch", ["gather", "bcsr"])
 def test_serve_loop_smoke_arch(dispatch):
-    """Full smoke-config serving loop on a real MoE arch, both backends,
-    two-phase auto-selected for bcsr.  Tiered behind --run-serve."""
+    """Full smoke-config serving loop on a real MoE arch, both backends.
+    Tiered behind --run-serve."""
     from repro.configs import get_smoke
 
     cfg = get_smoke("llama4-scout-17b-a16e")
@@ -153,5 +92,3 @@ def test_serve_loop_smoke_arch(dispatch):
     gen = loop.run(prompts, 4)
     assert gen.shape == (2, 4)
     assert (gen >= 0).all() and (gen < cfg.vocab_size).all()
-    if dispatch == "bcsr":
-        assert loop.two_phase and loop.summary()["compile_signatures"] >= 1

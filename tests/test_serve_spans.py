@@ -5,11 +5,11 @@ Every scheduler tick is one ``step`` record (a :class:`StepStat` and a
 ``prefill`` per admission), ``decode`` (the forward through the health
 fetch, the device sampler inside it), ``writeback`` (the commit of the
 slot pool that ``jit_decode_step`` took donated and updated in place:
-only making the returned pool the scheduler's; the layered two-phase path
-still scatters the step's rows there) and ``sample`` (the per-row host
-bookkeeping).  A sample-stage fault retries from the committed logits.
-The ``step`` record counts the host syncs made inside it.
-Tier-1, tiny config on the CPU.
+only making the returned pool the scheduler's) and ``sample`` (the
+per-row host bookkeeping).  The ``step`` record counts the host syncs made
+inside it.  The tick-shape tests run on a dense stack, a top-1 MoE stack
+and the hybrid Qwen3-Next stack.
+Tier-1, tiny configs on the CPU.
 """
 import glob
 
@@ -23,28 +23,52 @@ from repro.models.config import ArchConfig
 from repro.models import model as M
 from repro.launch.serve import ServeLoop, ServeScheduler
 
+from test_serve_inplace import MOE, QWEN3_NEXT, reuse_programs
+
 TINY = ArchConfig(
     name="tiny-spans", family="dense", d_model=32, n_heads=2, n_kv_heads=1,
     d_ff=48, vocab_size=64, block_unit=("attn",), n_repeats=2, head_dim=16,
     policy="f32")
+CONFIGS = {"dense": TINY, "moe": MOE, "qwen3-next": QWEN3_NEXT}
 MAX_SEQ = 24
 # per admission: the prefill's block, the poison gate's fetch, the position,
 # the first token
 SYNCS_PER_ADMISSION = 4
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    params = M.init_params(jax.random.PRNGKey(0), TINY)
+def _model(cfg):
+    params = jax.jit(M.init_params, static_argnums=1)(jax.random.PRNGKey(0),
+                                                      cfg)
     rng = np.random.default_rng(1)
-    reqs = [(rng.integers(0, TINY.vocab_size, int(rng.integers(4, 9))),
+    reqs = [(rng.integers(0, cfg.vocab_size, int(rng.integers(4, 9))),
              int(rng.integers(3, 7))) for _ in range(4)]
     return params, reqs
 
 
-def _serve(params, reqs, depth, slots=2):
-    sched = ServeScheduler(params, TINY, max_seq=MAX_SEQ, max_slots=slots,
-                           dispatch="gather", pipeline_depth=depth)
+@pytest.fixture(scope="module")
+def tiny():
+    return _model(TINY)
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One scheduler run per config, made when a test first asks for it:
+    ``served(name) -> (params, reqs, sched, tokens)``."""
+    runs = {}
+
+    def get(name):
+        if name not in runs:
+            params, reqs = _model(CONFIGS[name])
+            runs[name] = (params, reqs,
+                          *_serve(params, reqs, CONFIGS[name]))
+        return runs[name]
+    return get
+
+
+def _serve(params, reqs, cfg=TINY, slots=2):
+    sched = reuse_programs(ServeScheduler(
+        params, cfg, max_seq=MAX_SEQ, max_slots=slots, dispatch="gather",
+        pipeline_depth=0))
     for prompt, gen in reqs:
         sched.submit(prompt, gen)
     out = sched.run()
@@ -64,10 +88,9 @@ def _inside(inner, outer):
             and inner.start + inner.seconds <= outer.start + outer.seconds)
 
 
-@pytest.mark.parametrize("depth", [0, 1])
-def test_each_tick_nests_its_phases_in_order(tiny, depth):
-    params, reqs = tiny
-    sched, _ = _serve(params, reqs, depth)
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_each_tick_nests_its_phases_in_order(served, name):
+    _, reqs, sched, _ = served(name)
     ticks = _ticks(sched.stats)
     assert sorted(ticks) == list(range(sched.step_idx))
     assert sum(s.phase == "step" for s in sched.stats) == sched.step_idx
@@ -89,13 +112,12 @@ def test_each_tick_nests_its_phases_in_order(tiny, depth):
     assert admitted == len(reqs)
 
 
-@pytest.mark.parametrize("depth", [0, 1])
-def test_host_syncs_per_tick(tiny, depth):
-    params, reqs = tiny
-    sched, _ = _serve(params, reqs, depth)
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_host_syncs_per_tick(served, name):
+    _, _, sched, _ = served(name)
     for k, (step, kids) in _ticks(sched.stats).items():
         decode = next(s for s in kids if s.phase == "decode")
-        # one fetch of the device-sampled ids and health bits, any depth
+        # one fetch of the device-sampled ids and health bits
         assert decode.extra["active"] >= 1
         prefills = sum(s.phase == "prefill" for s in kids)
         assert step.extra["host_syncs"] == (
@@ -104,23 +126,22 @@ def test_host_syncs_per_tick(tiny, depth):
         s.extra["host_syncs"] for s in sched.stats if s.phase == "step")
 
 
-@pytest.mark.parametrize("depth", [0, 1])
-def test_tokens_unchanged(tiny, depth):
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_tokens_unchanged(served, name):
     """Spans and counting change no token: each request matches a
     sequential single-request ServeLoop."""
-    params, reqs = tiny
-    _, out = _serve(params, reqs, depth)
+    params, reqs, _, out = served(name)
     for uid, (prompt, gen) in enumerate(reqs):
-        loop = ServeLoop(params, TINY, max_seq=MAX_SEQ, dispatch="gather")
+        loop = reuse_programs(ServeLoop(params, CONFIGS[name],
+                                        max_seq=MAX_SEQ, dispatch="gather"))
         want = loop.run(jnp.asarray(prompt[None, :], jnp.int32), gen)[0]
         np.testing.assert_array_equal(out[uid], want)
 
 
-def test_decode_record_ends_at_the_health_fetch(tiny):
+def test_decode_record_ends_at_the_health_fetch(served):
     """``decode`` keeps its meaning (forward through the health fetch):
     it is what ``summary()`` and each token's latency read."""
-    params, reqs = tiny
-    sched, _ = _serve(params, reqs, 0)
+    _, _, sched, _ = served("dense")
     decodes = [s for s in sched.stats if s.phase == "decode"]
     s = sched.summary()
     assert s["decode"]["calls"] == len(decodes)
