@@ -4,7 +4,10 @@ Two drivers share one base (:class:`_ServeBase`: dispatch-backend override,
 fault hooks, spans, the degradation ladder), and both run the model as
 whole-stack compiled programs -- ``model.prefill`` and ``model.decode_step``,
 MoE layers included, on either dispatch backend ("gather" or the bcsr SpMM,
-traced over its full grid):
+traced over its full grid).  The base takes the weights once and holds
+their matrices in the policy's compute dtype (``model.compute_params``;
+``summary()["weights"]`` counts the bytes), so no compiled step converts
+them again:
 
 * :class:`ServeLoop` -- the static-batch driver: one prefill over a fixed
   (B, S) prompt batch, then lockstep decode, one jit-compiled
@@ -81,6 +84,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke
 from repro.core.masks import AttnMaskSpec
+from repro.core.precision import policy as precision_policy
 from repro.kernels import engine
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.models import model as M
@@ -214,6 +218,18 @@ def _commit_row(pool, row_cache, slot):
     return _put_rows(pool, row_cache, slot)
 
 
+def _weight_counts(given, served, cfg) -> Dict[str, int]:
+    """``summary()["weights"]``: the bytes of the served tree's leaves held
+    in the compute dtype and of all the others, and how many leaves
+    ``M.compute_params`` cast from the tree it was given."""
+    cd = jnp.dtype(precision_policy(cfg.policy).compute_dtype)
+    leaves = jax.tree.leaves(served)
+    return {"compute_bytes": sum(a.nbytes for a in leaves if a.dtype == cd),
+            "wide_bytes": sum(a.nbytes for a in leaves if a.dtype != cd),
+            "cast_leaves": sum(a.dtype != b.dtype for a, b in
+                               zip(leaves, jax.tree.leaves(given)))}
+
+
 class _ServeBase:
     """What the static-batch :class:`ServeLoop` and the continuous-batching
     :class:`ServeScheduler` share: the dispatch-backend override, the fault
@@ -238,6 +254,11 @@ class _ServeBase:
             # opt-in narrow expert FFN weights: one-time host quantization,
             # QuantTensor leaves then flow through the compiled step
             self.params = moe.quantize_model_experts(params, quantize_experts)
+        # the matrices held in the compute dtype once, here, so no compiled
+        # step converts the whole weight set again on every call
+        given = self.params
+        self.params = M.compute_params(given, cfg)
+        self._weights = _weight_counts(given, self.params, cfg)
         self.backend = dispatch or cfg.moe_dispatch
         self.temperature = temperature
         self._sample_seed = sample_seed
@@ -341,6 +362,7 @@ class _ServeBase:
                               "calls": len(ss)}
         out["timing"] = {"attention_ref_fallbacks":
                          flash_ops.fallback_count() - self._fallback_base}
+        out["weights"] = dict(self._weights)
         # resilience surface: monotonic counters + bounded event log
         # (HealthTracker), the degradation-ladder position, and the exact
         # faults the plan fired (see tests/README.md "Resilience contract")
@@ -365,8 +387,8 @@ class ServeLoop(_ServeBase):
     temperature : 0 = greedy argmax, > 0 = categorical sampling.
     quantize_experts : narrow dtype name ("fp8_e4m3" | "fp8_e5m2" | "int8")
         to BlockQuant the expert FFN weights at construction
-        (``moe.quantize_model_experts``); None (default) leaves params
-        untouched.
+        (``moe.quantize_model_experts``); None (default) leaves the
+        experts unquantized.
     kv_quant : narrow dtype name to store full-context KV caches as
         per-position narrow values + f32 scales (local ring buffers stay
         wide); None (default) keeps the wide cache bit-for-bit.

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.masks import NEG_INF
-from repro.core.precision import policy as precision_policy
+from repro.core.precision import QuantTensor, policy as precision_policy
 from repro.models.config import ArchConfig
 from repro.models import layers as L
 from repro.models import gdn, mamba2, moe, rwkv6
@@ -87,6 +87,45 @@ def init_params(key, cfg: ArchConfig) -> Params:
         p["prologue"] = jax.vmap(
             lambda k: init_block(k, cfg.block_unit[0], cfg))(pro_keys)
     return p
+
+
+# -------------------------------------------------------- served weights ----
+
+# Weight leaves whose every use in ``prefill`` and ``decode_step`` casts them
+# to the compute dtype: the matrices and biases of attention, of the dense,
+# shared and held-expert MLPs and of the Gated DeltaNet mixer, the shared
+# expert's gate, and the embedding tables.
+COMPUTE_LEAVES = frozenset({
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "w_gate", "w_up", "w_down",
+    "in_proj_qkvz", "in_proj_ba", "out_proj", "shared_gate", "embed",
+    "unembed"})
+# Weight leaves used in float32 whatever the policy: norm scales (rmsnorm
+# multiplies in f32), the MoE router (routing runs in f32), and the Gated
+# DeltaNet decays, conv taps and output-norm weights.
+WIDE_LEAVES = frozenset({"scale", "router", "A_log", "dt_bias", "conv_w",
+                         "norm"})
+
+
+def compute_params(params: Params, cfg: ArchConfig) -> Params:
+    """``params`` with every leaf named in :data:`COMPUTE_LEAVES` held in
+    the policy's compute dtype, for a driver that passes the tree to a
+    compiled step on every call: the step then reads the narrow copy
+    instead of converting the wide one each time.  The values are the ones
+    each use's ``.astype`` gives, so every logit is bit-identical.  All
+    other leaves come back as they are: :data:`WIDE_LEAVES`, quantized
+    expert weights (``QuantTensor``), the mamba and RWKV mixers' own
+    weights, and every leaf already in the compute dtype (all of them
+    under an f32 policy)."""
+    cd = precision_policy(cfg.policy).compute_dtype
+
+    def cast(path, a):
+        if (isinstance(a, QuantTensor) or a.dtype == cd
+                or getattr(path[-1], "key", None) not in COMPUTE_LEAVES):
+            return a
+        return a.astype(cd)
+
+    return jax.tree_util.tree_map_with_path(
+        cast, params, is_leaf=lambda a: isinstance(a, QuantTensor))
 
 
 # --------------------------------------------------------------- blocks -----
